@@ -12,6 +12,34 @@ import os
 import sys
 
 
+def _device_backend(cfg, holder):
+    """The TPUBackend for executor=tpu, over a mesh when mesh-devices
+    asks for one (ISSUE r13: block stacks sharded over the devices,
+    programs under shard_map with ICI collectives). A count the
+    platform cannot satisfy raises MeshConfigError rather than
+    under-sharding a node sized for more chips."""
+    import jax
+
+    from pilosa_tpu.exec.tpu import TPUBackend
+    from pilosa_tpu.parallel import MeshConfigError, ShardMesh
+
+    mesh = None
+    if cfg.mesh_devices:
+        devices = jax.devices()
+        want = len(devices) if cfg.mesh_devices < 0 else cfg.mesh_devices
+        if want > len(devices):
+            raise MeshConfigError(
+                f"mesh-devices={want} but only {len(devices)} "
+                "devices are visible"
+            )
+        if want > 1:
+            mesh = ShardMesh(devices[:want])
+    return TPUBackend(
+        holder, mesh=mesh, max_bytes=cfg.max_hbm_bytes or None,
+        heat_half_life=cfg.heat_half_life or None,
+    )
+
+
 def cmd_server(args) -> int:
     from pilosa_tpu.core import Holder
     from pilosa_tpu.exec import Executor
@@ -44,43 +72,29 @@ def cmd_server(args) -> int:
 
     backend = None
     if cfg.executor == "tpu":
+        # On the chip or not up. Whatever stops the device backend from
+        # being built — no TPU initialized (the platform rule,
+        # ops/runtime.py), mesh-devices past the inventory, a runtime
+        # that fails to start — ends the process with the reason and a
+        # non-zero code. The host path is `--executor cpu`, never a
+        # fallback taken here: a server that quietly served from the CPU
+        # under the name executor=tpu is the failure this refuses.
         try:
-            from pilosa_tpu.exec.tpu import TPUBackend
-
-            # mesh-devices (ISSUE r13): shard the block stacks over a
-            # device mesh so the serving programs run under shard_map
-            # with ICI collectives. A count the platform cannot satisfy
-            # raises MeshConfigError — caught below like any unusable
-            # device, logged with the structured message — instead of
-            # silently under-sharding a node sized for more chips.
-            mesh = None
-            if cfg.mesh_devices:
-                import jax
-
-                from pilosa_tpu.parallel import MeshConfigError, ShardMesh
-
-                devices = jax.devices()
-                want = (
-                    len(devices) if cfg.mesh_devices < 0 else cfg.mesh_devices
-                )
-                if want > len(devices):
-                    raise MeshConfigError(
-                        f"mesh-devices={want} but only {len(devices)} "
-                        "devices are visible"
-                    )
-                if want > 1:
-                    mesh = ShardMesh(devices[:want])
-            backend = TPUBackend(
-                holder, mesh=mesh, max_bytes=cfg.max_hbm_bytes or None,
-                heat_half_life=cfg.heat_half_life or None,
-            )
+            backend = _device_backend(cfg, holder)
+        except Exception as e:  # noqa: BLE001 — start-up boundary:
+            # every failure is reported and becomes exit code 1.
             log.printf(
-                "executor=tpu: device backend enabled (%d device%s)",
-                mesh.n if mesh is not None else 1,
-                "s" if mesh is not None and mesh.n > 1 else "",
+                "executor=tpu: cannot build the device backend (%s: %s); "
+                "not serving",
+                type(e).__name__, e,
             )
-        except Exception as e:  # no usable device: fall back
-            log.printf("executor=tpu unavailable (%s); falling back to cpu", e)
+            holder.close()
+            return 1
+        n_dev = backend.mesh.n if backend.mesh is not None else 1
+        log.printf(
+            "executor=tpu: device backend enabled (%d device%s)",
+            n_dev, "s" if n_dev > 1 else "",
+        )
     executor = Executor(holder, backend=backend)
     if backend is not None:
         from pilosa_tpu.exec.batcher import ShardLegBatcher

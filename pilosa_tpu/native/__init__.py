@@ -10,13 +10,14 @@ falls back to a pure-Python implementation with identical outputs.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "hash.cpp")
-_LIB = os.path.join(_HERE, "build", "libpilosa_native.so")
+_CXX = ("g++", "-O3", "-shared", "-fPIC")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -25,6 +26,37 @@ _scratch = threading.local()
 
 FNV32_OFFSET = 2166136261
 FNV64_OFFSET = 14695981039346656037
+
+
+def _cpu_features() -> str:
+    """The build host's instruction-set flags (Linux /proc/cpuinfo)."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line
+    except OSError:
+        pass
+    return os.uname().machine
+
+
+def _lib_path() -> str:
+    """Where the library for THIS source on THIS CPU lives. The name
+    carries a digest of everything the binary depends on — the source
+    text, the compiler line and, because -march=native bakes the build
+    host's instruction set in, the CPU's feature flags — so reuse never
+    rests on an mtime. A build directory copied from another machine
+    with its mtimes intact holds a library under another name, and this
+    host builds its own from src/hash.cpp instead of loading one that
+    may die on an illegal instruction, which no retry can catch."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX).encode())
+    h.update(_cpu_features().encode())
+    return os.path.join(
+        _HERE, "build", f"libpilosa_native-{h.hexdigest()[:16]}.so"
+    )
 
 
 def _load() -> ctypes.CDLL | None:
@@ -36,24 +68,32 @@ def _load() -> ctypes.CDLL | None:
             return _lib
         for attempt in ("load", "rebuild"):
             try:
-                stale = not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-                if stale or attempt == "rebuild":
-                    os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-                    base = ["g++", "-O3", "-shared", "-fPIC", "-o", _LIB, _SRC]
+                lib_path = _lib_path()
+                if attempt == "rebuild" or not os.path.exists(lib_path):
+                    os.makedirs(os.path.dirname(lib_path), exist_ok=True)
+                    # Compile to a private name and rename into place: a
+                    # concurrent process (a server child, a pool worker)
+                    # finds either no library, and builds its own, or a
+                    # whole one.
+                    tmp = f"{lib_path}.{os.getpid()}.tmp"
+                    tail = ("-o", tmp, _SRC)
                     try:
                         # -march=native: the .so is built per host on
                         # first use, so host-specific vectorization is
                         # safe; retried without for exotic toolchains.
                         # lint: allow-lock-discipline(one-time lazy toolchain build under the init latch; first callers accept the compile latency)
                         subprocess.run(
-                            base[:2] + ["-march=native"] + base[2:],
+                            _CXX[:2] + ("-march=native",) + _CXX[2:] + tail,
                             check=True,
                             capture_output=True,
                         )
                     except subprocess.CalledProcessError:
                         # lint: allow-lock-discipline(same one-time lazy build, -march fallback)
-                        subprocess.run(base, check=True, capture_output=True)
-                lib = ctypes.CDLL(_LIB)
+                        subprocess.run(
+                            _CXX + tail, check=True, capture_output=True
+                        )
+                    os.replace(tmp, lib_path)
+                lib = ctypes.CDLL(lib_path)
                 lib.pilosa_fnv32a.restype = ctypes.c_uint32
                 lib.pilosa_fnv32a.argtypes = [ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32]
                 lib.pilosa_fnv64a.restype = ctypes.c_uint64
@@ -106,8 +146,8 @@ def _load() -> ctypes.CDLL | None:
                 return _lib
             # lint: allow-except-exception(toolchain probe: loop retries a forced rebuild, then the fallback warns and pure-Python continues)
             except Exception:
-                # A stale/wrong-arch .so can fail to load: retry once with a
-                # forced rebuild before giving up on the native path.
+                # A truncated or foreign .so can fail to load: retry once
+                # with a forced rebuild before giving up on the native path.
                 continue
         _build_failed = True
         import warnings

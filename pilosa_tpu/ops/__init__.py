@@ -9,5 +9,15 @@ HBM roofline. Blocks are cached on device and re-uploaded only when the
 owning fragment's version changes.
 """
 
-from pilosa_tpu.ops.blocks import WORDS_PER_SHARD, pack_fragment
-from pilosa_tpu.ops.kernels import MAX_PAIR_SHARDS, pair_stats, pair_stats_xla
+from pilosa_tpu.ops.runtime import configure_compile_cache
+
+# Before anything under this package can compile: JAX latches whether a
+# persistent cache is in use at the process's first compile.
+configure_compile_cache()
+
+from pilosa_tpu.ops.blocks import WORDS_PER_SHARD, pack_fragment  # noqa: E402
+from pilosa_tpu.ops.kernels import (  # noqa: E402
+    MAX_PAIR_SHARDS,
+    pair_stats,
+    pair_stats_xla,
+)

@@ -9,13 +9,9 @@ from __future__ import annotations
 
 import json
 import os
+import tomllib
 from dataclasses import dataclass, field
 from typing import Any, Optional
-
-try:  # py3.11+ stdlib; gated so a 3.10 runtime still boots servers
-    import tomllib  # configured via env/flags (TOML files raise clearly)
-except ModuleNotFoundError:  # pragma: no cover — interpreter-dependent
-    tomllib = None
 
 
 @dataclass
@@ -326,11 +322,6 @@ class Config:
     ) -> "Config":
         cfg = Config()
         if toml_path:
-            if tomllib is None:
-                raise RuntimeError(
-                    "TOML config files need Python 3.11+ (tomllib); "
-                    "use PILOSA_TPU_* env vars or flags on this runtime"
-                )
             with open(toml_path, "rb") as f:
                 data = tomllib.load(f)
             cfg._apply_toml(data)

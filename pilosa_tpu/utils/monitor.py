@@ -24,6 +24,7 @@ from collections import deque
 from typing import Optional
 
 from pilosa_tpu import __version__
+from pilosa_tpu.native import has_native
 from pilosa_tpu.utils.stats import (
     BUCKET_BOUNDS,
     bucket_fraction_le,
@@ -579,6 +580,15 @@ class RuntimeMonitor:
             self._thread.join(timeout=5)
 
 
+def _dist_version(name: str) -> Optional[str]:
+    from importlib import metadata
+
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return None
+
+
 def _device_inventory() -> dict:
     """The jax device block for /debug/diagnostics (ISSUE r8 satellite):
     platform, device count, and per-device memory stats where the
@@ -588,11 +598,16 @@ def _device_inventory() -> dict:
     diagnostics endpoint must never 500 over its own inventory."""
     try:
         import jax
+        import jaxlib
 
         devices = jax.devices()
         inv: dict = {
             "platform": jax.default_backend(),
             "device_count": len(devices),
+            "version": jax.__version__,
+            "jaxlib_version": jaxlib.__version__,
+            "libtpu_version": _dist_version("libtpu"),
+            "compilation_cache_dir": jax.config.jax_compilation_cache_dir,
             "devices": [],
         }
         for d in devices:
@@ -630,6 +645,7 @@ def diagnostics_snapshot(holder=None, started_at: Optional[float] = None) -> dic
             "cpus": os.cpu_count(),
         },
         "jax": _device_inventory(),
+        "native": has_native(),
         "uptime_seconds": round(
             time.monotonic() - (started_at or PROCESS_STARTED_AT), 1
         ),

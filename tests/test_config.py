@@ -2,18 +2,9 @@
 round-trip through generate-config, and option wiring (reference
 server/config.go + docs/configuration.md)."""
 
-import pytest
+import tomllib
 
 from pilosa_tpu.server.config import Config
-
-try:  # py3.11+; the env/flag tests below still run on 3.10 (the module
-    import tomllib  # import is gated the same way in server/config.py)
-except ModuleNotFoundError:
-    tomllib = None
-
-needs_tomllib = pytest.mark.skipif(
-    tomllib is None, reason="tomllib needs Python 3.11+"
-)
 
 
 class TestSources:
@@ -24,7 +15,6 @@ class TestSources:
         assert cfg.max_hbm_bytes == 0
         assert cfg.client_timeout == 30.0
 
-    @needs_tomllib
     def test_toml_then_env_then_flags(self, tmp_path):
         p = tmp_path / "c.toml"
         p.write_text(
@@ -58,7 +48,6 @@ class TestSources:
 
 
 class TestRoundTrip:
-    @needs_tomllib
     def test_generate_config_reparses_to_same_values(self, tmp_path):
         cfg = Config.from_sources(env={})
         cfg.max_hbm_bytes = 789
@@ -103,7 +92,6 @@ class TestPlaneIsolationKnobs:
         assert d["refresh-window-ms"] == 50
         assert d["ingest-derate"] is False
 
-    @needs_tomllib
     def test_toml_text_round_trip(self, tmp_path):
         cfg = Config.from_sources(env={})
         cfg.snapshot_bandwidth = 8 << 20
