@@ -40,6 +40,28 @@ def _device_backend(cfg, holder):
     )
 
 
+def _close_holder(holder, log) -> None:
+    """holder.close(), and the one line that says where a graceful stop's
+    seconds went: the process is about to go, and /metrics with it. Per
+    step of Fragment.close() (and the attribute stores), summed seconds
+    over the number of closes."""
+    import time
+
+    from pilosa_tpu.utils.stats import global_stats
+
+    t0 = time.perf_counter()
+    holder.close()
+    steps = global_stats.timing_totals("holder_close_seconds")
+    log.printf(
+        "holder closed in %.2fs: holder_close_seconds %s",
+        time.perf_counter() - t0,
+        " ".join(
+            f"{name.split('"')[1]}={total:.2f}s/{n}"
+            for name, (total, n) in sorted(steps.items())
+        ),
+    )
+
+
 def cmd_server(args) -> int:
     from pilosa_tpu.core import Holder
     from pilosa_tpu.exec import Executor
@@ -384,7 +406,7 @@ def cmd_server(args) -> int:
         log.printf("shutting down")
         for d in daemons:
             d.stop()
-        holder.close()
+        _close_holder(holder, log)
     return 0
 
 

@@ -36,6 +36,7 @@ from pilosa_tpu.core.view import (
 from pilosa_tpu.roaring import Bitmap, serialize
 from pilosa_tpu.roaring.codec import deserialize
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils.stats import global_stats
 
 FIELD_TYPE_SET = "set"
 FIELD_TYPE_INT = "int"
@@ -192,10 +193,13 @@ class Field:
         with self.lock:
             for v in self.views.values():
                 v.close()
-            if self.row_attr_store is not None:
-                self.row_attr_store.close()
-            if self.translate_store is not None:
-                self.translate_store.close()
+            with global_stats.with_tags("step:attr_stores").timer(
+                "holder_close_seconds"
+            ):
+                if self.row_attr_store is not None:
+                    self.row_attr_store.close()
+                if self.translate_store is not None:
+                    self.translate_store.close()
 
     def _meta_path(self) -> str:
         return os.path.join(self.path, ".meta")

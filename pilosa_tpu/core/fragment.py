@@ -690,6 +690,21 @@ class Fragment:
         # view.lock across this call — stalling it stalls every new
         # shard of the view). Then wait outside the lock (the rewrite's
         # splice phase needs the lock to observe the flag).
+        from pilosa_tpu.utils.stats import global_stats
+
+        # What a graceful stop spends here, step by step and in this
+        # method's own words (holder_close_seconds{step}; cli.cmd_server
+        # logs the sums as one line; PERF.md has the bench index's).
+        t_step = time.perf_counter()
+
+        def lap(step: str) -> None:
+            nonlocal t_step
+            now = time.perf_counter()
+            global_stats.with_tags(f"step:{step}").timing(
+                "holder_close_seconds", now - t_step
+            )
+            t_step = now
+
         with self.lock:
             self._closed = True
         # A rewrite still queued behind other fragments is cancelled
@@ -697,9 +712,11 @@ class Fragment:
         # a worker already claimed is waited out — it aborts fast.
         if not SNAPSHOT_SCHEDULER.cancel(self):
             self.await_snapshot()
+        lap("snapshot_wait")
         with self._wal_drain_lock:
             with self.lock:
                 self.flush_cache()
+                lap("cache_flush")
                 if self._file is not None:
                     # Staged group-commit records go down before the fd
                     # detaches (ISSUE r19 tentpole 3); the extra flush
@@ -709,14 +726,17 @@ class Fragment:
                     self._drain_wal_locked()
                     if self.storage.op_writer is not None:
                         self.storage.op_writer.flush()
+                    lap("wal_drain")
                     # Every WAL byte is down: the sidecar's size stamp
                     # now describes exactly this file, so the next open
                     # adopts the epochs (directed repair survives clean
                     # restarts).
                     self._save_block_epochs()
+                    lap("block_epochs")
                     self._file.close()
                     self._file = None
                     self.storage.op_writer = None
+                    lap("file_close")
                 # This fragment's pending ops leave the live backlog
                 # with it (they are on disk and replay at the next open).
                 if self._backlog_reported:

@@ -10,10 +10,12 @@ from __future__ import annotations
 import os
 import shutil
 import threading
+import time
 from typing import Callable, Optional
 
 from pilosa_tpu.core.index import Index, IndexOptions
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils.stats import global_stats
 
 
 class Holder:
@@ -33,6 +35,7 @@ class Holder:
     def open(self) -> "Holder":
         """Scan the data directory and open all indexes (reference
         holder.go Open :137)."""
+        t0 = time.perf_counter()
         with self.lock:
             if self.path is not None:
                 os.makedirs(self.path, exist_ok=True)
@@ -43,6 +46,15 @@ class Holder:
                     idx = Index(full, entry, broadcast_shard=self._shard_broadcaster)
                     self.indexes[entry] = idx.open()
             self.opened = True
+            # What a restart pays before the first request: set once, so a
+            # scrape at any later time still reads the start-up's cost.
+            global_stats.gauge("holder_open_seconds", time.perf_counter() - t0)
+            global_stats.gauge("holder_fragments_opened", sum(
+                len(v.fragments)
+                for idx in self.indexes.values()
+                for f in idx.fields.values()
+                for v in f.views.values()
+            ))
         return self
 
     def close(self) -> None:

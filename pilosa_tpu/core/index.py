@@ -16,6 +16,7 @@ from typing import Callable, Optional
 
 from pilosa_tpu.core.field import Field, FieldOptions
 from pilosa_tpu.roaring import Bitmap
+from pilosa_tpu.utils.stats import global_stats
 
 EXISTENCE_FIELD_NAME = "_exists"
 
@@ -94,10 +95,13 @@ class Index:
         with self.lock:
             for f in self.fields.values():
                 f.close()
-            if self.column_attr_store is not None:
-                self.column_attr_store.close()
-            if self.translate_store is not None:
-                self.translate_store.close()
+            with global_stats.with_tags("step:attr_stores").timer(
+                "holder_close_seconds"
+            ):
+                if self.column_attr_store is not None:
+                    self.column_attr_store.close()
+                if self.translate_store is not None:
+                    self.translate_store.close()
 
     def _meta_path(self) -> str:
         return os.path.join(self.path, ".meta")

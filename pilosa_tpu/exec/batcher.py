@@ -48,6 +48,18 @@ attribute their whole cost to the `batch_wait` profile phase; the
 leader's dispatch work self-attributes (`device_dispatch` et al.) inside
 the backend calls it makes on behalf of the batch.
 
+The plane's own profile (ISSUE 26): every `_drain`, on a leader's thread
+or a helper's, runs under a utils/qprofile.py PlaneProfile, which times
+the steps of each drain (take, group, plan, slots, dispatch,
+device_wait, readback, scatter, handoff; docs/observability.md says what
+each covers) into `batch_step_seconds{step=…}`, puts each on the
+profiler's host plane as `pilosa.drain.<step>`, and forwards to the
+leader's request profile. Beside them `batch_drains_total`,
+`batch_queue_wait_seconds` (a leg's wait from its append to the drain
+that takes it: `batch_wait` less this is the leg's own drain) and
+`batch_idle_seconds_total` (no thread was draining: the plane had
+nothing to launch).
+
 Mesh composition (ISSUE r13): when the backend carries a ShardMesh,
 every launch this plane coalesces — count_batch/vec_batch scans, the
 pair-stats sweep, BSI aggregates, TopN popcounts — runs under
@@ -82,7 +94,7 @@ import time
 from typing import Optional
 
 from pilosa_tpu.utils.locks import InstrumentedLock
-from pilosa_tpu.utils.qprofile import current_profile
+from pilosa_tpu.utils.qprofile import PlaneProfile, current_profile
 from pilosa_tpu.utils.stats import global_stats
 from pilosa_tpu.utils.threads import spawn
 
@@ -96,7 +108,7 @@ class _Leg:
     """One enqueued shard-leg: a typed descriptor plus its rendezvous."""
 
     __slots__ = ("kind", "index", "shards", "payload", "event", "result",
-                 "error", "explain", "explain_rec")
+                 "error", "explain", "explain_rec", "queued_at")
 
     def __init__(self, kind: str, index: str, shards, payload):
         self.kind = kind
@@ -106,6 +118,7 @@ class _Leg:
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
+        self.queued_at = 0.0  # perf_counter at _submit's append
         # EXPLAIN (ISSUE 16): the submitter's plan leg-sink, captured at
         # construction ON THE SUBMITTING THREAD so the leader can
         # attribute this leg's group record into the right plan. None
@@ -132,10 +145,16 @@ class ShardLegBatcher:
         self._lock = InstrumentedLock("batcher_drain")
         self._pending: list[_Leg] = []
         self._leader_active = False
+        # perf_counter at which leadership was last released (None until
+        # the first drain has ended): the next leader adds the stretch
+        # since to batch_idle_seconds_total. Guarded by _lock.
+        self._idle_since: Optional[float] = None
         self.stats = global_stats
-        # EXPLAIN group ids: process-unique per batcher, so two legs of
-        # one query showing the same id PROVES they shared a drain
-        # group (itertools.count: GIL-atomic, no lock).
+        # Drain numbers and EXPLAIN group ids, from one counter:
+        # process-unique per batcher, so two legs of one query showing
+        # the same group id PROVES they shared a drain group, and the
+        # spans of one drain share its number (itertools.count:
+        # GIL-atomic, no lock).
         self._group_ids = itertools.count(1)
 
     # -- public submit API (one method per leg kind) -----------------------
@@ -178,12 +197,18 @@ class ShardLegBatcher:
     # -- leader/follower drain ---------------------------------------------
 
     def _submit(self, leg: _Leg):
+        idle = None
         with self._lock:
+            leg.queued_at = time.perf_counter()
             self._pending.append(leg)
             am_leader = not self._leader_active
             if am_leader:
                 self._leader_active = True
+                if self._idle_since is not None:
+                    idle = leg.queued_at - self._idle_since
         if am_leader:
+            if idle is not None:
+                self.stats.count("batch_idle_seconds_total", idle)
             self._drain(leader_call=True)
         # Telemetry: a follower's whole cost is this wait (the leader's
         # dispatch work self-attributes inside the backend calls); for
@@ -202,58 +227,96 @@ class ShardLegBatcher:
         held open serving everyone else's batches (code review r4). The
         helper loops until the queue is empty; leadership is released
         under the lock, so a concurrent submitter either sees pending
-        work claimed or becomes the next leader itself — never neither."""
+        work claimed or becomes the next leader itself — never neither.
+
+        Every turn of the loop is one drain, under the plane's profile,
+        and passes through the same steps on either kind of thread:
+        `take`, what `_serve` and the backend open (`group` … `scatter`),
+        and `handoff` (is anything queued meanwhile, and who serves
+        it)."""
         if leader_call and self.window > 0:
             # Optional fixed coalescing window before the leader's first
             # (only) drain; helper threads never sleep — the device round
             # trip itself is their window.
             time.sleep(self.window)
-        while True:
-            with self._lock:
-                batch = self._pending
-                self._pending = []
-                if not batch:
-                    self._leader_active = False
-                    return
-            try:
-                self._serve(batch)
-            except BaseException:
-                # KeyboardInterrupt/SystemExit (or a bug in _serve): free
-                # the waiters — INCLUDING followers already queued behind
-                # this leadership, who would otherwise wait forever with
-                # no leader — and release leadership before propagating.
-                err = RuntimeError("shard-leg batch leader interrupted")
-                with self._lock:
-                    stranded = self._pending
-                    self._pending = []
-                    self._leader_active = False
-                for leg in batch + stranded:
-                    if not leg.event.is_set():
-                        leg.error = err
-                        leg.event.set()
-                raise
-            if leader_call:
-                with self._lock:
-                    if not self._pending:
-                        self._leader_active = False
+        with PlaneProfile(self.stats) as plane:
+            batch = None
+            while True:
+                plane.drain = next(self._group_ids)
+                with plane.phase("take"):
+                    if batch is None:
+                        # A thread's first turn. Never empty: a leader's
+                        # own leg is queued before it takes, and a helper
+                        # is started only where `handoff` saw legs.
+                        with self._lock:
+                            batch = self._pending
+                            self._pending = []
+                    self.stats.count("batch_drains_total")
+                    taken_at = time.perf_counter()
+                    for leg in batch:
+                        self.stats.timing(
+                            "batch_queue_wait_seconds",
+                            taken_at - leg.queued_at,
+                        )
+                try:
+                    self._serve(batch, plane)
+                except BaseException:
+                    # KeyboardInterrupt/SystemExit (or a bug in _serve):
+                    # free the waiters — INCLUDING followers already
+                    # queued behind this leadership, who would otherwise
+                    # wait forever with no leader — and release
+                    # leadership before propagating.
+                    err = RuntimeError("shard-leg batch leader interrupted")
+                    with self._lock:
+                        stranded = self._pending
+                        self._pending = []
+                        self._release_leadership()
+                    for leg in batch + stranded:
+                        if not leg.event.is_set():
+                            leg.error = err
+                            leg.event.set()
+                    raise
+                with plane.phase("handoff"):
+                    # One visit to the lock a turn, as before the plane
+                    # had a profile: sixteen submitters contend for it,
+                    # and a contended acquire costs the drain thread a
+                    # round of the interpreter lock. A helper that finds
+                    # legs takes them here and goes round again.
+                    with self._lock:
+                        batch = self._pending
+                        if not batch:
+                            self._release_leadership()
+                            return
+                        if not leader_call:
+                            self._pending = []
+                    if leader_call:
+                        spawn("batcher-leader", self._drain, args=(False,))
                         return
-                spawn("batcher-leader", self._drain, args=(False,))
-                return
+
+    def _release_leadership(self) -> None:
+        """Under _lock: no thread drains from here on, until a submitter
+        finds the flag down and takes it."""
+        self._leader_active = False
+        self._idle_since = time.perf_counter()
 
     # -- batch service ------------------------------------------------------
 
-    def _serve(self, batch: list[_Leg]) -> None:
+    def _serve(self, batch: list[_Leg], plane: PlaneProfile) -> None:
         """Group the drained window by (kind, index, shard set), dispatch
         every async-capable group BEFORE resolving any (XLA pipelines the
         device work past the readback round trips), then run the
         synchronous groups and scatter results back by leg."""
-        groups: dict[tuple, list[_Leg]] = {}
-        for leg in batch:
-            groups.setdefault((leg.kind, leg.index, leg.shards), []).append(leg)
+        with plane.phase("group"):
+            groups: dict[tuple, list[_Leg]] = {}
+            for leg in batch:
+                groups.setdefault(
+                    (leg.kind, leg.index, leg.shards), []
+                ).append(leg)
+            for (kind, _index, _shards), legs in groups.items():
+                self._observe_group(kind, legs, plane.drain)
         pending = []  # (legs, resolver) for async kinds
         sync_groups = []
         for (kind, index, shards), legs in groups.items():
-            self._observe_group(kind, legs)
             if kind == "count":
                 pending.append((legs, self._dispatch_count(index, shards, legs)))
             elif kind == "row":
@@ -278,7 +341,7 @@ class ShardLegBatcher:
                 )
                 self._resolve_individually(legs)
 
-    def _observe_group(self, kind: str, legs: list[_Leg]) -> None:
+    def _observe_group(self, kind: str, legs: list[_Leg], drain: int) -> None:
         st = self.stats.with_tags(f"kind:{kind}")
         st.count("batch_legs_total", len(legs))
         if len(legs) > 1:
@@ -295,6 +358,7 @@ class ShardLegBatcher:
                 if leg.explain is None:
                     continue
                 rec = {
+                    "drain": drain,
                     "group": gid,
                     "kind": kind,
                     "occupancy": occ,
@@ -321,12 +385,13 @@ class ShardLegBatcher:
 
         def resolve():
             values = resolver()
-            off = 0
-            for leg in legs:
-                n = len(leg.payload)
-                leg.result = [int(v) for v in values[off : off + n]]
-                off += n
-                leg.event.set()
+            with current_profile().phase("scatter"):
+                off = 0
+                for leg in legs:
+                    n = len(leg.payload)
+                    leg.result = [int(v) for v in values[off : off + n]]
+                    off += n
+                    leg.event.set()
 
         return resolve
 
@@ -346,9 +411,10 @@ class ShardLegBatcher:
 
         def resolve():
             rows = resolver()
-            for leg, row in zip(legs, rows):
-                leg.result = row
-                leg.event.set()
+            with current_profile().phase("scatter"):
+                for leg, row in zip(legs, rows):
+                    leg.result = row
+                    leg.event.set()
 
         return resolve
 
@@ -388,9 +454,10 @@ class ShardLegBatcher:
                     leg.error = e
                     leg.event.set()
                 continue
-            for leg in members:
-                leg.result = result
-                leg.event.set()
+            with current_profile().phase("scatter"):
+                for leg in members:
+                    leg.result = result
+                    leg.event.set()
 
     # -- error isolation ----------------------------------------------------
 

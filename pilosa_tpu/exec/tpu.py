@@ -38,6 +38,7 @@ large to ever fit fall back to the CPU oracle (SURVEY.md §7 hard part c).
 from __future__ import annotations
 
 import functools
+import math
 import threading
 import time
 from typing import Callable, Optional
@@ -92,7 +93,7 @@ from pilosa_tpu.pql.ast import (
 from pilosa_tpu.roaring import Bitmap
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils.locks import InstrumentedRLock
-from pilosa_tpu.utils.qprofile import NOP_PROFILE, current_profile
+from pilosa_tpu.utils.qprofile import current_profile
 from pilosa_tpu.utils.reuse import ReuseDistanceEstimator
 from pilosa_tpu.utils.stats import global_stats
 
@@ -284,6 +285,7 @@ class _StackedBlocks:
             self._refresh_args[key] = (field_obj, shards, view_name, min_rows)
 
         def build(stale):
+            t_build = time.perf_counter()
             if stale is not None and self.refresh_window_ms > 0:
                 # Freshness attribution under windowing: a stale entry
                 # refreshed by the background flusher is a coalesced
@@ -422,6 +424,13 @@ class _StackedBlocks:
                     )
                 else:
                     self._warm_mesh_splice(arr, rows_p)
+            # A full build only (not a hit, not a splice): host pack, the
+            # upload's enqueue and the splice warm-up. What the device
+            # still has queued when this returns is the first launch's
+            # wait.
+            global_stats.with_tags(f"field:{field_obj.name}").timing(
+                "stack_build_seconds", time.perf_counter() - t_build
+            )
             return arr, rows_p, vers, tiers
 
         return self._cached_build(key, fingerprint, build)
@@ -1207,8 +1216,9 @@ def _eval_spec(spec, blocks_it, scalars_it):
         block = next(blocks_it)  # [S, R, W]
         row = next(scalars_it)  # traced scalar
         mask = next(scalars_it)
-        slab = jnp.take(block, row, axis=1)  # [S, W]
-        return slab * mask  # mask=0 zeroes rows beyond the packed range
+        with jax.named_scope("row_gather"):
+            slab = jnp.take(block, row, axis=1)  # [S, W]
+            return slab * mask  # mask=0 zeroes rows beyond the packed range
     if tag == "T":
         # Time-range row: union of per-view row slabs (executor.go:1441).
         n_views = spec[2]
@@ -1217,8 +1227,9 @@ def _eval_spec(spec, blocks_it, scalars_it):
             block = next(blocks_it)
             row = next(scalars_it)
             mask = next(scalars_it)
-            slab = jnp.take(block, row, axis=1) * mask
-            acc = slab if acc is None else acc | slab
+            with jax.named_scope("row_gather"):
+                slab = jnp.take(block, row, axis=1) * mask
+                acc = slab if acc is None else acc | slab
         return acc
     if tag == "A":
         block = next(blocks_it)  # existence stack
@@ -1271,19 +1282,30 @@ def _eval_spec(spec, blocks_it, scalars_it):
     if tag == "S":
         inner = _eval_spec(spec[2], blocks_it, scalars_it)
         return _shift_slab(inner, spec[1])
-    children = spec[1]
-    acc = _eval_spec(children[0], blocks_it, scalars_it)
-    for ch in children[1:]:
-        v = _eval_spec(ch, blocks_it, scalars_it)
-        if tag == "U":
-            acc = acc | v
-        elif tag == "I":
-            acc = acc & v
-        elif tag == "D":
-            acc = acc & ~v
-        elif tag == "X":
-            acc = acc ^ v
-    return acc
+    # Operands first, in the order _build emitted them, then the verb
+    # under a scope of its own (scopes are metadata for whoever opens a
+    # trace; one inside another would read "verb/row_gather").
+    operands = [_eval_spec(ch, blocks_it, scalars_it) for ch in spec[1]]
+    with jax.named_scope("verb"):
+        acc = operands[0]
+        for v in operands[1:]:
+            if tag == "U":
+                acc = acc | v
+            elif tag == "I":
+                acc = acc & v
+            elif tag == "D":
+                acc = acc & ~v
+            elif tag == "X":
+                acc = acc ^ v
+        return acc
+
+
+def _named(kind: str, fn):
+    """`fn` as jax.jit is to see it: the lowered module, and with it the
+    profiler's `XLA Modules` line, is then called `jit_pilosa_<kind>`,
+    so a trace reduction can tell a count program from a TopN's."""
+    fn.__name__ = fn.__qualname__ = "pilosa_" + kind
+    return fn
 
 
 def _pred_bits(value: int, depth: int) -> np.ndarray:
@@ -1459,10 +1481,15 @@ class _ProgramLedger:
     def block_ready(self, x):
         """jax.block_until_ready + close this thread's parked launches
         into their entries' cumulative device seconds."""
+        t0 = time.perf_counter()
         jax.block_until_ready(x)
+        now = time.perf_counter()
+        # The one place a request waits for the device: what
+        # /debug/workload ranks shapes by (leaders only; a helper's plane
+        # profile drops it, by the one-payer rule).
+        current_profile().incr("device_wait_us", math.ceil((now - t0) * 1e6))
         pend = getattr(self._local, "pending", None)
         if pend:
-            now = time.perf_counter()
             with self._lock:
                 for sig, t0 in pend:
                     e = self._entries.get(sig)
@@ -1981,9 +2008,11 @@ class TPUBackend:
 
     # -- compiled programs -------------------------------------------------
 
-    def _wrap(self, body, extra_block: bool, out_specs):
-        """jit the body; under a mesh, run it per-device via shard_map with
-        psum collectives (out_specs describes the reduced outputs)."""
+    def _wrap(self, kind: str, body, extra_block: bool, out_specs):
+        """jit the body under its kind's name; under a mesh, run it
+        per-device via shard_map with psum collectives (out_specs
+        describes the reduced outputs)."""
+        body = _named(kind, body)
         if self.mesh is None:
             return jax.jit(body)
         ax = self.mesh.axis
@@ -2028,17 +2057,19 @@ class TPUBackend:
             )
             sig = ledger.record_launch(kind, key, args, wall, compiled, t0)
             prof = current_profile()
-            if prof is not NOP_PROFILE:
+            if prof.charges:
                 # ISSUE 18 satellite fix: stamp the cheap scalar totals
                 # into EVERY profiled request's counters — before this,
-                # per-launch device-wait only existed inside explain
-                # plans, so /debug/queries ring entries dropped it for
+                # per-launch totals only existed inside explain plans,
+                # so /debug/queries ring entries dropped them for
                 # normal traffic and the workload table would have
-                # needed ?explain=1 traffic to accumulate.
+                # needed ?explain=1 traffic to accumulate. `wall` is the
+                # asynchronous call's: dispatch, not device time (the
+                # wait is block_ready's, which stamps device_wait_us).
                 shipped = _tree_nbytes(args)
                 returned = _tree_nbytes(out)
                 prof.incr("device_launches")
-                prof.incr("device_wait_us", int(wall * 1e6))
+                prof.incr("dispatch_us", int(wall * 1e6))
                 prof.incr("bytes_shipped", shipped)
                 prof.incr("bytes_returned", returned)
                 ex = prof.explain
@@ -2080,22 +2111,27 @@ class TPUBackend:
 
             def body(blocks, scalars):
                 slab = _eval_spec(spec, iter(blocks), iter(scalars))
-                per_shard = jnp.sum(
-                    jax.lax.population_count(slab), axis=-1, dtype=jnp.uint32
-                )
+                with jax.named_scope("popcount"):
+                    per_shard = jnp.sum(
+                        jax.lax.population_count(slab), axis=-1,
+                        dtype=jnp.uint32,
+                    )
                 if reduce_dev:
-                    return self._psum(jnp.sum(per_shard, dtype=jnp.uint32))
+                    with jax.named_scope("shard_sum"):
+                        return self._psum(
+                            jnp.sum(per_shard, dtype=jnp.uint32)
+                        )
                 return per_shard
 
             out = (P() if reduce_dev else ax) if mesh is not None else None
-            fn = self._wrap(body, False, out)
+            fn = self._wrap(kind, body, False, out)
 
         elif kind == "vec":
 
             def body(blocks, scalars):
                 return _eval_spec(spec, iter(blocks), iter(scalars))
 
-            fn = self._wrap(body, False, ax)
+            fn = self._wrap(kind, body, False, ax)
 
         elif kind == "count_batch":
 
@@ -2111,16 +2147,20 @@ class TPUBackend:
                 def step(_, qs):
                     act = qs[-1]
                     slab = _eval_spec(spec, iter(blocks), iter(qs[:-1]))
-                    per_shard = masked_lane_counts(slab, act)
+                    with jax.named_scope("popcount"):
+                        per_shard = masked_lane_counts(slab, act)
                     if reduce_dev:
-                        return None, self._psum(jnp.sum(per_shard, dtype=jnp.uint32))
+                        with jax.named_scope("shard_sum"):
+                            return None, self._psum(
+                                jnp.sum(per_shard, dtype=jnp.uint32)
+                            )
                     return None, per_shard
 
                 _, out = jax.lax.scan(step, None, scalars)
                 return out  # [Q] or [Q, S]
 
             out = (P() if reduce_dev else P(None, mesh.axis if mesh else None)) if mesh is not None else None
-            fn = self._wrap(body, False, out)
+            fn = self._wrap(kind, body, False, out)
 
         elif kind == "vec_batch":
 
@@ -2138,7 +2178,7 @@ class TPUBackend:
                 return out  # [Q, S, W]
 
             out = P(None, mesh.axis) if mesh is not None else None
-            fn = self._wrap(body, False, out)
+            fn = self._wrap(kind, body, False, out)
 
         elif kind == "topn_plain":
 
@@ -2150,6 +2190,7 @@ class TPUBackend:
                     return self._psum(jnp.sum(per, axis=0, dtype=jnp.uint32))
                 return per
 
+            body = _named(kind, body)
             if mesh is not None:
                 fn = jax.jit(
                     shard_map(
@@ -2176,7 +2217,7 @@ class TPUBackend:
                 return per
 
             out = (P() if reduce_dev else ax) if mesh is not None else None
-            fn = self._wrap(body, True, out)
+            fn = self._wrap(kind, body, True, out)
 
         elif kind == "bsi_sum":
             depth = extra
@@ -2205,7 +2246,7 @@ class TPUBackend:
                 return self._psum(pos_c), self._psum(neg_c), self._psum(cnt)
 
             out = (P(), P(), P()) if mesh is not None else None
-            fn = self._wrap(body, True, out)
+            fn = self._wrap(kind, body, True, out)
 
         elif kind in ("bsi_min", "bsi_max"):
             depth = extra
@@ -2257,7 +2298,7 @@ class TPUBackend:
                 return bits_a, cnt_a, bits_b, cnt_b, branch_any, consider_any
 
             out = (ax, ax, ax, ax, ax, ax) if mesh is not None else None
-            fn = self._wrap(body, True, out)
+            fn = self._wrap(kind, body, True, out)
 
         else:
             raise ValueError(kind)
@@ -2343,9 +2384,7 @@ class TPUBackend:
             for s in shards:
                 out.merge(self.cpu.bitmap_call_shard(index, c, s))
             return out
-        with jax.profiler.TraceAnnotation("pilosa.bitmap_call"), prof.phase(
-            "device_dispatch"
-        ):
+        with prof.phase("dispatch", span="pilosa.bitmap_call"):
             slab = self._program("vec", spec, False)(blocks, scalars)
             # Subset requests gather on device first: reading the whole
             # [S_pad, W] slab back for one shard would move ~120 MB to
@@ -2353,12 +2392,13 @@ class TPUBackend:
             sub = len(positions) * 4 <= slab.shape[0]
             if sub:
                 slab = slab[jnp.asarray(positions, dtype=jnp.int32)]
+        with prof.phase("device_wait"):
             # Block HERE so device_dispatch carries the device round
             # trip and host_reduce is pure host-side work (ISSUE r14:
             # the phase table's post-collapse contract,
             # docs/observability.md).
             self.programs.block_ready(slab)
-        with prof.phase("host_reduce"):
+        with prof.phase("readback"):
             # Whole-slab vectorized materialization: one readback, one
             # unpackbits+flatnonzero pass, shard bases added vectorized
             # -> ONE sorted column array backing a lazy Row. Replaces
@@ -2389,17 +2429,16 @@ class TPUBackend:
             return sum(self.cpu.count_shard(index, c, s) for s in shards)
         s_pad = blocks[0].shape[0]
         reduce_dev = s_pad <= MAX_DEVICE_SUM_SHARDS
-        with jax.profiler.TraceAnnotation("pilosa.count"), prof.phase(
-            "device_dispatch"
-        ):
+        with prof.phase("dispatch", span="pilosa.count"):
             partials = self._program("count", spec, reduce_dev)(blocks, scalars)
+        with prof.phase("device_wait"):
             # Block HERE: device_dispatch carries the device round trip
             # (dispatch floor included), host_reduce only the host-side
             # arithmetic — the phase table's post-collapse contract
             # (ISSUE r14, docs/observability.md).
             self.programs.block_ready(partials)
         # Host sum in Python ints: exact for any shard count.
-        with prof.phase("host_reduce"):
+        with prof.phase("readback"):
             return int(np.asarray(partials, dtype=np.uint64).sum())
 
     def count_batch(self, index: str, calls: list[Call], shards: list[int]) -> list[int]:
@@ -2426,7 +2465,13 @@ class TPUBackend:
         if not calls:
             return lambda: []
         shards_t = tuple(shards)
-        plan = self._cached_pair_plan(index, calls)
+        batch = None
+        # One `plan` for the batch: the pair plan and, where there is
+        # none, the scan path's assembly.
+        with current_profile().phase("plan"):
+            plan = self._cached_pair_plan(index, calls)
+            if plan is None:
+                batch = self._assemble_batch(index, calls, shards_t)
         if plan is not None:
             try:
                 return self._pair_batch_dispatch(index, plan, shards_t)
@@ -2442,7 +2487,7 @@ class TPUBackend:
                 self._count_device_fallback(
                     "pair_stats", (len(calls), len(shards_t)), e
                 )
-        return self._generic_batch_dispatch(index, calls, shards_t)
+        return self._generic_batch_dispatch(index, calls, shards_t, batch)
 
     # -- pair-stats batch fast path (VERDICT r2 #1: row-reuse kernel) ------
 
@@ -2573,12 +2618,12 @@ class TPUBackend:
                     pair, cf, cg = pair_stats(fb, gb, interpret=interpret)
                     return jnp.concatenate([pair.ravel(), cf, cg])
 
-            fn = jax.jit(flat)
+            fn = jax.jit(_named("pair_stats", flat))
         elif pershard:
             mesh = self.mesh
             fn = jax.jit(
                 shard_map(
-                    flat,
+                    _named("pair_stats", flat),
                     mesh=mesh.mesh,
                     in_specs=(P(mesh.axis), P(mesh.axis)),
                     out_specs=P(mesh.axis),
@@ -2597,7 +2642,7 @@ class TPUBackend:
 
             fn = jax.jit(
                 shard_map(
-                    body,
+                    _named("pair_stats", body),
                     mesh=mesh.mesh,
                     in_specs=(P(mesh.axis), P(mesh.axis)),
                     out_specs=P(),
@@ -2753,9 +2798,7 @@ class TPUBackend:
         # batches and the single-flight waiters share this one sweep
         # instead of each missing until the first resolver lands.
         self.stats.count("pair_stats_sweeps_total")
-        with jax.profiler.TraceAnnotation("pilosa.pair_stats"), prof.phase(
-            "device_dispatch"
-        ):
+        with prof.phase("dispatch", span="pilosa.pair_stats"):
             flat = self._pair_program(pershard=pershard_ok)(fblock, gblock)
         # Shards whose fragments moved during the stack build/dispatch
         # record _VERS_STALE (see _confirm_vers): the swept content for
@@ -2979,7 +3022,11 @@ class TPUBackend:
     def _pair_fetch(self, entries, ent, rf, rg) -> list[int]:
         """Resolve stats (device array on first touch, host np after) and
         derive the batch's counts."""
-        with current_profile().phase("host_reduce"):
+        prof = current_profile()
+        if not isinstance(ent.stats, np.ndarray):
+            with prof.phase("device_wait"):
+                self.programs.block_ready(ent.stats)
+        with prof.phase("readback"):
             return self._pair_fetch_inner(entries, ent, rf, rg)
 
     def _pair_fetch_inner(self, entries, ent, rf, rg) -> list[int]:
@@ -3054,7 +3101,7 @@ class TPUBackend:
             return pair_stats(f, stacks[1], interpret=interpret)[0]
 
         if self.mesh is None:
-            fn = jax.jit(stats)
+            fn = jax.jit(_named("groupby", stats))
         else:
             mesh = self.mesh
 
@@ -3064,7 +3111,7 @@ class TPUBackend:
             n_in = n + (1 if filtered else 0)
             fn = jax.jit(
                 shard_map(
-                    body,
+                    _named("groupby", body),
                     mesh=mesh.mesh,
                     in_specs=(P(mesh.axis),) * n_in,
                     out_specs=P(),
@@ -3119,7 +3166,7 @@ class TPUBackend:
         kind = "group_tile_pershard" if pershard else "group_tile"
         t0 = time.perf_counter()
         if self.mesh is None:
-            fn = jax.jit(flat).lower(*avals).compile()
+            fn = jax.jit(_named(kind, flat)).lower(*avals).compile()
         else:
             mesh = self.mesh
             n_sharded = 2 + n_extra + (1 if filtered else 0)
@@ -3138,7 +3185,7 @@ class TPUBackend:
                 + ((P(mesh.axis),) if filtered else ())
             )
             mapped = shard_map(
-                body, mesh=mesh.mesh, in_specs=in_specs,
+                _named(kind, body), mesh=mesh.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False,
             )
             shard3 = NamedSharding(mesh.mesh, P(mesh.axis))
@@ -3461,7 +3508,7 @@ class TPUBackend:
             else:
                 hit = None
         if hit is None:
-            with jax.profiler.TraceAnnotation("pilosa.group_by"):
+            with current_profile().phase("dispatch", span="pilosa.group_by"):
                 if n >= 3:
                     try:
                         payload = self._group_tiled_sweep(stacks, filt, rs)
@@ -3842,7 +3889,7 @@ class TPUBackend:
             _slot_bucket(min(k_live, MAX_GROUP_TILE_SLOTS)) if k_live else 0
         )
         try:
-            with jax.profiler.TraceAnnotation("pilosa.groupn"):
+            with current_profile().phase("dispatch", span="pilosa.groupn"):
                 tiles = self._group_tiles(
                     stacks, None, combos, t_slots, pershard=True
                 )
@@ -4224,7 +4271,46 @@ class TPUBackend:
         out.append(active)
         return tuple(out)
 
-    def _generic_batch_dispatch(self, index, calls, shards_t):
+    @staticmethod
+    def _dedupe_slots(assembled: dict, idxs: list[int]):
+        """(unique, slot_of): the calls of `idxs` that differ in their
+        scalar bytes, in order, and each call's slot among them."""
+        slot_index: dict[tuple, int] = {}
+        unique: list[int] = []
+        slot_of: dict[int, int] = {}
+        for i in idxs:
+            k = tuple(
+                np.asarray(s, dtype=np.uint32).tobytes()
+                for s in assembled[i][1]
+            )
+            if k not in slot_index:
+                slot_index[k] = len(unique)
+                unique.append(i)
+            slot_of[i] = slot_index[k]
+        return unique, slot_of
+
+    def _assemble_batch(self, index, calls, shards_t):
+        """(groups, assembled, fallbacks): the calls that assemble,
+        grouped by (spec, leaf blocks) and each with its (blocks,
+        scalars), and the indices of those with no device lowering."""
+        groups: dict = {}
+        assembled: dict[int, tuple] = {}
+        fallbacks: list[int] = []
+        for i, c in enumerate(calls):
+            try:
+                spec, blocks, scalars = self._assemble(index, c, shards_t)
+            except _Unsupported:
+                fallbacks.append(i)
+                continue
+            # Blocks are cache-owned arrays, so identity keys the
+            # group: same spec shape with different views/fields means
+            # different block objects and must not share one dispatch.
+            key = (spec, tuple(id(b) for b in blocks))
+            groups.setdefault(key, []).append(i)
+            assembled[i] = (blocks, scalars)
+        return groups, assembled, fallbacks
+
+    def _generic_batch_dispatch(self, index, calls, shards_t, batch=None):
         """Group same-(spec, leaf-blocks) calls into fused scan dispatches:
         row ids become [Q] traced slot vectors, one program per group.
         Slot counts pad to a power-of-two bucket (_slot_bucket) so batch
@@ -4232,25 +4318,15 @@ class TPUBackend:
         batching — maps to O(log Q) compiled signatures instead of one
         XLA compile per occupancy; padded slots are lane-masked in-kernel
         and the `idxs` per-slot query-id vector scatters live results
-        back at resolve time."""
+        back at resolve time. `batch` is _assemble_batch's answer where
+        the caller's `plan` already has it (not after a pair sweep that
+        failed)."""
         prof = current_profile()
         results: list[Optional[int]] = [None] * len(calls)
-        groups: dict = {}
-        assembled: dict[int, tuple] = {}
-        fallbacks: list[int] = []
-        with prof.phase("plan"):
-            for i, c in enumerate(calls):
-                try:
-                    spec, blocks, scalars = self._assemble(index, c, shards_t)
-                except _Unsupported:
-                    fallbacks.append(i)
-                    continue
-                # Blocks are cache-owned arrays, so identity keys the
-                # group: same spec shape with different views/fields means
-                # different block objects and must not share one dispatch.
-                key = (spec, tuple(id(b) for b in blocks))
-                groups.setdefault(key, []).append(i)
-                assembled[i] = (blocks, scalars)
+        if batch is None:
+            with prof.phase("plan"):
+                batch = self._assemble_batch(index, calls, shards_t)
+        groups, assembled, fallbacks = batch
         pending = []
         for (spec, _bk), idxs in groups.items():
             blocks = assembled[idxs[0]][0]
@@ -4262,9 +4338,8 @@ class TPUBackend:
                 # SAME program over the same blocks (e.g. Count(All())
                 # repeated) — one fused count serves them all; a scan
                 # over a zero-leaf pytree has no query axis to scan.
-                with jax.profiler.TraceAnnotation(
-                    "pilosa.count_batch"
-                ), prof.phase("device_dispatch"):
+                with prof.phase("dispatch", span="pilosa.count_batch",
+                                legs=len(idxs), slots=1):
                     out = self._program("count", spec, reduce_dev)(blocks, ())
                 pending.append((idxs, out, None))
                 continue
@@ -4274,36 +4349,26 @@ class TPUBackend:
             # cost is O(slots) — Q must be the number of DISTINCT
             # queries, never the number of submitted legs (347 legs of a
             # 32-query pool used to scan 512 padded slots per launch).
-            slot_index: dict[tuple, int] = {}
-            unique: list[int] = []
-            slot_of: dict[int, int] = {}
-            for i in idxs:
-                k = tuple(
-                    np.asarray(s, dtype=np.uint32).tobytes()
-                    for s in assembled[i][1]
+            with prof.phase("slots"):
+                unique, slot_of = self._dedupe_slots(assembled, idxs)
+                qb = _slot_bucket(len(unique))
+                scalars = self._padded_slot_scalars(
+                    [assembled[i][1] for i in unique], qb
                 )
-                if k not in slot_index:
-                    slot_index[k] = len(unique)
-                    unique.append(i)
-                slot_of[i] = slot_index[k]
-            scalars = self._padded_slot_scalars(
-                [assembled[i][1] for i in unique], _slot_bucket(len(unique))
-            )
-            with jax.profiler.TraceAnnotation(
-                "pilosa.count_batch"
-            ), prof.phase("device_dispatch"):
+            with prof.phase("dispatch", span="pilosa.count_batch",
+                            legs=len(idxs), slots=qb):
                 out = self._program("count_batch", spec, reduce_dev)(blocks, scalars)
             pending.append((idxs, out, slot_of))
 
         def resolve() -> list[int]:
             prof_r = current_profile()
-            with prof_r.phase("device_dispatch"):
+            with prof_r.phase("device_wait"):
                 # The device wait belongs to the dispatch phase;
                 # host_reduce below is pure host arithmetic (ISSUE r14).
                 # Dispatches are already enqueued, so blocking here does
                 # not undo the callers' batch pipelining.
                 self.programs.block_ready([out for _, out, _ in pending])
-            with prof_r.phase("host_reduce"):
+            with prof_r.phase("readback"):
                 for idxs, out, slot_of in pending:
                     arr = np.asarray(out, dtype=np.uint64)
                     if slot_of is None:  # shared zero-scalar program
@@ -4352,19 +4417,10 @@ class TPUBackend:
             positions = list(range(len(shards)))
         prof = current_profile()
         results: list[Optional[Row]] = [None] * len(calls)
-        groups: dict = {}
-        assembled: dict[int, tuple] = {}
-        fallbacks: list[int] = []
         with prof.phase("plan"):
-            for i, c in enumerate(calls):
-                try:
-                    spec, blocks, scalars = self._assemble(index, c, shards_t)
-                except _Unsupported:
-                    fallbacks.append(i)
-                    continue
-                key = (spec, tuple(id(b) for b in blocks))
-                groups.setdefault(key, []).append(i)
-                assembled[i] = (blocks, scalars)
+            groups, assembled, fallbacks = self._assemble_batch(
+                index, calls, shards_t
+            )
         # (query ids, per-query slot, chunked device outputs, slots/chunk)
         pending: list[tuple] = []
         for (spec, _bk), idxs in groups.items():
@@ -4373,18 +4429,8 @@ class TPUBackend:
             # Slot dedupe by scalar bytes: the per-slot query-id mapping
             # (slot_of) scatters one computed slab to every leg that
             # asked for it.
-            slot_index: dict[tuple, int] = {}
-            unique: list[int] = []
-            slot_of: dict[int, int] = {}
-            for i in idxs:
-                k = tuple(
-                    np.asarray(s, dtype=np.uint32).tobytes()
-                    for s in assembled[i][1]
-                )
-                if k not in slot_index:
-                    slot_index[k] = len(unique)
-                    unique.append(i)
-                slot_of[i] = slot_index[k]
+            with prof.phase("slots"):
+                unique, slot_of = self._dedupe_slots(assembled, idxs)
             # Per-DEVICE slab bytes: the cap guards device memory, and
             # under a mesh the [Q, S, W] output is sharded over the
             # shard axis so each device holds only its 1/n chunk — a
@@ -4399,9 +4445,8 @@ class TPUBackend:
             per_chunk = max(1, MAX_ROW_BATCH_BYTES // slab_bytes)
             per_chunk = 1 << (per_chunk.bit_length() - 1)
             outs = []
-            with jax.profiler.TraceAnnotation("pilosa.row_batch"), prof.phase(
-                "device_dispatch"
-            ):
+            with prof.phase("dispatch", span="pilosa.row_batch",
+                            legs=len(idxs), slots=len(unique)):
                 for base in range(0, len(unique), per_chunk):
                     chunk = unique[base : base + per_chunk]
                     if len(chunk) == 1:
@@ -4430,7 +4475,7 @@ class TPUBackend:
 
         def resolve() -> list[Row]:
             prof_r = current_profile()
-            with prof_r.phase("device_dispatch"):
+            with prof_r.phase("device_wait"):
                 # The device wait belongs to the dispatch phase (the
                 # leader pays it once per launch); host_reduce below is
                 # pure host-side materialization (ISSUE r14).
@@ -4446,7 +4491,7 @@ class TPUBackend:
                         g.append(out)
                     self.programs.block_ready(g)
                     gathered.append(g)
-            with prof_r.phase("host_reduce"):
+            with prof_r.phase("readback"):
                 row_pos = list(range(len(positions))) if sub else positions
                 contiguous = row_pos == list(range(len(row_pos)))
                 sel = None if contiguous else np.asarray(
@@ -4589,7 +4634,7 @@ class TPUBackend:
         else:
             s_pad = block.shape[0]
             _, reduce_dev = self._topn_gates(s_pad, rp, src_call)
-            with jax.profiler.TraceAnnotation("pilosa.topn"):
+            with current_profile().phase("dispatch", span="pilosa.topn"):
                 if not src_call:
                     counts = self._program("topn_plain", None, reduce_dev)(block)
                 else:
@@ -4597,6 +4642,8 @@ class TPUBackend:
                     counts = self._program("topn_src", spec, reduce_dev)(
                         block, blocks, scalars
                     )
+            with current_profile().phase("device_wait"):
+                self.programs.block_ready(counts)
             counts = np.asarray(counts, dtype=np.uint64)
             if counts.ndim == 2:  # [S, R] per-shard partials
                 pershard = counts.astype(np.int64)
@@ -4750,7 +4797,7 @@ class TPUBackend:
             dev = self.blocks._put(host)
             global_stats.count("hbm_page_uploads_total")
             global_stats.count("hbm_page_bytes_total", host.nbytes)
-            with jax.profiler.TraceAnnotation("pilosa.topn_page"):
+            with current_profile().phase("dispatch", span="pilosa.topn_page"):
                 if src is None:
                     out = self._program("topn_plain", None, reduce_dev)(dev)
                 else:
@@ -4758,7 +4805,9 @@ class TPUBackend:
                     out = self._program("topn_src", spec, reduce_dev)(
                         dev, blocks, scalars
                     )
-            arr = np.asarray(out, dtype=np.uint64)  # readback completes page
+            with current_profile().phase("device_wait"):
+                self.programs.block_ready(out)  # the page is complete
+            arr = np.asarray(out, dtype=np.uint64)
             dev = None  # release before the next upload: 1 page in flight
             if arr.ndim == 2:
                 arr = arr.sum(axis=0)
@@ -4830,13 +4879,13 @@ class TPUBackend:
             self._host_path("bsi", "shard axis past the device-sum bound")
             return None
         depth = opts.bit_depth
-        with jax.profiler.TraceAnnotation("pilosa.bsi_sum"), prof.phase(
-            "device_dispatch"
-        ):
+        with prof.phase("dispatch", span="pilosa.bsi_sum"):
             pos_c, neg_c, cnt = self._program(
                 "bsi_sum", spec, True, extra=depth
             )(bsi_block, blocks, scalars)
-        with prof.phase("host_reduce"):
+        with prof.phase("device_wait"):
+            self.programs.block_ready((pos_c, neg_c, cnt))
+        with prof.phase("readback"):
             pos_c = np.asarray(pos_c, dtype=np.uint64)
             neg_c = np.asarray(neg_c, dtype=np.uint64)
             total = sum(
@@ -5037,14 +5086,15 @@ class TPUBackend:
             self._host_path("bsi", "shard axis past the device-sum bound")
             return None
         depth = opts.bit_depth
-        with jax.profiler.TraceAnnotation("pilosa." + kind), prof.phase(
-            "device_dispatch"
-        ):
+        with prof.phase("dispatch", span="pilosa." + kind):
+            outs = self._program(kind, spec, True, extra=depth)(
+                bsi_block, blocks, scalars
+            )
+        with prof.phase("device_wait"):
+            self.programs.block_ready(outs)
+        with prof.phase("readback"):
             bits_a, cnt_a, bits_b, cnt_b, branch_any, consider_any = (
-                np.asarray(x)
-                for x in self._program(kind, spec, True, extra=depth)(
-                    bsi_block, blocks, scalars
-                )
+                np.asarray(x) for x in outs
             )
 
         def assemble_max(bits) -> int:  # maxUnsigned decision bits

@@ -15,6 +15,14 @@ from pilosa_tpu.ops.runtime import configure_compile_cache
 # persistent cache is in use at the process's first compile.
 configure_compile_cache()
 
+# A process that runs device programs puts its phases on the profiler's
+# host plane, on the device planes' clock (utils/qprofile.py `phase`).
+import jax.profiler  # noqa: E402
+
+from pilosa_tpu.utils import qprofile  # noqa: E402
+
+qprofile.set_span_factory(jax.profiler.TraceAnnotation)
+
 from pilosa_tpu.ops.blocks import WORDS_PER_SHARD, pack_fragment  # noqa: E402
 from pilosa_tpu.ops.kernels import (  # noqa: E402
     MAX_PAIR_SHARDS,

@@ -289,6 +289,11 @@ class RuntimeMonitor:
         # hysteresis, so the ladder never flaps on sub-minute blips.
         self._derate_level = 0
         self._seen_indexes: set[str] = set()
+        # Stop-the-world pauses of the collector, (generation, seconds),
+        # stamped by _on_gc and moved to runtime_gc_pause_seconds by the
+        # poll thread's tick.
+        self._gc_started: Optional[float] = None
+        self._gc_pauses: list[tuple[int, float]] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -551,9 +556,29 @@ class RuntimeMonitor:
                 tagged.remove_gauge("index_available_shards")
             self._seen_indexes = current
 
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """gc.callbacks hook. It runs inside the collector, on whichever
+        thread tripped it, and that thread may hold the stats registry's
+        lock at that moment (any allocation can trip a collection): so
+        it only stamps and appends, and _flush_gc_pauses observes."""
+        if phase == "start":
+            self._gc_started = time.perf_counter()
+        elif self._gc_started is not None and len(self._gc_pauses) < 65536:
+            self._gc_pauses.append(
+                (info["generation"], time.perf_counter() - self._gc_started)
+            )
+
+    def _flush_gc_pauses(self) -> None:
+        pauses, self._gc_pauses = self._gc_pauses, []
+        for generation, seconds in pauses:
+            global_stats.with_tags(f"generation:{generation}").timing(
+                "runtime_gc_pause_seconds", seconds
+            )
+
     def start(self) -> "RuntimeMonitor":
         from pilosa_tpu.utils.threads import spawn
 
+        gc.callbacks.append(self._on_gc)
         self._thread = spawn("monitor-poll", self._run)
         return self
 
@@ -566,6 +591,7 @@ class RuntimeMonitor:
         while not self._stop.wait(tick):
             try:
                 global_flight_recorder.sample()
+                self._flush_gc_pauses()
                 now = time.monotonic()
                 if now >= next_poll:
                     next_poll = now + self.interval
@@ -578,6 +604,9 @@ class RuntimeMonitor:
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=5)
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self._flush_gc_pauses()
 
 
 def _dist_version(name: str) -> Optional[str]:

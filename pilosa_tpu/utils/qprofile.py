@@ -15,9 +15,20 @@ work (`plan`/`device_dispatch`/`host_reduce`) exactly once per launch,
 so summing `query_phase_seconds{phase=device_dispatch}` over a window
 yields the PER-BATCH launch cost while `phase=batch_wait` carries the
 per-query experience — shared device work has exactly one payer per
-dispatch, never one per coalesced query. Helper-thread drains run with
-no active profile (NOP sink); their launches stay visible through
-`device_launches_total{kind=…}` and the `batch_occupancy` histogram.
+dispatch, never one per coalesced query. Whichever thread drains, leader
+or detached helper, runs under the plane's own profile (PlaneProfile):
+it times every step of the drain into `batch_step_seconds{step=…}` and
+forwards to the leader's request profile where there is one, so a
+helper's launches have a series too.
+
+`phase()` is the one span primitive (ISSUE 26): it times, and for a
+working phase it also opens a profiler span (`jax.profiler.
+TraceAnnotation`, installed by pilosa_tpu.ops where the process has
+imported jax), so that every phase lands on the host plane of a profiler
+trace, on the device planes' clock. The span is inert unless a profiler
+session is on. A phase in which the thread only waits (WAITING_PHASES)
+carries none: sixteen waiting threads would cover every idle gap of the
+device and name none.
 
 Three export surfaces (all fed from profile_scope.__exit__):
 - tagged histograms on /metrics: query_phase_seconds{call=...,phase=...}
@@ -36,7 +47,7 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import Callable, Optional
 
 #: Canonical phase order for display; profiles may carry others (they
 #: sort after these in summaries). "other" is derived, never recorded:
@@ -54,8 +65,63 @@ PHASES = (
     "resp_write",
 )
 
+#: Phases in which the thread does nothing but wait for another's work:
+#: timed like the rest, never put on the profiler's host plane.
+WAITING_PHASES = frozenset({"batch_wait"})
+
+#: The steps of a drain (exec/batcher.py; docs/observability.md lists
+#: what each covers), in the order a drain passes through them. The
+#: backend's batched entry points open the middle five under these names;
+#: a request profile files each under the coarser phase /metrics has
+#: always carried (None: under none, it stays in `other`).
+DRAIN_STEPS = (
+    "take", "group", "plan", "slots", "dispatch", "device_wait",
+    "readback", "scatter", "handoff",
+)
+_STEP_PHASE = {
+    "take": None, "group": None, "slots": None, "scatter": None,
+    "handoff": None,
+    "dispatch": "device_dispatch", "device_wait": "device_dispatch",
+    "readback": "host_reduce",
+}
+
+#: Span names coarser than their phases: a trace reduction names a gap of
+#: the device by the ONE name that covers more than half of it, summed
+#: over threads, so the short phases a request passes through before and
+#: after the batcher share a name each.
+_REQUEST_PRE = "pilosa.request.pre"
+_REQUEST_POST = "pilosa.request.post"
+_SPAN_NAMES = {
+    "parse": _REQUEST_PRE, "plan": _REQUEST_PRE,
+    "key_translate": _REQUEST_PRE,
+    "host_reduce": _REQUEST_POST, "readback": _REQUEST_POST,
+    "serialize": _REQUEST_POST, "resp_write": _REQUEST_POST,
+}
+
 _qid_counter = itertools.count(1)
 _local = threading.local()
+
+#: jax.profiler.TraceAnnotation where the process has imported jax
+#: (pilosa_tpu/ops/__init__.py installs it); a host-path server
+#: (`--executor cpu`) never does, and its phases only time. A provider
+#: hook for the reason utils/stats.py has one: this module must import
+#: without jax.
+_span_factory: Optional[Callable] = None
+
+
+def set_span_factory(factory: Optional[Callable]) -> None:
+    global _span_factory
+    _span_factory = factory
+
+
+def _span(phase: str, name: Optional[str], meta: dict):
+    """The profiler span of one phase, or None for a waiting phase and
+    for a process without jax."""
+    if _span_factory is None or phase in WAITING_PHASES:
+        return None
+    return _span_factory(
+        name or _SPAN_NAMES.get(phase) or "pilosa." + phase, **meta
+    )
 
 
 def cache_state(counters: Optional[dict]) -> Optional[str]:
@@ -126,18 +192,24 @@ class ExplainPlan:
 
 
 class _PhaseTimer:
-    __slots__ = ("profile", "name", "t0")
+    __slots__ = ("profile", "name", "span", "t0")
 
-    def __init__(self, profile: "QueryProfile", name: str):
+    def __init__(self, profile: "QueryProfile", name: Optional[str], span):
         self.profile = profile
         self.name = name
+        self.span = span
 
     def __enter__(self):
+        if self.span is not None:
+            self.span.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.profile.add_phase(self.name, time.perf_counter() - self.t0)
+        if self.name is not None:
+            self.profile.add_phase(self.name, time.perf_counter() - self.t0)
+        if self.span is not None:
+            self.span.__exit__(*exc)
 
 
 class QueryProfile:
@@ -149,6 +221,9 @@ class QueryProfile:
         "phases", "counters", "error", "duration", "remote",
         "explain", "shards", "shape",
     )
+    #: A request pays for what this thread does: per-request counters
+    #: (bytes shipped, launches) are worth working out.
+    charges = True
 
     def __init__(self, index: str = "", query: str = "", call: str = ""):
         self.qid = next(_qid_counter)
@@ -181,8 +256,15 @@ class QueryProfile:
         # executor after parse; the workload table's aggregation key.
         self.shape: Optional[str] = None
 
-    def phase(self, name: str) -> _PhaseTimer:
-        return _PhaseTimer(self, name)
+    def phase(self, name: str, span: Optional[str] = None,
+              **meta) -> _PhaseTimer:
+        """Time one phase and put it on the profiler's host plane, as
+        `span` where the caller names it (a dispatch keeps its kind's
+        name), with `meta` as the span's metadata. A drain step's name
+        files under the phase it has always belonged to."""
+        return _PhaseTimer(
+            self, _STEP_PHASE.get(name, name), _span(name, span, meta)
+        )
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phases[name] = self.phases.get(name, 0.0) + seconds
@@ -277,9 +359,12 @@ class NopProfile:
     explain = None
     shards = None
     shape = None
+    charges = False
 
-    def phase(self, name: str):
-        return self._PHASE
+    def phase(self, name: str, span: Optional[str] = None, **meta):
+        # Unprofiled work (prewarm threads, direct backend calls) still
+        # shows in a trace: the span alone.
+        return _span(name, span, meta) or self._PHASE
 
     def add_phase(self, name: str, seconds: float) -> None:
         pass
@@ -289,6 +374,102 @@ class NopProfile:
 
 
 NOP_PROFILE = NopProfile()
+
+
+class _StepTimer:
+    __slots__ = ("plane", "step", "span")
+
+    def __init__(self, plane: "PlaneProfile", step: str, span):
+        self.plane = plane
+        self.step = step
+        self.span = span
+
+    def __enter__(self):
+        self.plane._open = True
+        if self.span is not None:
+            self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        self.plane._lap(self.step)
+
+
+class PlaneProfile(NopProfile):
+    """The batching plane's own profile (exec/batcher.py runs each
+    `_drain` inside `with PlaneProfile(stats)`, on whichever thread
+    drains: a leader's request thread or a detached helper).
+
+    Its phases are the STEPS of a drain, kept as laps: a step runs from
+    the end of the one before it (from the activation, for the first) to
+    its own end, so the steps of a drain add up to its wall with nothing
+    between them, and the few bytecodes between two `with` blocks belong
+    to the step they prepare. Each step is observed as
+    `batch_step_seconds{step=…}` with the thread's CPU time over the same
+    stretch in `batch_step_cpu_seconds_total{step=…}` (less CPU than wall
+    is the thread waiting: for the device in `device_wait`, for the
+    interpreter lock or a lock anywhere else), carries a profiler span
+    `pilosa.drain.<step>` with the drain's number as metadata (a
+    dispatch keeps its kind's span name, `pilosa.count_batch`, …), and is
+    forwarded to the leader's request profile under the phase it has
+    always had there, so `query_phase_seconds` keeps one payer per
+    dispatch. A helper has no leader; what it would forward is dropped."""
+
+    def __init__(self, stats):
+        self.stats = stats
+        self.leader: Optional[QueryProfile] = None
+        #: The number of the drain being served: what its spans share.
+        self.drain = 0
+        self._open = False
+        self._wall = time.perf_counter()
+        self._cpu = time.thread_time()
+
+    def __enter__(self) -> "PlaneProfile":
+        """Active on this thread, over the request profile it carries (a
+        leader's; none on a helper), which is restored on exit."""
+        self.leader = getattr(_local, "profile", None)
+        _local.profile = self
+        return self
+
+    def __exit__(self, *exc):
+        _local.profile = self.leader
+        return False
+
+    @property
+    def explain(self):
+        return self.leader.explain if self.leader is not None else None
+
+    @property
+    def charges(self) -> bool:
+        return self.leader is not None
+
+    def phase(self, name: str, span: Optional[str] = None, **meta):
+        if self._open:
+            # Inside an open step: the step covers the stretch and its
+            # span names it; only the leader's phase table hears more.
+            if self.leader is None:
+                return self._PHASE
+            return _PhaseTimer(self.leader, _STEP_PHASE.get(name, name), None)
+        return _StepTimer(self, name, _span(
+            name, span or "pilosa.drain." + name, dict(meta, drain=self.drain)
+        ))
+
+    def _lap(self, step: str) -> None:
+        wall, cpu = time.perf_counter(), time.thread_time()
+        d_wall, d_cpu = wall - self._wall, cpu - self._cpu
+        self._wall, self._cpu = wall, cpu
+        self._open = False
+        st = self.stats.with_tags(f"step:{step}")
+        st.timing("batch_step_seconds", d_wall)
+        st.count("batch_step_cpu_seconds_total", d_cpu)
+        name = _STEP_PHASE.get(step, step)
+        if self.leader is not None and name is not None:
+            self.leader.add_phase(name, d_wall)
+
+    def incr(self, name: str, value: int = 1) -> None:
+        if self.leader is not None:
+            self.leader.incr(name, value)
 
 
 def current_profile():

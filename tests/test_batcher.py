@@ -21,7 +21,9 @@ import numpy as np
 import pytest
 
 from pilosa_tpu.exec.batcher import CountBatcher, ShardLegBatcher
-from pilosa_tpu.utils.stats import global_stats
+from pilosa_tpu.utils import qprofile
+from pilosa_tpu.utils.qprofile import DRAIN_STEPS, current_profile, profile_scope
+from pilosa_tpu.utils.stats import StatsClient, global_stats
 
 
 class StubBackend:
@@ -401,3 +403,229 @@ class TestBatchedDifferential:
             assert result_to_json(ex.execute("i", q)[0]) == result_to_json(
                 oracle.execute("i", q)[0]
             ), q
+
+
+# -- the plane's own profile (ISSUE 26) ----------------------------------
+
+class SteppedBackend:
+    """count_batch_async that opens the backend's steps as exec/tpu.py
+    does (plan, slots, dispatch; device_wait, readback in the resolver),
+    each `step_s` long, and can hold a dispatch at a gate."""
+
+    def __init__(self, step_s=0.0):
+        self.step_s = step_s
+        self.gates = []  # one threading.Event per dispatch to hold, in order
+        self.entered = threading.Semaphore(0)
+
+    def count_batch_async(self, index, calls, shards):
+        prof = current_profile()
+        for step in ("plan", "slots"):
+            with prof.phase(step):
+                time.sleep(self.step_s)
+        with prof.phase("dispatch", span="pilosa.count_batch",
+                        legs=len(calls), slots=len(calls)):
+            self.entered.release()
+            if self.gates:
+                assert self.gates.pop(0).wait(10)
+            time.sleep(self.step_s)
+
+        def resolve():
+            prof_r = current_profile()
+            for step in ("device_wait", "readback"):
+                with prof_r.phase(step):
+                    time.sleep(self.step_s)
+            return [c * 10 for c in calls]
+
+        return resolve
+
+
+class _RecordingSpan:
+    """Stands in for jax.profiler.TraceAnnotation: what was opened, on
+    which thread, with what metadata."""
+
+    log: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    def __enter__(self):
+        self.log.append((threading.current_thread().name, self.name, self.meta))
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+@pytest.fixture
+def spans():
+    before = qprofile._span_factory
+    _RecordingSpan.log = []
+    qprofile.set_span_factory(_RecordingSpan)
+    yield _RecordingSpan.log
+    qprofile.set_span_factory(before)
+
+
+def _plane_batcher(step_s=0.0):
+    be = SteppedBackend(step_s)
+    b = ShardLegBatcher(be)
+    b.stats = StatsClient()  # a registry of this test's own
+    return be, b
+
+
+def _step_table(stats, family="batch_step_seconds"):
+    return {
+        name.split('"')[1]: v
+        for name, v in stats.timing_totals(family).items()
+    }
+
+
+def _leader_then_helper(be, b, when_leading=lambda: None):
+    """One leg served by its own submitter and a second, queued while the
+    first's dispatch is held at a gate, served by a helper thread."""
+    gate = threading.Event()
+    be.gates.append(gate)
+    out = {}
+    t1 = threading.Thread(
+        target=lambda: out.setdefault(1, b.count("i", [1], [0])), name="client-1"
+    )
+    t1.start()
+    assert be.entered.acquire(timeout=10)  # the leader is in its dispatch
+    when_leading()
+    t2 = threading.Thread(
+        target=lambda: out.setdefault(2, b.count("i", [2], [0])), name="client-2"
+    )
+    t2.start()
+    while True:  # the second leg is queued behind the leadership flag
+        with b._lock:
+            if b._pending:
+                break
+        time.sleep(0.001)
+    gate.set()
+    for t in (t1, t2):
+        t.join(10)
+        assert not t.is_alive()
+    assert out == {1: [10], 2: [20]}
+
+
+class TestPlaneProfile:
+    def test_helper_drain_observes_every_step_once_in_order(self, spans):
+        be, b = _plane_batcher()
+        _leader_then_helper(be, b)
+        helper = [
+            (name, meta) for thread, name, meta in spans
+            if thread.startswith("batcher-leader")
+        ]
+        want = [
+            "pilosa.count_batch" if s == "dispatch" else "pilosa.drain." + s
+            for s in DRAIN_STEPS
+        ]
+        assert [name for name, _ in helper] == want
+        # One drain, one number on every span of it, and not the leader's.
+        drains = {meta["drain"] for _, meta in helper}
+        assert len(drains) == 1
+        leader = [meta["drain"] for t, n, meta in spans if t == "client-1"]
+        assert len(set(leader)) == 1 and set(leader) != drains
+        assert [n for t, n, _ in spans if t == "client-1"] == want
+        dispatch = dict(helper)["pilosa.count_batch"]
+        assert dispatch["legs"] == 1 and dispatch["slots"] == 1
+        # Both drains, every step: one observation each.
+        steps = _step_table(b.stats)
+        assert set(steps) == set(DRAIN_STEPS)
+        assert all(n == 2 for _, n in steps.values())
+        assert b.stats.counter_totals("batch_drains_total") == {
+            "batch_drains_total": 2.0
+        }
+
+    def test_waiting_phase_carries_no_span(self, spans):
+        be, b = _plane_batcher()
+        with profile_scope(index="i", query="q", call="Count"):
+            assert b.count("i", [1], [0]) == [10]
+        assert not [n for _, n, _ in spans if "batch_wait" in n]
+
+    def test_steps_of_a_drain_sum_to_its_wall(self):
+        be, b = _plane_batcher(step_s=0.004)
+        walls = []
+        drain = b._drain
+
+        def timed(leader_call):
+            t0 = time.perf_counter()
+            try:
+                drain(leader_call)
+            finally:
+                walls.append(time.perf_counter() - t0)
+
+        b._drain = timed
+        for k in range(5):
+            assert b.count("i", [k], [0]) == [k * 10]
+        steps = _step_table(b.stats)
+        stepped = sum(total for total, _ in steps.values())
+        assert len(walls) == 5 and sum(walls) > 5 * 5 * 0.004
+        # Laps: nothing lies between two steps, so what is missing is
+        # only the activation around the first and after the last.
+        assert stepped <= sum(walls)
+        assert sum(walls) - stepped < 0.002 * len(walls)
+        # The thread's CPU time beside each: a step that sleeps uses none.
+        cpu = {
+            n.split('"')[1]: v for n, v in
+            b.stats.counter_totals("batch_step_cpu_seconds_total").items()
+        }
+        assert set(cpu) == set(steps)
+        assert sum(cpu.values()) < 0.5 * stepped
+
+    def test_queue_wait_one_observation_a_leg(self):
+        be, b = _plane_batcher()
+        for k in range(4):
+            b.count("i", [k], [0])
+        (total, n), = b.stats.timing_totals("batch_queue_wait_seconds").values()
+        assert n == 4
+        assert total < 4 * 0.001  # an uncontended leader takes its own leg at once
+        be2, b2 = _plane_batcher()
+        _leader_then_helper(be2, b2)
+        (total2, n2), = b2.stats.timing_totals("batch_queue_wait_seconds").values()
+        assert n2 == 2
+        assert total2 > total / 4  # the queued leg waited out the gate
+
+    def test_idle_does_not_grow_while_a_helper_loops(self):
+        be, b = _plane_batcher()
+
+        def idle():
+            return b.stats.counter_totals("batch_idle_seconds_total").get(
+                "batch_idle_seconds_total", 0.0
+            )
+
+        b.count("i", [0], [0])  # the first leader: nothing to add yet
+        assert idle() == 0.0
+        time.sleep(0.05)
+        # The plane stood idle for those 50 ms, and the next leader says
+        # so as it takes the flag ...
+        be.step_s = 0.02
+        seen = []
+        _leader_then_helper(be, b, when_leading=lambda: seen.append(idle()))
+        assert seen[0] > 0.04
+        # ... while the stretch in which the helper served (5 steps of 20
+        # ms at least) added nothing: leadership was never released.
+        assert idle() == seen[0]
+        assert _step_table(b.stats)["device_wait"][0] > 0.035
+        time.sleep(0.03)
+        b.count("i", [3], [0])
+        assert idle() - seen[0] > 0.025
+
+    def test_leader_phases_keep_their_meaning(self):
+        """The leader's request profile hears of the shared work under
+        the names /metrics has always had: plan, device_dispatch (the
+        dispatch and the device wait), host_reduce (the readback), and
+        nothing under a step's name. A follower's is its batch_wait."""
+        be, b = _plane_batcher(step_s=0.01)
+        with profile_scope(index="i", query="q", call="Count") as prof:
+            b.count("i", [1], [0])
+            assert current_profile() is prof
+        assert set(prof.phases) == {
+            "plan", "device_dispatch", "host_reduce", "batch_wait"
+        }
+        assert prof.phases["device_dispatch"] == pytest.approx(0.02, abs=0.01)
+        assert prof.phases["plan"] == pytest.approx(0.01, abs=0.008)
+        assert prof.phases["host_reduce"] == pytest.approx(0.01, abs=0.008)
+        steps = _step_table(b.stats)
+        assert steps["dispatch"][0] + steps["device_wait"][0] == pytest.approx(
+            prof.phases["device_dispatch"]
+        )

@@ -145,3 +145,32 @@ def test_upload_programs_compile_pinned_to_a_mesh_device(v5e):
     sparse._zeros_prog(dev, n_pad)
     sparse._place_prog(dev, n_pad)
     sparse._final_prog(dev, n_pad, (SHARDS // 4 + 1, ROWS, WORDS_PER_SHARD))
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+def test_count_batch_scan_carries_its_kind(v5e, on_chip, meshed):
+    """The benchmark's count program (a 3-row verb, four query slots) at
+    the deployment's shape: it compiles for the chip with its scopes, and
+    the module is named for its kind, which is how a trace reduction
+    tells a count program from any other (ISSUE 26)."""
+    if meshed:
+        mesh = ShardMesh(v5e)
+        be = TPUBackend(on_chip, mesh=mesh)
+        place = NamedSharding(mesh.mesh, P(mesh.axis))
+        scalar = NamedSharding(mesh.mesh, P())
+        shards = be.blocks._pad_shards(SHARDS)
+    else:
+        be = TPUBackend(on_chip, device=v5e[0])
+        place = scalar = SingleDeviceSharding(v5e[0])
+        shards = SHARDS
+    spec = ("I", (("R", "f"), ("R", "g"), ("R", "h")))
+    blocks = (_stack(place, shards=shards),) * 3
+    slots = jax.ShapeDtypeStruct((4,), jnp.uint32, sharding=scalar)
+    scalars = (slots,) * 7  # a row id and a mask for each leaf, the lane mask
+    program = be._program("count_batch", spec, True).__wrapped__
+    lowered = program.lower(blocks, scalars)
+    assert "jit_pilosa_count_batch" in lowered.as_text()[:400]
+    text = lowered.compile().as_text()
+    assert "HloModule jit_pilosa_count_batch" in text
+    for scope in ("row_gather", "verb", "popcount", "shard_sum"):
+        assert scope in text, scope

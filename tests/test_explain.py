@@ -94,6 +94,10 @@ class TestExplainOptIn:
         setup_index(server)
         post_query(server, "Row(f=1)")
         post_query(server, "Count(Row(f=1))", suffix="?explain=1")
+        # A profile enters the ring one GIL slice after its reply has
+        # reached this client: wait for that, or an earlier test's entry
+        # of the same query text is the newest one seen.
+        assert server.quiesce(timeout=5.0)
         recent = get_json(server, "/debug/queries")["recent"]
         # The ring is process-global and newest-first: keep the newest
         # entry per query so earlier tests' entries don't shadow ours.

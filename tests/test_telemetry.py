@@ -614,3 +614,144 @@ class TestBenchCaptureProof:
         # 4 new queries costing 2 ms total -> 0.5 ms mean, not the
         # cumulative 1.002/14.
         assert means["parse"] == pytest.approx(0.5)
+
+
+class TestDrainAndSetupTelemetry:
+    """ISSUE 26: the plane's steps through the real backend, the program
+    names a trace carries, a device wait that is one, and the set-up and
+    stop-the-world counters."""
+
+    @pytest.fixture
+    def served(self, tmp_path):
+        tpu = pytest.importorskip(
+            "pilosa_tpu.exec.tpu",
+            reason="device backend needs jax.shard_map",
+            exc_type=ImportError,
+        )
+        from pilosa_tpu.exec.batcher import ShardLegBatcher
+
+        holder = Holder(str(tmp_path / "data")).open()
+        idx = holder.create_index("i")
+        for name in ("f", "g", "h"):
+            idx.create_field(name)
+        be = tpu.TPUBackend(holder)
+        ex = Executor(holder, backend=be)
+        ex.batcher = ShardLegBatcher(be)
+        ex.execute("i", "Set(10, f=1) Set(10, g=2) Set(10, h=1) Set(1048577, f=1)")
+        yield holder, be, ex
+        holder.close()
+
+    @staticmethod
+    def _steps():
+        return {
+            name.split('"')[1]: v
+            for name, v in global_stats.timing_totals("batch_step_seconds").items()
+        }
+
+    def test_count_drain_steps_and_request_phases(self, served):
+        holder, be, ex = served
+        q = "Count(Intersect(Row(f=1), Row(g=2), Row(h=1)))"
+        before = self._steps()
+        with profile_scope(index="i", query=q, call="Count") as prof:
+            assert ex.execute("i", q) == [1]
+        grew = {
+            s for s, (_, n) in self._steps().items()
+            if n > before.get(s, (0.0, 0))[1]
+        }
+        from pilosa_tpu.utils.qprofile import DRAIN_STEPS
+
+        assert grew == set(DRAIN_STEPS)
+        # The leader's own table: the names /metrics has always had.
+        assert {"plan", "device_dispatch", "host_reduce", "batch_wait"} <= set(
+            prof.phases
+        )
+        assert not set(prof.phases) & {
+            "take", "group", "slots", "dispatch", "device_wait", "readback",
+            "scatter", "handoff",
+        }
+        # The asynchronous call's wall is dispatch; the wait is block_ready's.
+        assert prof.counters["device_launches"] == 1
+        assert prof.counters["dispatch_us"] > 0
+        assert prof.counters["device_wait_us"] > 0
+
+    def test_lowered_count_batch_module_is_named_for_its_kind(self, served):
+        from pilosa_tpu.pql import parse_string
+
+        holder, be, ex = served
+        call = parse_string("Intersect(Row(f=1), Row(g=2), Row(h=1))").calls[0]
+        spec, blocks, scalars = be._assemble("i", call, (0, 1))
+        slots = be._padded_slot_scalars([scalars, scalars], 2)
+        program = be._program("count_batch", spec, True)
+        text = program.__wrapped__.lower(blocks, slots).as_text()
+        assert "jit_pilosa_count_batch" in text[:400]
+        one = be._program("count", spec, True).__wrapped__.lower(blocks, scalars)
+        assert "jit_pilosa_count" in one.as_text()[:400]
+
+    def test_stack_build_and_holder_open_close_series(self, served, tmp_path):
+        holder, be, ex = served
+        builds0 = global_stats.timing_totals("stack_build_seconds")
+        ex.execute("i", "Count(Intersect(Row(f=1), Row(g=2), Row(h=1)))")
+        ex.execute("i", "Count(Intersect(Row(f=1), Row(g=2), Row(h=1)))")
+        builds = global_stats.timing_totals("stack_build_seconds")
+        for name in ("f", "g", "h"):  # one full build each; the second query hits
+            key = f'stack_build_seconds{{field="{name}"}}'
+            assert builds[key][1] - builds0.get(key, (0, 0))[1] == 1
+        closes0 = global_stats.timing_totals("holder_close_seconds")
+        holder.close()
+        closes = {
+            n.split('"')[1]: v[1] - closes0.get(n, (0, 0))[1]
+            for n, v in global_stats.timing_totals("holder_close_seconds").items()
+        }
+        frags = sum(
+            len(v.fragments) for f in holder.index("i").fields.values()
+            for v in f.views.values()
+        )
+        assert frags >= 4
+        for step in ("snapshot_wait", "cache_flush", "wal_drain",
+                     "block_epochs", "file_close"):
+            assert closes[step] == frags, (step, closes)
+        assert closes["attr_stores"] >= 4  # every field's, and the index's
+        again = Holder(str(tmp_path / "data")).open()
+        try:
+            assert global_stats.gauge_value("holder_fragments_opened") == frags
+            assert global_stats.gauge_value("holder_open_seconds") > 0
+        finally:
+            again.close()
+
+    def test_graceful_stop_logs_where_the_seconds_went(self, served):
+        from pilosa_tpu import cli
+
+        holder, be, ex = served
+        lines = []
+
+        class Log:
+            def printf(self, fmt, *args):
+                lines.append(fmt % args)
+
+        cli._close_holder(holder, Log())
+        (line,) = lines
+        assert line.startswith("holder closed in ")
+        for step in ("snapshot_wait=", "cache_flush=", "wal_drain=",
+                     "block_epochs=", "file_close=", "attr_stores="):
+            assert step in line, line
+
+    def test_gc_pauses_are_observed_by_generation(self):
+        import gc
+
+        from pilosa_tpu.utils.monitor import RuntimeMonitor
+
+        key = 'runtime_gc_pause_seconds{generation="2"}'
+        n0 = global_stats.timing_totals("runtime_gc_pause_seconds").get(
+            key, (0.0, 0)
+        )[1]
+        mon = RuntimeMonitor()
+        gc.callbacks.append(mon._on_gc)
+        try:
+            gc.collect()  # a full collection: generation 2
+        finally:
+            gc.callbacks.remove(mon._on_gc)
+        # Stamped inside the collector, observed outside it.
+        assert [g for g, _ in mon._gc_pauses].count(2) == 1
+        mon._flush_gc_pauses()
+        assert global_stats.timing_totals("runtime_gc_pause_seconds")[key][1] == n0 + 1
+        assert mon._gc_pauses == []

@@ -42,7 +42,7 @@ METRIC_SUFFIXES = (
     "_inflight", "_up", "_fds", "_threads", "_nodes", "_fields",
     "_shards", "_evictions", "_rederives", "_state",
     "_occupancy", "_queries", "_ops", "_entries",
-    "_programs", "_live", "_heat", "_depth",
+    "_programs", "_live", "_heat", "_depth", "_opened",
 )
 
 _CALL_RE = re.compile(
@@ -166,6 +166,11 @@ ALLOWED_TAG_KEYS = {
     "le",      # histogram bucket bound (static BUCKET_BOUNDS)
     "site",    # instrumented-lock site name (utils/locks call sites)
     "program", # device-program ledger kind (program kinds are finite)
+    "step",    # a step of a drain (utils/qprofile.py DRAIN_STEPS plus the
+               # phases a drain can pass through) or of Fragment.close()
+               # (five literals + attr_stores): literals at the call
+               # sites, never request content
+    "generation",  # garbage-collector generation (0, 1, 2)
     "shape",   # canonical-PQL shape fingerprint (pql/ast.py shape_key:
                # structure only — call vocabulary x schema field names;
                # literals never survive into the key)
