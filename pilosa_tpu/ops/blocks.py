@@ -3,8 +3,17 @@
 Layout: one fragment (view ∩ shard) becomes uint32[rows_padded, WORDS]
 where WORDS = SHARD_WIDTH/32 (32768 for the default 2^20 shard width, i.e.
 128 KiB per row). uint32 is the TPU-native word (int64 is emulated on
-TPU); rows are padded to a multiple of 8 to satisfy float32-class tile
-shapes (8x128 VPU lanes; a 32768-word row is 256 full lanes).
+TPU); rows are padded to a multiple of 8.
+
+The host packs and unpacks rows flat, [..., WORDS]. On the device the word
+axis is split in two, [..., WORD_LINES, WORD_LANES] (256 x 128 at the
+default width): the TPU tiles an array's two minor axes in (8, 128), and
+with the words alone on those axes a row of a shard is WORD_LINES/8 whole
+tiles, 128 KiB contiguous, which a program reads in place. With the flat
+[shards, rows, WORDS] shape the ROW axis was the tile's sublane axis: every
+4 KiB tile held 512 B of each of 8 rows, and a program that wanted one row
+first copied it out of the whole stack (ISSUE 27). The two shapes are the
+same bytes in the same order, so tile_words / flat_words are views.
 
 Packing walks roaring containers directly: a container key maps to
 (row, word-range) and its 1024 uint64 words view as 2048 little-endian
@@ -25,6 +34,28 @@ _CONTAINERS_PER_ROW = SHARD_WIDTH >> 16
 _WORDS_PER_CONTAINER = (1 << 16) // 32  # 2048
 
 ROW_PAD = 8
+
+#: The word axis as the device holds it (see the module docstring). A shard
+#: row is at least one 2^16-bit container, 2048 words, so the lanes divide it.
+WORD_LANES = 128
+WORD_LINES = WORDS_PER_SHARD // WORD_LANES
+
+
+def stack_shape(n_shards: int, n_rows: int) -> tuple[int, int, int, int]:
+    """Device shape of a stack of n_shards x n_rows shard rows."""
+    return (n_shards, n_rows, WORD_LINES, WORD_LANES)
+
+
+def tile_words(words):
+    """[..., W] -> [..., W/128, 128]: flat rows as the device holds them
+    (a view of a contiguous host array)."""
+    return words.reshape(words.shape[:-1] + (-1, WORD_LANES))
+
+
+def flat_words(words):
+    """[..., W/128, 128] -> [..., W]: device rows as the host's unpackers
+    take them."""
+    return words.reshape(words.shape[:-2] + (-1,))
 
 
 def _padded_rows(n_rows: int) -> int:

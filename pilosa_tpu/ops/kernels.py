@@ -20,12 +20,16 @@ and the host derives any verb in O(1) per query:
 
 Each stack byte is read exactly once per batch — the row-reuse roofline —
 vs bytes x queries for the naive loop. Measured on v5e at the 1B-column
-bench shape (954 shards, 8 rows/field): 1.65 ms per sweep vs 2.73 ms for
-the equivalent fused-XLA broadcast and ~64 GB of re-gathered traffic for
-the per-query loop. The kernel tiles [1, R, WT] blocks of both stacks
-through VMEM over a (shards, word-tiles) grid, accumulating all three
+bench shape (954 shards, 8 rows/field): 2.8 ms per sweep (chip, PR 27; an
+earlier 1.65 ms was read against 2.73 ms for the equivalent fused-XLA
+broadcast) and ~64 GB of re-gathered traffic for
+the per-query loop. The kernel tiles [1, R, LT, 128] blocks of both stacks
+through VMEM over a (shards, word-line-tiles) grid, accumulating all three
 stats in VMEM across grid steps (dimension_semantics=arbitrary keeps the
-accumulator resident).
+accumulator resident). Stacks are uint32[S, R, L, 128], the words of a
+shard row as L lines of 128 (ops/blocks.py): a row of a block is whole
+(8, 128) vregs, so the [Rf, Rg] broadcast runs over leading axes and
+never across sublanes.
 
 Counts accumulate in int32: a (row-pair, shard) popcount is <= 2^20, so
 the sweep is exact while S*2^20 < 2^31, i.e. up to MAX_PAIR_SHARDS
@@ -47,10 +51,22 @@ from jax.experimental.pallas import tpu as pltpu
 # int32 accumulator bound: MAX_PAIR_SHARDS * 2^20 < 2^31.
 MAX_PAIR_SHARDS = 2047
 
-# VMEM budget for the broadcast intermediate [Rf, Rg, WT] (int32) — half
-# of the 16 MiB VMEM, leaving headroom for double-buffered input tiles
-# and the accumulator blocks.
+# VMEM budget for the broadcast intermediate [Rf, Rg, LT, 128] (int32) —
+# half of the 16 MiB VMEM, leaving headroom for double-buffered input
+# tiles and the accumulator blocks.
 _VMEM_TILE_BYTES = 8 * 1024 * 1024
+
+#: Sublanes of a vreg: the fewest word lines a block may hold (Mosaic
+#: wants a block's second-minor dim a multiple of it, or the whole axis).
+_MIN_LINES = 8
+
+
+def _word_counts(x):
+    """Sum an int32[..., LT, 128] popcount block over both word axes, lines
+    first (whole-vreg adds), then lanes. One joint (-2, -1) reduce aborts
+    Mosaic's layout pass (jax 0.9.0) and a flattening reshape relays the
+    block out in VMEM: 6.64 ms a sweep against 2.82 (chip, PR 27)."""
+    return jnp.sum(jnp.sum(x, axis=-2), axis=-1)
 
 
 def _sequential_grid(n_axes: int):
@@ -74,38 +90,43 @@ def _pair_stats_kernel(f_ref, g_ref, pair_ref, cf_ref, cg_ref):
         cf_ref[...] = jnp.zeros_like(cf_ref)
         cg_ref[...] = jnp.zeros_like(cg_ref)
 
-    f = f_ref[0]  # [Rf, WT]
-    g = g_ref[0]  # [Rg, WT]
-    pc = jax.lax.population_count(f[:, None, :] & g[None, :, :]).astype(jnp.int32)
-    pair_ref[...] += jnp.sum(pc, axis=-1)
-    cf_ref[...] += jnp.sum(jax.lax.population_count(f).astype(jnp.int32), axis=-1)
-    cg_ref[...] += jnp.sum(jax.lax.population_count(g).astype(jnp.int32), axis=-1)
+    f = f_ref[0]  # [Rf, LT, 128]
+    g = g_ref[0]  # [Rg, LT, 128]
+    pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
+    pair_ref[...] += _word_counts(pc)
+    cf_ref[...] += _word_counts(jax.lax.population_count(f).astype(jnp.int32))
+    cg_ref[...] += _word_counts(jax.lax.population_count(g).astype(jnp.int32))
 
 
-def _word_tile(rf: int, rg: int, words: int) -> int:
-    wt = words
-    while rf * rg * wt * 4 > _VMEM_TILE_BYTES and wt % 2 == 0:
-        wt //= 2
-    return wt
+def _line_tile(resident_rows: int, lines: int, lanes: int) -> int:
+    """Word lines a block: the largest halving of `lines` whose
+    resident_rows x LT x lanes int32 working set fits the VMEM budget."""
+    lt = lines
+    while (
+        resident_rows * lt * lanes * 4 > _VMEM_TILE_BYTES
+        and lt % (2 * _MIN_LINES) == 0
+    ):
+        lt //= 2
+    return lt
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pair_stats(f_stack, g_stack, interpret: bool = False):
-    """(uint32[S, Rf, W], uint32[S, Rg, W]) ->
+    """(uint32[S, Rf, L, 128], uint32[S, Rg, L, 128]) ->
     (pair int32[Rf, Rg], cf int32[Rf], cg int32[Rg]).
 
     Single-device form; the mesh path shard_maps this over the shard axis
     and psums the partials (see TPUBackend._pair_program).
     """
-    s, rf, w = f_stack.shape
+    s, rf, lines, lanes = f_stack.shape
     rg = g_stack.shape[1]
-    wt = _word_tile(rf, rg, w)
+    lt = _line_tile(rf * rg, lines, lanes)
     return pl.pallas_call(
         _pair_stats_kernel,
-        grid=(s, w // wt),
+        grid=(s, lines // lt),
         in_specs=[
-            pl.BlockSpec((1, rf, wt), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, rg, wt), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, rf, lt, lanes), lambda i, j: (i, 0, j, 0)),
+            pl.BlockSpec((1, rg, lt, lanes), lambda i, j: (i, 0, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((rf, rg), lambda i, j: (0, 0)),
@@ -131,18 +152,18 @@ def _pair_stats_pershard_kernel(f_ref, g_ref, pair_ref, cf_ref, cg_ref):
         cf_ref[...] = jnp.zeros_like(cf_ref)
         cg_ref[...] = jnp.zeros_like(cg_ref)
 
-    f = f_ref[0]  # [Rf, WT]
-    g = g_ref[0]  # [Rg, WT]
-    pc = jax.lax.population_count(f[:, None, :] & g[None, :, :]).astype(jnp.int32)
-    pair_ref[0] += jnp.sum(pc, axis=-1)
-    cf_ref[0, 0] += jnp.sum(jax.lax.population_count(f).astype(jnp.int32), axis=-1)
-    cg_ref[0, 0] += jnp.sum(jax.lax.population_count(g).astype(jnp.int32), axis=-1)
+    f = f_ref[0]  # [Rf, LT, 128]
+    g = g_ref[0]  # [Rg, LT, 128]
+    pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
+    pair_ref[0] += _word_counts(pc)
+    cf_ref[0, 0] += _word_counts(jax.lax.population_count(f).astype(jnp.int32))
+    cg_ref[0, 0] += _word_counts(jax.lax.population_count(g).astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pair_stats_pershard(f_stack, g_stack, interpret: bool = False):
     """pair_stats WITHOUT the shard reduction:
-    (uint32[S, Rf, W], uint32[S, Rg, W]) ->
+    (uint32[S, Rf, L, 128], uint32[S, Rg, L, 128]) ->
     (pair int32[S, Rf, Rg], cf int32[S, 1, Rf], cg int32[S, 1, Rg]).
 
     The per-shard table is what makes write churn cheap: the host keeps
@@ -153,18 +174,18 @@ def pair_stats_pershard(f_stack, g_stack, interpret: bool = False):
     (cache.go:136-301) applied to the pair matrix. Per-shard counts are
     <= 2^20 so int32 is exact for ANY shard count (the summed kernel's
     MAX_PAIR_SHARDS bound applies only to device-side totals)."""
-    s, rf, w = f_stack.shape
+    s, rf, lines, lanes = f_stack.shape
     rg = g_stack.shape[1]
-    wt = _word_tile(rf, rg, w)
+    lt = _line_tile(rf * rg, lines, lanes)
     return pl.pallas_call(
         _pair_stats_pershard_kernel,
         # Shards outermost: each shard's output blocks see their word-tile
         # visits consecutively, so the VMEM accumulator carries across w
         # and flushes once per shard.
-        grid=(s, w // wt),
+        grid=(s, lines // lt),
         in_specs=[
-            pl.BlockSpec((1, rf, wt), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, rg, wt), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, rf, lt, lanes), lambda i, j: (i, 0, j, 0)),
+            pl.BlockSpec((1, rg, lt, lanes), lambda i, j: (i, 0, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, rf, rg), lambda i, j: (i, 0, 0)),
@@ -216,17 +237,15 @@ def _make_nary_kernel(n_extra: int, extra_rows: tuple, filtered: bool):
         rem = k
         for t in range(n_extra - 1, -1, -1):
             rh = extra_rows[t]
-            row = h_refs[t][0, rem % rh]  # [WT]
+            row = h_refs[t][0, rem % rh]  # [LT, 128]
             rem = rem // rh
             m = row if m is None else (m & row)
         if filtered:
             m = m & filt_ref[0, 0]
-        f = f_ref[0] & m[None, :]
+        f = f_ref[0] & m[None]
         g = g_ref[0]
-        pc = jax.lax.population_count(
-            f[:, None, :] & g[None, :, :]
-        ).astype(jnp.int32)
-        pair_ref[0] += jnp.sum(pc, axis=-1)
+        pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
+        pair_ref[0] += _word_counts(pc)
 
     return kernel
 
@@ -244,8 +263,9 @@ def nary_stats(f_stack, g_stack, extras, filt=None, interpret: bool = False):
     """The whole N-field GroupBy tensor in ONE sweep (VERDICT r3 #4 —
     removes the 3-field cliff):
 
-    (uint32[S, Rf, W], uint32[S, Rg, W], (uint32[S, Rh1, W], ...)
-    [, uint32[S, W]]) -> int32[K, Rf, Rg] with K = prod(Rhi) and
+    (uint32[S, Rf, L, 128], uint32[S, Rg, L, 128],
+    (uint32[S, Rh1, L, 128], ...) [, uint32[S, L, 128]])
+    -> int32[K, Rf, Rg] with K = prod(Rhi) and
     out[k, a, b] = popcount(F_a & G_b & H1_{k1} & ... & Hm_{km} [& filt])
     where k = odometer over (k1..km), LAST extra field fastest.
 
@@ -254,34 +274,29 @@ def nary_stats(f_stack, g_stack, extras, filt=None, interpret: bool = False):
     masked pair sweeps (each its own dispatch round trip). f/g tiles are
     re-read per k — the same HBM traffic the separate sweeps paid.
     Accumulator bound: same MAX_PAIR_SHARDS int32 argument."""
-    s, rf, w = f_stack.shape
+    s, rf, lines, lanes = f_stack.shape
     rg = g_stack.shape[1]
     extra_rows = tuple(h.shape[1] for h in extras)
     k_total = 1
     for rh in extra_rows:
         k_total *= rh
-    # Tile budget must cover the [rf,rg,wt] broadcast AND every extra
+    # Tile budget must cover the [rf,rg,lt,128] broadcast AND every extra
     # field's full-rows block that stays VMEM-resident.
-    wt = w
-    while (rf * rg + sum(extra_rows)) * wt * 4 > _VMEM_TILE_BYTES and wt % 2 == 0:
-        wt //= 2
-    in_specs = [
-        pl.BlockSpec((1, rf, wt), lambda k, i, j: (i, 0, j)),
-        pl.BlockSpec((1, rg, wt), lambda k, i, j: (i, 0, j)),
-    ] + [
-        pl.BlockSpec((1, rh, wt), lambda k, i, j: (i, 0, j))
-        for rh in extra_rows
-    ]
+    lt = _line_tile(rf * rg + sum(extra_rows), lines, lanes)
+    row_block = lambda r: pl.BlockSpec(  # noqa: E731
+        (1, r, lt, lanes), lambda k, i, j: (i, 0, j, 0)
+    )
+    in_specs = [row_block(r) for r in (rf, rg, *extra_rows)]
     operands = [f_stack, g_stack, *extras]
     if filt is not None:
-        in_specs.append(pl.BlockSpec((1, 1, wt), lambda k, i, j: (i, 0, j)))
-        operands.append(filt[:, None, :])  # singleton row axis (Mosaic)
+        in_specs.append(row_block(1))
+        operands.append(filt[:, None])  # a stack of one row
     kernel = _make_nary_kernel(len(extras), extra_rows, filt is not None)
     return pl.pallas_call(
         kernel,
         # k outermost; shard + word-tile reduction dims innermost (see
         # kernel comment — accumulator-visit contiguity).
-        grid=(k_total, s, w // wt),
+        grid=(k_total, s, lines // lt),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rf, rg), lambda k, i, j: (k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k_total, rf, rg), jnp.int32),
@@ -310,15 +325,13 @@ def _make_nary_pershard_kernel(n_extra: int, extra_rows: tuple):
         rem = pl.program_id(0)
         for t in range(n_extra - 1, -1, -1):
             rh = extra_rows[t]
-            row = h_refs[t][0, rem % rh]  # [WT]
+            row = h_refs[t][0, rem % rh]  # [LT, 128]
             rem = rem // rh
             m = row if m is None else (m & row)
-        f = f_ref[0] & m[None, :]
+        f = f_ref[0] & m[None]
         g = g_ref[0]
-        pc = jax.lax.population_count(
-            f[:, None, :] & g[None, :, :]
-        ).astype(jnp.int32)
-        pair_ref[0, 0] += jnp.sum(pc, axis=-1)
+        pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
+        pair_ref[0, 0] += _word_counts(pc)
 
     return kernel
 
@@ -333,26 +346,21 @@ def nary_stats_pershard(f_stack, g_stack, extras, interpret: bool = False):
     its int64 sum over shards, and a write epoch that dirtied D shards
     replaces D rows instead of re-sweeping the stacks — the same design
     as pair_stats_pershard for the 2-field case."""
-    s, rf, w = f_stack.shape
+    s, rf, lines, lanes = f_stack.shape
     rg = g_stack.shape[1]
     extra_rows = tuple(h.shape[1] for h in extras)
     k_total = 1
     for rh in extra_rows:
         k_total *= rh
-    wt = w
-    while (rf * rg + sum(extra_rows)) * wt * 4 > _VMEM_TILE_BYTES and wt % 2 == 0:
-        wt //= 2
+    lt = _line_tile(rf * rg + sum(extra_rows), lines, lanes)
     in_specs = [
-        pl.BlockSpec((1, rf, wt), lambda k, i, j: (i, 0, j)),
-        pl.BlockSpec((1, rg, wt), lambda k, i, j: (i, 0, j)),
-    ] + [
-        pl.BlockSpec((1, rh, wt), lambda k, i, j: (i, 0, j))
-        for rh in extra_rows
+        pl.BlockSpec((1, r, lt, lanes), lambda k, i, j: (i, 0, j, 0))
+        for r in (rf, rg, *extra_rows)
     ]
     kernel = _make_nary_pershard_kernel(len(extras), extra_rows)
     return pl.pallas_call(
         kernel,
-        grid=(k_total, s, w // wt),
+        grid=(k_total, s, lines // lt),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, rf, rg), lambda k, i, j: (k, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k_total, s, rf, rg), jnp.int32),
@@ -463,8 +471,8 @@ def expand_run_spans(acc, lo, hi, nnz):
 def splice_shard_slabs(block, slabs, idx, valid):
     """Splice dirty shard slabs into one device's local stack block.
 
-    block: uint32[S_local, R, W] — this device's shard slabs.
-    slabs: uint32[C, R, W] — replacement slabs for this device (padding
+    block: uint32[S_local, R, L, 128] — this device's shard slabs.
+    slabs: uint32[C, R, L, 128] — replacement slabs for this device (padding
         entries are ignored via `valid`).
     idx: int32[C] — LOCAL shard positions (0..S_local-1) each slab
         lands at; padding entries may hold any in-range value.
@@ -504,12 +512,19 @@ def mask_lane_slab(slab, active):
     return slab & (jnp.uint32(0) - active)
 
 
+def slab_counts(slab):
+    """Popcounts of slabs, summed over both word axes:
+    uint32[S, ..., L, 128] -> uint32[S, ...]."""
+    return jnp.sum(
+        jax.lax.population_count(slab), axis=(-2, -1), dtype=jnp.uint32
+    )
+
+
 def masked_lane_counts(slab, active):
     """Per-shard popcounts of one slot's slab with inactive lanes zeroed:
-    uint32[S, W], uint32 0/1 -> uint32[S]. The count-batch scan body uses
-    this so a padded slot contributes exactly 0 to any reduction."""
-    per = jnp.sum(jax.lax.population_count(slab), axis=-1, dtype=jnp.uint32)
-    return per * active
+    uint32[S, L, 128], uint32 0/1 -> uint32[S]. The count-batch scan body
+    uses this so a padded slot contributes exactly 0 to any reduction."""
+    return slab_counts(slab) * active
 
 
 # ---------------------------------------------------------------------------
@@ -523,13 +538,13 @@ def masked_lane_counts(slab, active):
 # empty rows, cut the live product into tiles, and launch each tile
 # through the same program with zero recompiles. Fused-XLA formulation
 # (precedent: pair_stats_xla; on v5e the fused pair sweep measured
-# 2.73 ms vs 1.65 ms Pallas — an acceptable trade for a traced-operand
+# 2.73 ms vs 1.65 ms Pallas then — an acceptable trade for a traced-operand
 # program, and on CPU hosts it avoids interpret-mode Pallas entirely,
 # which walks the (K, S, W) grid in Python).
 # ---------------------------------------------------------------------------
 
 #: Shard-axis chunk for the tile programs' inner reduction scan. The
-#: [SB, Rf, Rg, WT] popcount broadcast must stay small enough for the
+#: [SB, Rf, Rg, L, 128] popcount broadcast must stay small enough for the
 #: backend's vector units to fuse well: measured on the 1-core CPU host
 #: at the bench shape, SB=6 sweeps in 2.8 s where SB=12 falls off a
 #: vectorization cliff to 37 s. Shard counts that don't divide evenly
@@ -543,15 +558,15 @@ def _tile_chunk_counts(fm, g_stack, pershard: bool):
     keeps vector-shaped outputs at every step (sum the word axis first,
     then shards) — a joint multi-axis reduce lowers catastrophically on
     XLA CPU."""
-    s, rf, w = fm.shape
+    s, rf = fm.shape[:2]
     rg = g_stack.shape[1]
     sb = min(s, GROUP_TILE_SHARD_CHUNK)
 
     def pc_block(fc, gc):
         pc = jax.lax.population_count(
-            fc[:, :, None, :] & gc[:, None, :, :]
+            fc[:, :, None] & gc[:, None]
         ).astype(jnp.int32)
-        return jnp.sum(pc, axis=3)  # [sb, Rf, Rg]
+        return jnp.sum(pc, axis=(3, 4))  # [sb, Rf, Rg]
 
     n_chunks = s // sb
     if pershard:
@@ -592,13 +607,13 @@ def _group_tile(f_stack, g_stack, extras, rows_idx, active, filt, pershard):
         m = None
         for t, h in enumerate(extras):
             row = jax.lax.dynamic_index_in_dim(h, idx[t], axis=1, keepdims=False)
-            m = row if m is None else (m & row)  # [S, W]
+            m = row if m is None else (m & row)  # [S, L, 128]
         if filt is not None:
             m = m & filt
         # Padded slots replay slot 0's rows; the lane mask zeroes their
         # slab so they contribute exactly 0 to every cell.
         m = mask_lane_slab(m, act)
-        fm = f_stack & m[:, None, :]
+        fm = f_stack & m[:, None]
         return carry, _tile_chunk_counts(fm, g_stack, pershard)
 
     _, out = jax.lax.scan(slot, None, (rows_idx, active))
@@ -608,8 +623,9 @@ def _group_tile(f_stack, g_stack, extras, rows_idx, active, filt, pershard):
 def group_tile_stats(f_stack, g_stack, extras, rows_idx, active, filt=None):
     """One tile of the N-field group tensor, slot-indexed:
 
-    (uint32[S, Rf, W], uint32[S, Rg, W], (uint32[S, Rh1, W], ...),
-    int32[T, E], uint32[T] [, uint32[S, W]]) -> int32[T, Rf, Rg] with
+    (uint32[S, Rf, L, 128], uint32[S, Rg, L, 128],
+    (uint32[S, Rh1, L, 128], ...), int32[T, E], uint32[T]
+    [, uint32[S, L, 128]]) -> int32[T, Rf, Rg] with
     out[q, a, b] = popcount(F_a & G_b & H1_{rows_idx[q,0]} & ... [& filt])
     for active[q] == 1, exactly 0 for padded slots.
 
@@ -633,13 +649,13 @@ def pair_stats_xla(f_stack, g_stack):
     as the differential oracle for the Pallas kernel and as the fallback
     where Pallas/Mosaic is unavailable)."""
     pc = jax.lax.population_count(
-        f_stack[:, :, None, :] & g_stack[:, None, :, :]
+        f_stack[:, :, None] & g_stack[:, None]
     ).astype(jnp.int32)
-    pair = jnp.sum(pc, axis=(0, 3))
+    pair = jnp.sum(pc, axis=(0, 3, 4))
     cf = jnp.sum(
-        jax.lax.population_count(f_stack).astype(jnp.int32), axis=(0, 2)
+        jax.lax.population_count(f_stack).astype(jnp.int32), axis=(0, 2, 3)
     )
     cg = jnp.sum(
-        jax.lax.population_count(g_stack).astype(jnp.int32), axis=(0, 2)
+        jax.lax.population_count(g_stack).astype(jnp.int32), axis=(0, 2, 3)
     )
     return pair, cf, cg

@@ -1,6 +1,6 @@
 """Packed wire formats for cold stack uploads (VERDICT r4 #1, ISSUE r7).
 
-Dense uint32[S, R, W] is the right DEVICE layout for the sweep programs
+Dense uint32[S, R, W/128, 128] is the right DEVICE layout for the sweep programs
 but a wasteful WIRE format: at the bench shape the h-field stack ships
 1 GB of which >80% of words are zero, and the host->HBM upload sits on
 the 3-field GroupBy cold path. Which tiers earn their place on a locally

@@ -14,10 +14,17 @@ from pilosa_tpu.core.field import options_for_int
 from pilosa_tpu.exec import Executor
 from pilosa_tpu.exec.result import result_to_json
 from pilosa_tpu.exec.tpu import TPUBackend
-from pilosa_tpu.ops.blocks import WORDS_PER_SHARD, pack_fragment, pack_row, unpack_row
+from pilosa_tpu.ops.blocks import (
+    WORDS_PER_SHARD,
+    pack_fragment,
+    pack_row,
+    tile_words as _tiled,
+    unpack_row,
+)
 from pilosa_tpu.ops.kernels import pair_stats, pair_stats_xla
 from pilosa_tpu.parallel import ShardMesh
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils.stats import global_stats
 
 
 @pytest.fixture
@@ -62,7 +69,10 @@ class TestPairStatsKernel:
         S, RF, RG, W = 3, 8, 16, 512
         f = rng.integers(0, 2**32, (S, RF, W), dtype=np.uint32)
         g = rng.integers(0, 2**32, (S, RG, W), dtype=np.uint32)
-        pair, cf, cg = (np.asarray(x) for x in pair_stats(f, g, interpret=True))
+        pair, cf, cg = (
+            np.asarray(x)
+            for x in pair_stats(_tiled(f), _tiled(g), interpret=True)
+        )
         want_pair = np.zeros((RF, RG), dtype=np.int64)
         for a in range(RF):
             for b in range(RG):
@@ -75,8 +85,8 @@ class TestPairStatsKernel:
         S, R, W = 5, 8, 256
         f = rng.integers(0, 2**32, (S, R, W), dtype=np.uint32)
         g = rng.integers(0, 2**32, (S, R, W), dtype=np.uint32)
-        got = pair_stats(f, g, interpret=True)
-        want = pair_stats_xla(f, g)
+        got = pair_stats(_tiled(f), _tiled(g), interpret=True)
+        want = pair_stats_xla(_tiled(f), _tiled(g))
         for a, b in zip(got, want):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -274,6 +284,127 @@ class TestShardMesh:
         assert len(jax.devices()) == 8
 
 
+class TestRowLeafEdges:
+    """The row leaf at its edges (ISSUE 27): a row id beyond the packed
+    rows reads as an all-zero slab under every verb and under Not (the
+    leaf's mask; Union, Difference, Xor and Not sit above it and would
+    count a clamped row's bits), rows 0 and rows_p - 1 read their own
+    bits, and a padded slot of a batched launch contributes 0. Every
+    count against numpy on the bits that were set."""
+
+    N_SHARDS = 3
+    ROWS = {"f": range(8), "g": (0, 3, 7), "h": range(4)}
+    #: (f, g, h) row ids: first rows, last packed rows (rows_p - 1 = 7;
+    #: h packs 4 rows into 8), then ids past the packed rows, alone and
+    #: mixed with live ones.
+    TRIPLES = [(0, 0, 0), (7, 7, 3), (8, 100, 9), (0, 100, 0), (7, 0, 9),
+               (1000, 3, 2)]
+    VERBS = {
+        "Intersect": lambda a, b, c: a & b & c,
+        "Union": lambda a, b, c: a | b | c,
+        "Difference": lambda a, b, c: a & ~b & ~c,
+        "Xor": lambda a, b, c: a ^ b ^ c,
+    }
+
+    def _setup(self, holder, rng):
+        idx = holder.create_index("i")
+        width = self.N_SHARDS * SHARD_WIDTH
+        bits = {}
+        exists = np.zeros(width, dtype=bool)
+        for name, rows in self.ROWS.items():
+            fld = idx.create_field(name)
+            for row in rows:
+                cols = np.unique(rng.integers(0, width, 4000, dtype=np.uint64))
+                fld.import_bits(np.full(cols.size, row, dtype=np.uint64), cols)
+                idx.existence_field().import_bits(
+                    np.zeros(cols.size, dtype=np.uint64), cols
+                )
+                mask = np.zeros(width, dtype=bool)
+                mask[cols] = True
+                bits[name, row] = mask
+                exists |= mask
+        none = np.zeros(width, dtype=bool)
+        return TPUBackend(holder), lambda name, row: bits.get((name, row), none), exists
+
+    def _cases(self, verb, row_bits, exists):
+        """[(pql, numpy count)] for a verb over the edge rows."""
+        if verb == "Not":
+            cases = [
+                (f"Not(Row(f={r}))", exists & ~row_bits("f", r))
+                for r in (0, 7, 8, 1000)
+            ]
+            cases.append((
+                "Not(Union(Row(f=8), Row(g=0)))", exists & ~row_bits("g", 0)
+            ))
+        else:
+            cases = [
+                (
+                    f"{verb}(Row(f={a}), Row(g={b}), Row(h={c}))",
+                    self.VERBS[verb](
+                        row_bits("f", a), row_bits("g", b), row_bits("h", c)
+                    ),
+                )
+                for a, b, c in self.TRIPLES
+            ]
+        return [(q, int(m.sum())) for q, m in cases]
+
+    @pytest.mark.parametrize("batched", [False, True], ids=["count", "count_batch"])
+    @pytest.mark.parametrize(
+        "verb", ["Intersect", "Union", "Difference", "Xor", "Not"]
+    )
+    def test_counts_equal_numpy(self, holder, rng, verb, batched):
+        from pilosa_tpu.pql import parse_string
+
+        be, row_bits, exists = self._setup(holder, rng)
+        cases = self._cases(verb, row_bits, exists)
+        calls = [parse_string(q).calls[0] for q, _ in cases]
+        shards = list(range(self.N_SHARDS))
+        assert be._pair_batch_plan("i", calls) is None  # the scan path
+        if batched:
+            launches = global_stats._counters.get(
+                ("device_launches_total", ("kind:count_batch",)), 0
+            )
+            got = be.count_batch("i", calls, shards)
+            assert global_stats._counters[
+                ("device_launches_total", ("kind:count_batch",))
+            ] > launches
+        else:
+            got = [be.count_shards("i", c, shards) for c in calls]
+        assert got == [n for _, n in cases], [q for q, _ in cases]
+
+    @pytest.mark.parametrize("verb", ["I", "U", "D", "X"])
+    def test_masked_leaf_and_padded_slot(self, holder, rng, verb):
+        """The program itself, below the batcher's routing: slot 1's h
+        leaf is masked (row id past the packed rows, clamped to the last
+        packed row as _build_row clamps it) and slots 2 and 3 are padding
+        that replays slot 0 with the lane mask off."""
+        be, row_bits, _ = self._setup(holder, rng)
+        shards_t = tuple(range(self.N_SHARDS))
+        blocks = tuple(
+            be._get_block("i", be._field("i", n), shards_t)[0] for n in "fgh"
+        )
+        assert blocks[2].shape[1] == 8  # h: 4 rows packed into 8
+        u32 = lambda *xs: np.array(xs, dtype=np.uint32)  # noqa: E731
+        scalars = (
+            u32(7, 0, 7, 7), u32(1, 1, 1, 1),  # f rows, masks
+            u32(7, 3, 7, 7), u32(1, 1, 1, 1),  # g
+            u32(3, 7, 3, 3), u32(1, 0, 1, 1),  # h: slot 1 masked
+            u32(1, 1, 0, 0),                   # lane mask
+        )
+        spec = (verb, (("R", "f"), ("R", "g"), ("R", "h")))
+        out = np.asarray(be._program("count_batch", spec, True)(blocks, scalars))
+        fn = self.VERBS[
+            {"I": "Intersect", "U": "Union", "D": "Difference", "X": "Xor"}[verb]
+        ]
+        none = np.zeros_like(row_bits("f", 0))
+        want = [
+            int(fn(row_bits("f", 7), row_bits("g", 7), row_bits("h", 3)).sum()),
+            int(fn(row_bits("f", 0), row_bits("g", 3), none).sum()),
+            0, 0,
+        ]
+        assert out.tolist() == want
+
+
 class TestCountBatch:
     def test_count_batch_matches_singles(self, holder, rng):
         idx = holder.create_index("i")
@@ -446,7 +577,8 @@ class TestCountBatch:
         f = rng.integers(0, 2**32, (S, RF, W), dtype=np.uint32)
         g = rng.integers(0, 2**32, (S, RG, W), dtype=np.uint32)
         pair, cf, cg = (
-            np.asarray(x) for x in pair_stats_pershard(f, g, interpret=True)
+            np.asarray(x)
+            for x in pair_stats_pershard(_tiled(f), _tiled(g), interpret=True)
         )
         for i in range(S):
             np.testing.assert_array_equal(
@@ -1071,14 +1203,19 @@ class TestTriStatsKernel:
         g = rng.integers(0, 1 << 32, (S, RG, W), dtype=np.uint32)
         h = rng.integers(0, 1 << 32, (S, RH, W), dtype=np.uint32)
         filt = rng.integers(0, 1 << 32, (S, W), dtype=np.uint32)
-        tri = np.asarray(tri_stats(f, g, h, interpret=True))
-        tri_f = np.asarray(tri_stats(f, g, h, filt, interpret=True))
+        tf, tg, th = _tiled(f), _tiled(g), _tiled(h)
+        tri = np.asarray(tri_stats(tf, tg, th, interpret=True))
+        tri_f = np.asarray(tri_stats(tf, tg, th, _tiled(filt), interpret=True))
         for k in range(RH):
             m = h[:, k, :]
-            want = np.asarray(pair_stats((f & m[:, None, :]), g, interpret=True)[0])
+            want = np.asarray(
+                pair_stats(_tiled(f & m[:, None, :]), tg, interpret=True)[0]
+            )
             np.testing.assert_array_equal(tri[k], want)
             want_f = np.asarray(
-                pair_stats((f & (m & filt)[:, None, :]), g, interpret=True)[0]
+                pair_stats(
+                    _tiled(f & (m & filt)[:, None, :]), tg, interpret=True
+                )[0]
             )
             np.testing.assert_array_equal(tri_f[k], want_f)
 
@@ -1326,7 +1463,9 @@ class TestGroupNMaintainedTensor:
         gs = rng.integers(0, 2**32, (2, rg, w), dtype=np.uint32)
         hs = rng.integers(0, 2**32, (2, rh, w), dtype=np.uint32)
         per = np.asarray(
-            nary_stats_pershard(fs, gs, (hs,), interpret=True)
+            nary_stats_pershard(
+                _tiled(fs), _tiled(gs), (_tiled(hs),), interpret=True
+            )
         )  # [K, S, rf, rg]
         for s in range(2):
             host = _host_slab_groupn([fs[s], gs[s], hs[s]], [rf, rg, rh])
