@@ -1,11 +1,12 @@
-"""The deployment's data, a function of (configuration, seed, shard).
+"""One shard of the deployment's data in hand, and the two ways the
+loader ships it.
 
-Copied from chip_smoke.py (`field_bits`, `v_values`, `pack64`,
-`roaring_body`), which copied bench.py's draw: per row, n uniform
-columns with replacement; a field marked `density_split_over_rows`
-splits one field's n over its rows. The RNG key is [seed, shard, position
-of the field in the configuration], so the same seed gives the same bits
-in every run, here and in the reference.
+What a field holds is its draw's business (benchmark/draws/<name>.py,
+named by the field's `draw` key or its type's default): one shard of one
+field as a function of (configuration, seed, shard, field). A draw says
+how it is shipped: `SHIP = "roaring"` (bool[rows, shard_width], posted to
+`import-roaring/{shard}`) or `SHIP = "values"` ((columns, values), posted
+to `import`).
 
 Only `roaring_body` touches the program: it encodes one shard of one
 field in the wire format of `POST .../import-roaring/{shard}` with the
@@ -16,44 +17,45 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import plugins
+
 
 def set_fields(config: dict) -> list[str]:
     return [n for n, f in config["fields"].items() if f["type"] == "set"]
-
-
-def int_fields(config: dict) -> list[str]:
-    return [n for n, f in config["fields"].items() if f["type"] == "int"]
 
 
 def field_position(config: dict, field: str) -> int:
     return list(config["fields"]).index(field)
 
 
-def field_bits(config: dict, seed: int, shard: int, field: str) -> np.ndarray:
-    """bool[rows, shard_width] of one shard of one set field."""
-    spec = config["fields"][field]
-    width = config["shard_width"]
-    rows = spec["rows"]
-    n_bits = int(width * spec["density"])
-    if spec.get("density_split_over_rows"):
-        n_bits //= rows
-    rng = np.random.default_rng([seed, shard, field_position(config, field)])
-    cols = rng.integers(0, width, size=(rows, n_bits), dtype=np.uint32)
-    bits = np.zeros((rows, width), dtype=bool)
-    bits[np.arange(rows)[:, None], cols] = True
-    return bits
+def draw(config: dict, seed: int, shard: int, field: str):
+    """One shard of one field, as its draw gives it."""
+    return plugins.draw_of(config, field).draw(config, seed, shard, field)
 
 
-def int_values(config: dict, seed: int, shard: int, field: str):
-    """(in-shard columns, values) of one shard of one int field."""
-    spec = config["fields"][field]
-    width = config["shard_width"]
-    rng = np.random.default_rng([seed, shard, field_position(config, field)])
-    cols = np.unique(
-        rng.integers(0, width, spec["values_per_shard"], dtype=np.int64)
-    )
-    lo, hi = spec["value_range"]
-    return cols, rng.integers(lo, hi + 1, cols.size)
+class ShardData:
+    """One shard's fields, each drawn when first asked for and kept: the
+    loader ships what it holds, and a shape's `shard_tables` reads the
+    fields it needs from the same arrays. `given` overrides the seed's
+    draw, field by field (the tests' hand-worked shards)."""
+
+    def __init__(self, config: dict, seed: int, shard: int,
+                 given: dict | None = None):
+        self.config, self.seed, self.shard = config, seed, shard
+        self._have = dict(given or {})
+
+    def field(self, name: str):
+        if name not in self._have:
+            self._have[name] = draw(self.config, self.seed, self.shard, name)
+        return self._have[name]
+
+    def bits(self, name: str) -> np.ndarray:
+        """bool[rows, shard_width] of a field shipped as bits."""
+        return self.field(name)
+
+    def values(self, name: str):
+        """(in-shard columns, values) of a field shipped as values."""
+        return self.field(name)
 
 
 def pack64(bits: np.ndarray) -> np.ndarray:

@@ -16,8 +16,8 @@ import socket
 import threading
 import time
 
+from . import plugins, traffic
 from . import reference as ref_mod
-from . import traffic
 
 
 class _Conn:
@@ -92,13 +92,16 @@ def _client_phase(conn: _Conn, stream, phase: dict, out: dict) -> None:
         records.append((t0, t1, status, data, calls))
 
 
-def _judge(records, reference) -> dict:
-    """Compare every answer with the reference's. An answer is wrong when
-    the request failed, the body does not parse, or any count differs."""
+def _judge(records, reference, shape: str) -> dict:
+    """Compare every answer with the reference's, each result as the
+    group's shape compares it. An answer is wrong when the request failed,
+    the body does not parse, or any result differs."""
     wrong = failed = 0
     worst = 0
     examples = []
     ok = []
+    mod = plugins.load("shapes", shape)
+    config, totals = reference.config, reference.totals.get(shape, {})
     for t0, t1, status, data, calls in records:
         if status != 200:
             failed += 1
@@ -110,17 +113,20 @@ def _judge(records, reference) -> dict:
             got = json.loads(data)["results"]
         except (ValueError, KeyError, TypeError):
             got = None
-        want = [reference.answer(v, leaves) for v, leaves in calls]
-        ok.append(got == want)
-        if got != want:
+        want = [mod.answer(config, totals, call) for call in calls]
+        right = isinstance(got, list) and len(got) == len(want)
+        if right:
+            for g, w in zip(got, want):
+                equal, error = mod.compare(g, w)
+                right = right and equal
+                if error is not None:
+                    worst = max(worst, error)
+        ok.append(right)
+        if not right:
             wrong += 1
-            if isinstance(got, list) and len(got) == len(want):
-                for g, w in zip(got, want):
-                    if isinstance(g, int):
-                        worst = max(worst, abs(g - w))
             if len(examples) < 3:
                 examples.append(
-                    f"{traffic.render(calls)[:100]!r}: got {str(got)[:120]} "
+                    f"{mod.render(calls)[:100]!r}: got {str(got)[:120]} "
                     f"want {str(want)[:120]}"
                 )
     return {"wrong": wrong, "failed": failed, "ok": ok,
@@ -174,12 +180,13 @@ def worker_main(pipe, port: int, mix: dict, config: dict, seed: int,
             for c, out in outs.items():
                 recs = out.get("records", [])
                 entry = {
+                    "shape": plugins.shape_name(groups[c]),
                     "sent": [r[0] for r in recs],
                     "done": [r[1] for r in recs],
                     "calls": [len(r[4]) for r in recs],
                 }
                 if cmd.get("judge") and reference is not None:
-                    entry["judged"] = _judge(recs, reference)
+                    entry["judged"] = _judge(recs, reference, entry["shape"])
                 reply["clients"][c] = entry
             pipe.send(reply)
     finally:

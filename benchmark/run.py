@@ -11,11 +11,14 @@ of its own, compares every answer of the window with the numpy reference,
 and prints one JSON object as the last line of standard output. This
 process never imports jax: a parent that had would hold the chip.
 
-Everything that belongs to one cell is data found by name: the cell, its
+Everything that belongs to one cell is found by name: the cell, its
 configuration and its metrics in BENCHMARK.json; the configuration's file
-as named there; the mix in benchmark/traffic/<traffic>.json; each metric
-in benchmark/end_to_end/<name>.json or benchmark/layer_metrics/<name>.json,
-naming a reader in benchmark/readers/. See benchmark/README.md.
+as named there, each field's draw in benchmark/draws/; the mix in
+benchmark/traffic/<traffic>.json, each group's call shape (its calls,
+their text, their reference and their warm-up) in benchmark/shapes/; each
+metric in benchmark/end_to_end/<name>.json or
+benchmark/layer_metrics/<name>.json, naming a reader in
+benchmark/readers/. See benchmark/README.md.
 
 `--rehearse cpu` walks the same phases against a child on JAX's CPU
 devices, to debug the harness without a chip: it never exits 0 and never
@@ -30,7 +33,6 @@ import time
 T_PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -44,7 +46,7 @@ REPO = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, BENCH_DIR)
 sys.path.insert(1, REPO)
 
-from harness import dataset, hostwatch, loadgen, traffic  # noqa: E402
+from harness import dataset, hostwatch, loadgen, plugins, reference, traffic  # noqa: E402
 from harness.server import LAUNCHER, BenchFailure, Server, delta, scrape  # noqa: E402
 
 REHEARSAL_EXIT = 3
@@ -85,13 +87,7 @@ def read_metric(section_dir: str, metric: dict, ctx: dict):
     """Value of one metric by its definition file, or None where the
     reader finds nothing to read."""
     spec = load_json(os.path.join(BENCH_DIR, section_dir, metric["name"] + ".json"))
-    path = os.path.join(BENCH_DIR, "readers", spec["reader"] + ".py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_reader_" + spec["reader"], path
-    )
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read(ctx, **spec.get("args", {}))
+    return plugins.load("readers", spec["reader"]).read(ctx, **spec.get("args", {}))
 
 
 # ---------------------------------------------------------------------------
@@ -124,20 +120,6 @@ def wait_for_roles(srv: Server, roles: list[str], timeout: float = 900.0) -> flo
     return 0.0
 
 
-def distinct_calls(group: dict, config: dict, seed: int, client: int,
-                   verb: str, n: int) -> list:
-    """n different calls of one verb, drawn as the group's traffic is."""
-    stream = traffic.RequestStream(group, config, seed, client, stream=1,
-                                   verbs=[verb])
-    calls: dict = {}
-    for _ in range(64 * n):
-        for v, leaves in stream.next()[1]:
-            calls.setdefault((v, tuple(leaves)), (v, leaves))
-        if len(calls) >= n:
-            return list(calls.values())[:n]
-    raise BenchFailure(f"the group's pool holds fewer than {n} calls of {verb}")
-
-
 def warm_up(srv: Server, gen: loadgen.Generator, config: dict, mix: dict,
             seed: int) -> None:
     """Build the cell's stacks, compile its programs, and go on until
@@ -146,13 +128,9 @@ def warm_up(srv: Server, gen: loadgen.Generator, config: dict, mix: dict,
     1. One request alone from each group of clients, in the mix's order:
        the first answers, which build the stacks the mix's fields need
        (and no others).
-    2. For a group that states `warm_batch_sizes`: for each of its verbs
-       and each size n, one request of n different calls of that verb. The
-       batcher pads a group of concurrent calls of one shape to a power of
-       two, and each (verb, padded size) is a program of its own; one
-       request of n calls reaches the backend as one group of n, so every
-       program the window can need is compiled here, whatever sizes the
-       window's timing then brings about.
+    2. What the group's shape knows it needs beyond that (its `warm`):
+       requests that reach programs the window's timing may or may not
+       bring about, so that each is compiled here whatever the window does.
     3. Rounds of the mix itself until a round compiles nothing
        (/debug/programs) twice running, and no thread of a role the mix
        lists under `quiet_roles` is alive (/debug/threads): in a checkout
@@ -161,24 +139,26 @@ def warm_up(srv: Server, gen: loadgen.Generator, config: dict, mix: dict,
        would hold compilation.
     """
     warm = mix.get("warm", {})
+    path = loadgen.query_path(config)
     client = 0
     for group in mix["groups"]:
-        path = loadgen.query_path(config)
+        name = group.get("name", client)
         stream = traffic.RequestStream(group, config, seed, client, stream=1)
         t0 = time.monotonic()
         srv.request("POST", path, stream.next()[0])
-        say(f"warm-up: first answer of group {group.get('name', client)!r} "
+        say(f"warm-up: first answer of group {name!r} "
             f"in {time.monotonic() - t0:.1f}s")
         t0 = time.monotonic()
-        for verb in group["verbs"]:
-            for n in group.get("warm_batch_sizes", []):
-                srv.request("POST", path, traffic.render(
-                    distinct_calls(group, config, seed, client, verb, int(n))
-                ))
-        if group.get("warm_batch_sizes"):
-            say(f"warm-up: batches of {group['warm_batch_sizes']} calls of each "
-                f"verb in {time.monotonic() - t0:.1f}s, "
+
+        def send(body: bytes) -> None:
+            srv.request("POST", path, body)
+
+        def said(what: str) -> None:
+            say(f"warm-up: group {name!r}: {what} in "
+                f"{time.monotonic() - t0:.1f}s, "
                 f"{compile_count(srv)[0]} programs compiled so far")
+
+        plugins.shape_of(group).warm(group, config, seed, client, send, said)
         client += int(group["clients"])
     stream_id = 2
     quiet, rounds = 0, 0
@@ -214,8 +194,9 @@ def generator_late_by(window: dict) -> float:
 
 def gather(window: dict) -> dict:
     """Flatten the generator processes' replies of the window phase."""
-    out = {k: [] for k in ("sent", "done", "calls", "ok")}
-    judged = {"wrong": 0, "failed": 0, "worst_abs_error": 0, "examples": []}
+    out = {k: [] for k in ("sent", "done", "calls", "ok", "shape")}
+    judged = {"wrong": 0, "failed": 0, "worst_abs_error": 0, "examples": [],
+              "by_shape": {}}
     cpu = []
     for reply in window["replies"]:
         cpu.append(reply["cpu_s"] / reply["wall_s"] if reply["wall_s"] else 0.0)
@@ -224,8 +205,15 @@ def gather(window: dict) -> dict:
                 out[k].extend(entry[k])
             j = entry["judged"]
             out["ok"].extend(j["ok"])
+            out["shape"].extend([entry["shape"]] * len(j["ok"]))
             judged["wrong"] += j["wrong"]
             judged["failed"] += j["failed"]
+            mine = judged["by_shape"].setdefault(
+                entry["shape"], {"requests": 0, "wrong": 0, "failed": 0}
+            )
+            mine["requests"] += len(j["ok"])
+            mine["wrong"] += j["wrong"]
+            mine["failed"] += j["failed"]
             judged["worst_abs_error"] = max(
                 judged["worst_abs_error"], j["worst_abs_error"]
             )
@@ -233,6 +221,27 @@ def gather(window: dict) -> dict:
     out.update(t_start=window["t_start"], t_end=window["t_end"],
                seconds=window["seconds"])
     return {"window": out, "judged": judged, "generator_cpu_share": cpu}
+
+
+def say_latency(w: dict) -> None:
+    """The window's latency percentiles on standard error: of all requests,
+    and of each shape's where the mix has more than one."""
+    shapes = sorted(set(w["shape"]))
+    for shape in [None] + (shapes if len(shapes) > 1 else []):
+        lat = sorted(
+            (t1 - t0) * 1e3
+            for t0, t1, s in zip(w["sent"], w["done"], w["shape"])
+            if shape in (None, s)
+        )
+        if not lat:
+            continue
+        say("latency, send to last byte, ms"
+            + (f", shape {shape!r} ({len(lat)} requests)" if shape else "")
+            + ": " + " ".join(
+                f"p{p}={lat[min(len(lat) - 1, len(lat) * p // 100)]:.1f}"
+                for p in (50, 75, 90, 95, 97, 99)
+            ) + "; share over 1.5 times the median: "
+            f"{sum(x > 1.5 * lat[len(lat) // 2] for x in lat) / len(lat):.4f}")
 
 
 def trace_stretch(srv: Server, at: float, seconds: float, out: dict) -> None:
@@ -286,7 +295,8 @@ def run_cell(args) -> dict:
     srv = gen = None
     try:
         data_dir, tables = dataset.ensure(
-            config, args.seed, work, say, root=args.data_root,
+            config, args.seed, work, say, reference.needs(mix, config),
+            root=args.data_root,
             extra_env={"JAX_PLATFORMS": rehearse} if rehearse else None,
         )
         t_data = time.monotonic() - T_PROCESS_START
@@ -363,13 +373,7 @@ def run_cell(args) -> dict:
         answered = sorted(w["done"])
         say("longest silence between two answers: "
             f"{max((b - a for a, b in zip(answered, answered[1:])), default=0.0):.3f}s")
-        lat = sorted((t1 - t0) * 1e3 for t0, t1 in zip(w["sent"], w["done"]))
-        if lat:
-            say("latency, send to last byte, ms: " + " ".join(
-                f"p{p}={lat[min(len(lat) - 1, len(lat) * p // 100)]:.1f}"
-                for p in (50, 75, 90, 95, 97, 99)
-            ) + "; share over 1.5 times the median: "
-                f"{sum(x > 1.5 * lat[len(lat) // 2] for x in lat) / len(lat):.4f}")
+        say_latency(w)
         say("generator CPU share per process (1.0 = one core): "
             + " ".join(f"{x:.2f}" for x in g["generator_cpu_share"]))
         say(f"compiles inside the window: programs {compiles1[0] - compiles0[0]}, "
@@ -447,10 +451,18 @@ def run_cell(args) -> dict:
             "device_fallbacks": {"value": fallbacks, "limit": 0},
             "requests_judged": {"value": attempted, "limit_at_least": 1},
         }
-        correct = (
-            judged["wrong"] == 0 and judged["failed"] == 0
-            and judged["worst_abs_error"] == 0 and fallbacks == 0
-            and attempted >= 1
+        # Every shape of the mix has to have been judged, not one of them
+        # for the others.
+        for shape in plugins.groups_by_shape(mix):
+            mine = judged["by_shape"].get(shape, {"requests": 0})
+            compared["judged_" + shape] = {
+                "value": mine["requests"], "limit_at_least": 1,
+            }
+            say(f"judged, shape {shape!r}: " + json.dumps(mine))
+        correct = all(
+            v["value"] <= v["limit"] if "limit" in v
+            else v["value"] >= v["limit_at_least"]
+            for v in compared.values()
         )
         for ex in judged["examples"][:5]:
             say("wrong: " + ex)
