@@ -49,16 +49,59 @@ def _close_holder(holder, log) -> None:
 
     from pilosa_tpu.utils.stats import global_stats
 
+    def per_tag(family: str) -> str:
+        return " ".join(
+            f"{name.split('"')[1]}={total:.2f}s/{n}"
+            for name, (total, n) in sorted(
+                global_stats.timing_totals(family).items()
+            )
+        )
+
     t0 = time.perf_counter()
     holder.close()
-    steps = global_stats.timing_totals("holder_close_seconds")
+    said = "holder closed in %.2fs: holder_close_seconds %s" % (
+        time.perf_counter() - t0, per_tag("holder_close_seconds"),
+    )
+    loads = per_tag("import_roaring_seconds")
+    if loads:
+        # What a bulk load through import-roaring cost this process, by
+        # the kind of view it went to (a loader's last word).
+        said += "; import_roaring_seconds %s import_roaring_bits_total=%d" % (
+            loads,
+            sum(global_stats.counter_totals("import_roaring_bits_total").values()),
+        )
+    log.printf("%s", said)
+
+
+#: Container objects allocated (less freed) before a young collection, and
+#: young collections per older one: CPython's defaults are 700, 10, 10.
+GC_THRESHOLDS = (50_000, 20, 20)
+
+
+def _tune_collector(log) -> None:
+    """The cyclic collector, set for a process that holds a large heap
+    for good and answers requests of thousands of objects each.
+
+    What the holder has just built (every fragment, its containers and
+    caches: hundreds of thousands of objects that live as long as the
+    process) is frozen: out of every collection's sight. Frozen objects
+    are still freed by their reference counts; only a cycle among them
+    that becomes garbage is kept, and a served holder makes none.
+
+    And collections are made rarer. A collection stops every thread, an
+    answer of a few thousand groups is ~15,000 containers that reference
+    counting frees by itself, and sixteen such answers in flight are the
+    heap a collection walks: at the default thresholds a 51 s window held
+    16,500 young collections and 136 full ones of 54 ms (118 ms before
+    the freeze), 15 s in all (PERF.md, PR 30)."""
+    import gc
+
+    gc.collect()
+    gc.freeze()
+    gc.set_threshold(*GC_THRESHOLDS)
     log.printf(
-        "holder closed in %.2fs: holder_close_seconds %s",
-        time.perf_counter() - t0,
-        " ".join(
-            f"{name.split('"')[1]}={total:.2f}s/{n}"
-            for name, (total, n) in sorted(steps.items())
-        ),
+        "gc: %d objects frozen after the holder opened, thresholds %s",
+        gc.get_freeze_count(), GC_THRESHOLDS,
     )
 
 
@@ -91,6 +134,7 @@ def cmd_server(args) -> int:
     log = StandardLogger(stream=log_stream, verbose=cfg.verbose)
     data_dir = os.path.expanduser(cfg.data_dir)
     holder = Holder(data_dir).open()
+    _tune_collector(log)
 
     backend = None
     if cfg.executor == "tpu":

@@ -14,12 +14,14 @@ import datetime as dt
 import json
 import os
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
 import numpy as np
 
 from pilosa_tpu.core.cache import Pair
+from pilosa_tpu.core.fragment import BSI_OFFSET_BIT
 from pilosa_tpu.core.row import Row
 from pilosa_tpu.core.timequantum import (
     validate_quantum,
@@ -542,10 +544,64 @@ class Field:
             frag.bulk_import(rows_v[ssel], cols_v[ssel], clear=clear)
             self.add_available_shard(int(shard))
 
+    def own_view_name(self) -> str:
+        """The view a bulk load with no view named goes to: the one this
+        field's queries read."""
+        if self.options.type == FIELD_TYPE_INT:
+            return bsi_view_name(self.name)
+        return VIEW_STANDARD
+
     def import_roaring(self, shard: int, data: bytes, view_name: str = VIEW_STANDARD, clear: bool = False, epoch_unknown: bool = False) -> int:
+        """Union (or clear) a serialized roaring bitmap into one shard of
+        one view; the bits changed. `view_name` "" is the field's own
+        view. An int field's own view holds its bit-sliced planes as rows
+        (exists, sign, then the magnitude of value - base, lowest bit
+        first: the fragment's BSI layout), so a union into it raises
+        `bit_depth` to the highest plane the bitmap holds, as
+        `import_value` would have for the same values; a plane that no
+        value between the field's min and max can set is refused before
+        anything is written."""
+        view_name = view_name or self.own_view_name()
+        bsi = (
+            self.options.type == FIELD_TYPE_INT
+            and view_name == bsi_view_name(self.name)
+        )
+        t0 = time.perf_counter()
+        parsed = None
+        if bsi and not clear:
+            parsed = deserialize(data)
+            if parsed.any():
+                self._raise_bit_depth(
+                    parsed.max() // SHARD_WIDTH - BSI_OFFSET_BIT + 1
+                )
         frag = self.create_view_if_not_exists(view_name).create_fragment_if_not_exists(shard)
         self.add_available_shard(shard)
-        return frag.import_roaring(data, clear=clear, epoch_unknown=epoch_unknown)
+        changed = frag.import_roaring(
+            data, clear=clear, epoch_unknown=epoch_unknown, parsed=parsed
+        )
+        global_stats.with_tags(
+            "view_kind:bsi" if bsi else "view_kind:set"
+        ).timing("import_roaring_seconds", time.perf_counter() - t0)
+        global_stats.count("import_roaring_bits_total", changed)
+        return changed
+
+    def _raise_bit_depth(self, depth: int) -> None:
+        """Planes up to `depth` are about to hold bits: the field's
+        `bit_depth` covers them from here on."""
+        opts = self.options
+        limit = max(
+            bit_depth_of(opts.min - opts.base), bit_depth_of(opts.max - opts.base)
+        )
+        if depth > limit:
+            raise ValueError(
+                f"bit plane {BSI_OFFSET_BIT + depth - 1} is beyond the "
+                f"{limit} magnitude planes of field {self.name} "
+                f"(min {opts.min}, max {opts.max})"
+            )
+        with self.lock:
+            if depth > opts.bit_depth:
+                opts.bit_depth = depth
+                self.save_meta()
 
     # -- TopN -------------------------------------------------------------
 

@@ -1774,9 +1774,12 @@ class Fragment:
 
     @_drains_wal
     def import_roaring(self, data: bytes, clear: bool = False,
-                       epoch_unknown: bool = False) -> int:
+                       epoch_unknown: bool = False,
+                       parsed: Optional[Bitmap] = None) -> int:
         """Union/clear a pre-serialized roaring bitmap in one op
-        (reference fragment.importRoaring :2255). `epoch_unknown` is for
+        (reference fragment.importRoaring :2255). `parsed` is the
+        deserialization of `data` where the caller has made it already.
+        `epoch_unknown` is for
         COPIES of data that already exists elsewhere (resize shard
         migration): minting a fresh epoch would out-date the genuinely
         newer blocks surviving replicas hold, and directed repair would
@@ -1784,7 +1787,7 @@ class Fragment:
         blocks to union repair until a real write stamps them."""
         with self.lock:
             # One parse serves both the import and the epoch stamping.
-            other = deserialize(data)
+            other = parsed if parsed is not None else deserialize(data)
             changed = self.storage.import_roaring_bits(
                 data, clear=clear, parsed=other
             )

@@ -333,17 +333,21 @@ class Executor:
                     if node is not None:
                         node["cache"] = {"verdict": "bypass"}
                 check_deadline("device_dispatch")
-                with self.tracer.start_span(f"executor.execute{call.name}"):
-                    result = self.execute_call(index, call, shards, opt)
-                if node is not None:
-                    node["route"] = "execute"
-                    node["devices"] = self._explain_devices()
-                    if prof.shards is not None:
-                        node["shards"] = prof.shards
-                if not opt.remote:
-                    check_deadline("key_translate")
-                    with prof.phase("key_translate"):
-                        result = self._translate_result(idx, call, result)
+                # A call's own latency, whichever place it has in the
+                # request's body: `query_seconds{call}` is the request's,
+                # under its first call's name.
+                with prof.call_timer(call.name):
+                    with self.tracer.start_span(f"executor.execute{call.name}"):
+                        result = self.execute_call(index, call, shards, opt)
+                    if node is not None:
+                        node["route"] = "execute"
+                        node["devices"] = self._explain_devices()
+                        if prof.shards is not None:
+                            node["shards"] = prof.shards
+                    if not opt.remote:
+                        check_deadline("key_translate")
+                        with prof.phase("key_translate"):
+                            result = self._translate_result(idx, call, result)
                 if token is not None:
                     cache.commit(token, result)
                 results.append(result)

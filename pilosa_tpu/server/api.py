@@ -767,9 +767,10 @@ class API:
                 raise self._map_import_client_error(e) from e
             return
         for view_name, data in views.items():
-            name = view_name or "standard"
+            # View "" is the field's own: `standard`, or the plane view
+            # of an int field (core/field.py import_roaring).
             try:
-                f.import_roaring(shard, data, view_name=name, clear=clear)
+                f.import_roaring(shard, data, view_name=view_name, clear=clear)
             except ValueError as e:
                 raise APIError(str(e)) from e
 

@@ -200,6 +200,8 @@ class _PhaseTimer:
         self.span = span
 
     def __enter__(self):
+        if self.profile.in_call is not None:
+            self.profile.in_call.suspend()
         if self.span is not None:
             self.span.__enter__()
         self.t0 = time.perf_counter()
@@ -210,6 +212,56 @@ class _PhaseTimer:
             self.profile.add_phase(self.name, time.perf_counter() - self.t0)
         if self.span is not None:
             self.span.__exit__(*exc)
+        if self.profile.in_call is not None:
+            self.profile.in_call.resume()
+
+
+class _CallTimer:
+    """One PQL call of a request, the first or the tenth of its body:
+    observed as `query_call_seconds{call=<name>}` from its start to its
+    result, waits included (what the client of that call would feel),
+    and on the profiler's host plane as `pilosa.call.<name>` over the
+    stretches in which its thread is in no phase and serves no drain: a
+    phase and a drain step carry spans of their own, and a wait carries
+    none (WAITING_PHASES), so the call's span names what is left, the
+    thread's own work between them."""
+
+    __slots__ = ("profile", "name", "span", "covered", "t0")
+
+    def __init__(self, profile: "QueryProfile", name: str):
+        self.profile = profile
+        self.name = name
+        self.span = None
+        #: Phases and drains open inside the call (and, outside `with`,
+        #: the call's own being shut): the span is open while this is 0.
+        self.covered = 1
+
+    def __enter__(self):
+        self.profile.in_call = self
+        self.resume()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        from pilosa_tpu.utils.stats import global_stats
+
+        global_stats.with_tags(f"call:{self.name}").timing(
+            "query_call_seconds", time.perf_counter() - self.t0
+        )
+        self.suspend()
+        self.profile.in_call = None
+
+    def suspend(self) -> None:
+        self.covered += 1
+        if self.span is not None:
+            self.span.__exit__(None, None, None)
+            self.span = None
+
+    def resume(self) -> None:
+        self.covered -= 1
+        if self.covered == 0 and _span_factory is not None:
+            self.span = _span_factory("pilosa.call." + self.name)
+            self.span.__enter__()
 
 
 class QueryProfile:
@@ -219,7 +271,7 @@ class QueryProfile:
     __slots__ = (
         "qid", "index", "query", "call", "started_at", "_t0",
         "phases", "counters", "error", "duration", "remote",
-        "explain", "shards", "shape",
+        "explain", "shards", "shape", "in_call",
     )
     #: A request pays for what this thread does: per-request counters
     #: (bytes shipped, launches) are worth working out.
@@ -255,6 +307,12 @@ class QueryProfile:
         # structure + field names, literals stripped), stamped by the
         # executor after parse; the workload table's aggregation key.
         self.shape: Optional[str] = None
+        #: The call of the request's body being executed (`call`).
+        self.in_call: Optional[_CallTimer] = None
+
+    def call_timer(self, name: str) -> _CallTimer:
+        """Time one call of the request's body (see _CallTimer)."""
+        return _CallTimer(self, name)
 
     def phase(self, name: str, span: Optional[str] = None,
               **meta) -> _PhaseTimer:
@@ -360,11 +418,15 @@ class NopProfile:
     shards = None
     shape = None
     charges = False
+    in_call = None
 
     def phase(self, name: str, span: Optional[str] = None, **meta):
         # Unprofiled work (prewarm threads, direct backend calls) still
         # shows in a trace: the span alone.
         return _span(name, span, meta) or self._PHASE
+
+    def call_timer(self, name: str):
+        return self._PHASE
 
     def add_phase(self, name: str, seconds: float) -> None:
         pass
@@ -430,10 +492,14 @@ class PlaneProfile(NopProfile):
         leader's; none on a helper), which is restored on exit."""
         self.leader = getattr(_local, "profile", None)
         _local.profile = self
+        if self.leader is not None and self.leader.in_call is not None:
+            self.leader.in_call.suspend()
         return self
 
     def __exit__(self, *exc):
         _local.profile = self.leader
+        if self.leader is not None and self.leader.in_call is not None:
+            self.leader.in_call.resume()
         return False
 
     @property

@@ -755,3 +755,74 @@ class TestDrainAndSetupTelemetry:
         mon._flush_gc_pauses()
         assert global_stats.timing_totals("runtime_gc_pause_seconds")[key][1] == n0 + 1
         assert mon._gc_pauses == []
+
+
+class TestCallTimer:
+    """`query_call_seconds{call}` and the span `pilosa.call.<name>`
+    (PR 30): every call of a request's body is timed by itself, and its
+    span is open only while its thread is in no phase and serves no
+    drain, so that a waiting call never names a gap of the device."""
+
+    class Span:
+        events: list = []
+
+        def __init__(self, name, **meta):
+            self.name = name
+
+        def __enter__(self):
+            self.events.append(("open", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            self.events.append(("shut", self.name))
+
+    @pytest.fixture
+    def events(self):
+        from pilosa_tpu.utils import qprofile
+
+        before = qprofile._span_factory
+        self.Span.events = []
+        qprofile.set_span_factory(self.Span)
+        yield self.Span.events
+        qprofile.set_span_factory(before)
+
+    @staticmethod
+    def observed(call: str) -> float:
+        key = f'query_call_seconds{{call="{call}"}}'
+        return global_stats.timing_totals("query_call_seconds").get(key, (0, 0))[1]
+
+    def test_the_span_is_shut_over_phases_waits_and_drains(self, events):
+        from pilosa_tpu.utils.qprofile import PlaneProfile
+
+        before = self.observed("Sum")
+        with profile_scope(index="i") as prof:
+            with prof.call_timer("Sum"):
+                with prof.phase("stack_fetch"):
+                    with prof.phase("freshness"):  # nested: still shut
+                        pass
+                with prof.phase("batch_wait"):  # a wait has no span at all
+                    pass
+                with PlaneProfile(global_stats) as plane:  # leading a drain
+                    with plane.phase("take"):
+                        pass
+            assert prof.in_call is None
+        call = "pilosa.call.Sum"
+        assert events == [
+            ("open", call), ("shut", call),
+            ("open", "pilosa.stack_fetch"), ("open", "pilosa.freshness"),
+            ("shut", "pilosa.freshness"), ("shut", "pilosa.stack_fetch"),
+            ("open", call), ("shut", call),     # before the wait
+            ("open", call), ("shut", call),     # between wait and drain
+            ("open", "pilosa.drain.take"), ("shut", "pilosa.drain.take"),
+            ("open", call), ("shut", call),
+        ]
+        assert self.observed("Sum") == before + 1
+
+    def test_every_call_of_a_body_is_observed(self, server):
+        req(server, "POST", "/index/i", "{}", ctype="application/json")
+        req(server, "POST", "/index/i/field/f", "{}", ctype="application/json")
+        before = {c: self.observed(c) for c in ("TopN", "Rows", "Set")}
+        req(server, "POST", "/index/i/query",
+            "Set(1, f=1)TopN(f)Rows(f)TopN(f, n=1)")
+        grown = {c: self.observed(c) - before[c] for c in before}
+        assert grown == {"TopN": 2, "Rows": 1, "Set": 1}
