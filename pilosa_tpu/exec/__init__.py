@@ -10,6 +10,7 @@ structure of the reference's mapReduce (reference executor.go:2460).
 from pilosa_tpu.exec.executor import Executor, ExecOptions
 from pilosa_tpu.exec.result import (
     GroupCount,
+    GroupCounts,
     FieldRow,
     PairsField,
     RowIDs,

@@ -25,6 +25,7 @@ from pilosa_tpu.exec.cpu import CPUBackend, NotFoundError, QueryError
 from pilosa_tpu.exec.result import (
     FieldRow,
     GroupCount,
+    GroupCounts,
     PairField,
     PairsField,
     RowIDs,
@@ -519,7 +520,14 @@ class Executor:
                     count=result.pair.count,
                     key=f.translate_store.translate_id(result.pair.id) or "",
                 )
-        if isinstance(result, list) and result and isinstance(result[0], GroupCount):
+        if isinstance(result, GroupCounts):
+            # The device path's columnar answer: one bulk lookup a keyed
+            # field, written into this request's own result.
+            for j, name in enumerate(result.fields):
+                f = idx.field(name)
+                if f is not None and f.options.keys and f.translate_store is not None:
+                    result.translate(j, f.translate_store.translate_ids)
+        elif isinstance(result, list) and result and isinstance(result[0], GroupCount):
             for gc in result:
                 for fr in gc.group:
                     f = idx.field(fr.field)

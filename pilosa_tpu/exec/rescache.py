@@ -164,6 +164,7 @@ def result_nbytes(value: Any) -> int:
     from pilosa_tpu.core.row import Row
     from pilosa_tpu.exec.result import (
         GroupCount,
+        GroupCounts,
         PairField,
         PairsField,
         RowIDs,
@@ -201,6 +202,8 @@ def result_nbytes(value: Any) -> int:
         if value.keys is not None:
             n += sum(56 + len(k) for k in value.keys)
         return n
+    if isinstance(value, GroupCounts):
+        return value.nbytes
     if isinstance(value, GroupCount):
         return 64 + sum(
             64 + len(fr.field) + len(fr.row_key) for fr in value.group

@@ -4111,160 +4111,83 @@ class TPUBackend:
                     return None
         return len(ops)
 
+    @staticmethod
+    def _group_candidates(starts, child_rows, rs):
+        """Per field, the candidate row ids in the order the reference
+        iterator visits them (a child's pre-computed Rows, else every row
+        from `previous` + 1), as int64 arrays. A row at or past the
+        stack's height holds no bit, so it is left out here."""
+        cand = []
+        for lo, rows, height in zip(starts, child_rows, rs):
+            if rows is None:
+                cand.append(np.arange(min(lo, height), height, dtype=np.int64))
+                continue
+            c = np.fromiter(rows, dtype=np.uint64, count=len(rows))
+            cand.append(
+                c[(c >= min(lo, height)) & (c < height)].astype(np.int64)
+            )
+        return cand
+
+    @staticmethod
+    def _group_cells(fields, tensor, index, rows, cap):
+        """The non-empty groups of `tensor` (one axis a field, in child
+        order) restricted to `index` (per axis, positions in candidate
+        order; `rows` the row ids they stand for), as GroupCounts.
+        np.nonzero walks the restricted tensor in C order, first axis
+        slowest, which is the reference groupByIterator's odometer
+        (executor.go:3063); the first `cap` cells are exactly where its
+        loop would have stopped."""
+        from pilosa_tpu.exec.result import GroupCounts
+
+        sub = tensor[np.ix_(*index)]
+        hit = np.nonzero(sub > 0)
+        if cap is not None:
+            hit = tuple(h[:cap] for h in hit)
+        return GroupCounts(
+            [name for name, _ in fields],
+            np.stack([r[h] for r, h in zip(rows, hit)], axis=1),
+            sub[hit].astype(np.int64),
+        )
+
     def _group_enumerate(self, fields, starts, child_rows, rs, stats_np, n,
                          cap=None):
         """Candidate enumeration over the group stats (tensor or table),
-        matching the reference groupByIterator's ordering. Stops after
-        `cap` nonzero groups when set: the executor's limit+offset bound
-        is a prefix of the odometer order, so early exit is exact."""
-        from pilosa_tpu.exec.result import FieldRow, GroupCount
-
-        cand = []
-        for i in range(n):
-            if child_rows[i] is not None:
-                cand.append([r for r in child_rows[i] if r >= starts[i]])
-            else:
-                cand.append(list(range(starts[i], rs[i])))
-        out = []
-        full = cap if cap is not None else float("inf")
-        if n == 1:
-            for a in cand[0]:
-                v = int(stats_np[a]) if a < rs[0] else 0
-                if v > 0:
-                    out.append(GroupCount([FieldRow(fields[0][0], a)], v))
-                    if len(out) >= full:
-                        return out
-        elif n == 2:
-            for a in cand[0]:
-                for b in cand[1]:
-                    v = int(stats_np[a, b]) if (a < rs[0] and b < rs[1]) else 0
-                    if v > 0:
-                        out.append(
-                            GroupCount(
-                                [FieldRow(fields[0][0], a), FieldRow(fields[1][0], b)], v
-                            )
-                        )
-                        if len(out) >= full:
-                            return out
-        else:
-            # N-field odometer: the tensor's k axis runs over fields 3..n
-            # (last fastest — the tile odometer's decomposition order),
-            # while enumeration order is child order (first field
-            # outermost), matching the reference groupByIterator
-            # (executor.go:3063).
-            import itertools
-
-            extra_rs = rs[2:]
-            for a in cand[0]:
-                for b in cand[1]:
-                    if not (a < rs[0] and b < rs[1]):
-                        continue
-                    for extra in itertools.product(*cand[2:]):
-                        if any(e >= extra_rs[t] for t, e in enumerate(extra)):
-                            continue
-                        k = 0
-                        for t, e in enumerate(extra):
-                            k = k * extra_rs[t] + e
-                        v = int(stats_np[k, a, b])
-                        if v > 0:
-                            out.append(
-                                GroupCount(
-                                    [
-                                        FieldRow(fields[0][0], a),
-                                        FieldRow(fields[1][0], b),
-                                    ]
-                                    + [
-                                        FieldRow(fields[2 + t][0], e)
-                                        for t, e in enumerate(extra)
-                                    ],
-                                    v,
-                                )
-                            )
-                            if len(out) >= full:
-                                return out
-        return out
+        matching the reference groupByIterator's ordering. Keeps the
+        first `cap` nonzero groups when set: the executor's limit+offset
+        bound is a prefix of the odometer order, so the cut is exact."""
+        cand = self._group_candidates(starts, child_rows, rs)
+        if n >= 3:
+            # The tensor's k axis runs over fields 3..n (last fastest —
+            # the tile odometer's decomposition order), while enumeration
+            # order is child order (first field outermost).
+            stats_np = np.moveaxis(
+                stats_np.reshape(*rs[2:], rs[0], rs[1]), (-2, -1), (0, 1)
+            )
+        return self._group_cells(fields, stats_np, cand, cand, cap)
 
     def _group_enumerate_live(self, fields, starts, child_rows, rs,
                               live_rows, stats_live, n, cap=None):
-        """Streamed enumeration over the PRUNED group tensor
-        [K_live, Rf, Rg] (ISSUE 17): nonzero extraction runs per
-        (a-row × combo-chunk) slice in enumeration order — first field
-        outermost, extras-odometer (last fastest) innermost — so the
-        full dense product tensor never materializes on the host and a
-        `cap` (limit+offset) exits after the first slices that fill it.
-        Combinations pruned before dispatch are genuinely absent here:
-        they contained a globally-empty row, so their count is zero and
-        the reference iterator would skip them too."""
-        from pilosa_tpu.exec.result import FieldRow, GroupCount
-
-        cand = []
-        for i in range(n):
-            if child_rows[i] is not None:
-                cand.append([r for r in child_rows[i] if r >= starts[i]])
-            else:
-                cand.append(list(range(starts[i], rs[i])))
-        cand_a = [a for a in cand[0] if a < rs[0]]
-        cand_b = np.asarray([b for b in cand[1] if b < rs[1]], dtype=np.int64)
-        # Per extra field: the candidate rows that are live, with their
-        # position in the live row list (the tile odometer runs over
-        # live-list POSITIONS; enumeration preserves CANDIDATE order,
-        # exactly like the dense path's itertools.product over cand).
-        dims = [len(lr) for lr in live_rows]
-        pos_lists = []
-        row_lists = []
-        for t in range(n - 2):
-            lookup = {int(r): p for p, r in enumerate(live_rows[t])}
-            keep = [
-                (lookup[r], r) for r in cand[2 + t]
-                if r < rs[2 + t] and r in lookup
-            ]
-            pos_lists.append(np.asarray([p for p, _ in keep], dtype=np.int64))
-            row_lists.append(np.asarray([r for _, r in keep], dtype=np.int64))
-        if (
-            not cand_a
-            or cand_b.size == 0
-            or any(p.size == 0 for p in pos_lists)
-            or stats_live.shape[0] == 0
-        ):
-            return []
-        # Flat live-tensor index for every candidate combination, in
-        # extras-odometer enumeration order, plus the combination's
-        # per-field row ids for result assembly.
-        grids = np.meshgrid(*pos_lists, indexing="ij")
-        flat = None
-        for t, gpos in enumerate(grids):
-            flat = gpos if flat is None else flat * dims[t] + gpos
-        flat = flat.ravel()
-        extra_rows = [
-            g.ravel() for g in np.meshgrid(*row_lists, indexing="ij")
-        ]
-        sel = stats_live[flat]  # [M, Rf, Rg] — bounded by the live tensor
-        out = []
-        full = cap if cap is not None else float("inf")
-        fname_a, fname_b = fields[0][0], fields[1][0]
-        enames = [fields[2 + t][0] for t in range(n - 2)]
-        for a in cand_a:
-            # [M, B] slice for this a-row; transpose so nonzero walks
-            # b-major then combo (the odometer order within fixed a).
-            arr = sel[:, a][:, cand_b].T  # [B, M]
-            bi, mi = np.nonzero(arr)
-            if bi.size == 0:
-                continue
-            vals = arr[bi, mi]
-            for j in range(bi.size):
-                m = int(mi[j])
-                frs = [
-                    FieldRow(fname_a, int(a)),
-                    FieldRow(fname_b, int(cand_b[bi[j]])),
-                ]
-                frs.extend(
-                    FieldRow(enames[t], int(extra_rows[t][m]))
-                    for t in range(n - 2)
-                )
-                out.append(GroupCount(frs, int(vals[j])))
-                if len(out) >= full:
-                    return out
-        return out
+        """Enumeration over the PRUNED group tensor [K_live, Rf, Rg]
+        (ISSUE 17), whose k axis is an odometer over POSITIONS in each
+        extra field's live-row list: an extra field's candidates are
+        those of its rows that are live, in candidate order, each with
+        its position. Combinations pruned before dispatch are genuinely
+        absent here: they contained a globally-empty row, so their count
+        is zero and the reference iterator would skip them too."""
+        cand = self._group_candidates(starts, child_rows, rs)
+        index = cand[:2]
+        rows = cand[:2]
+        for c, lr, height in zip(cand[2:], live_rows, rs[2:]):
+            pos = np.full(height, -1, dtype=np.int64)
+            pos[np.asarray(lr, dtype=np.int64)] = np.arange(len(lr))
+            at = pos[c]
+            index.append(at[at >= 0])
+            rows.append(c[at >= 0])
+        tensor = np.moveaxis(
+            stats_live.reshape(*(len(lr) for lr in live_rows), rs[0], rs[1]),
+            (-2, -1), (0, 1),
+        )
+        return self._group_cells(fields, tensor, index, rows, cap)
 
     # -- generic batched scan path -----------------------------------------
 

@@ -354,6 +354,7 @@ def encode_query_result(r) -> bytes:
     from pilosa_tpu.core.row import Row
     from pilosa_tpu.exec.result import (
         GroupCount,
+        GroupCounts,
         PairField,
         PairsField,
         RowIDs,
@@ -396,7 +397,9 @@ def encode_query_result(r) -> bytes:
         for k in getattr(r, "keys", None) or []:
             body += _encode_string(2, k)
         out += _encode_bytes(9, body)
-    elif isinstance(r, list) and (not r or isinstance(r[0], GroupCount)):
+    elif isinstance(r, GroupCounts) or (
+        isinstance(r, list) and (not r or isinstance(r[0], GroupCount))
+    ):
         out += _encode_tag(6, 0) + _encode_varint(QUERY_RESULT_GROUPCOUNTS)
         for gc in r:
             gbody = b""

@@ -28,6 +28,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from pilosa_tpu.utils.stats import global_stats
+
 #: Powers of ten covering the uint64 range (10^19 < 2^64 < 10^20).
 _POW10 = np.array([10 ** k for k in range(20)], dtype=np.uint64)
 
@@ -41,27 +43,18 @@ _LUT100 = np.array(
 )
 
 
-def encode_uints(a: np.ndarray) -> bytes:
-    """Non-negative integer array -> ASCII b"1, 2, 3" (no brackets),
-    byte-identical to ", ".join(str(int(v))...). Vectorized: every value
-    renders fixed-width (two digits per divide pass via the _LUT100
-    table), then one row-major boolean selection strips the leading
-    zeros and splices the ", " separators — no PyLong boxing, no
-    per-element str()."""
+def _digits(a: np.ndarray) -> tuple:
+    """Non-negative integer array [n] -> its decimal digits as a _splice
+    column: uint8[n, w] of ASCII digits, right-aligned and zero-padded to
+    the widest value's even width, and each value's own decimal width.
+    Two digits per divide pass via the _LUT100 table — no PyLong boxing,
+    no per-element str()."""
     a = np.ascontiguousarray(a, dtype=np.uint64)
-    n = a.size
-    if n == 0:
-        return b""
     # Decimal width per value = #{k : 10^k <= v}, floor 1 for v=0.
     nd = np.maximum(np.searchsorted(_POW10, a, side="right"), 1)
-    # Values < 10^10 render through signed-int64 divides (measurably
-    # faster than uint64 on this numpy); the full-range path is the
-    # same loop at width 20.
-    wide = int(a.max()) >= 10 ** 10
-    wmax = 20 if wide else 10
-    half = wmax // 2
-    mat16 = np.empty((n, half), dtype=np.uint16)
-    if wide:
+    half = (int(nd.max()) + 1) // 2
+    mat16 = np.empty((a.size, half), dtype=np.uint16)
+    if half > 5:
         d = a.copy()
         hundred = np.uint64(100)
         for j in range(half - 1, -1, -1):
@@ -69,19 +62,56 @@ def encode_uints(a: np.ndarray) -> bytes:
             mat16[:, j] = _LUT100[(d - q * hundred).astype(np.int64)]
             d = q
     else:
+        # Values < 10^10 render through signed-int64 divides (measurably
+        # faster than uint64 on this numpy).
         d = a.astype(np.int64)
         for j in range(half - 1, -1, -1):
             q = d // 100
             mat16[:, j] = _LUT100[d - q * 100]
             d = q
-    mat = np.empty((n, wmax + 2), dtype=np.uint8)
-    mat[:, :wmax] = mat16.view(np.uint8).reshape(n, wmax)
-    mat[:, wmax] = 0x2C  # ","
-    mat[:, wmax + 1] = 0x20  # " "
-    # Keep the last nd digits of each row plus the separator pair; the
-    # boolean selection is row-major, so per-value byte order holds.
-    mask = np.arange(wmax + 2)[None, :] >= (wmax - nd)[:, None]
-    return mat[mask].tobytes()[:-2]
+    return mat16.view(np.uint8).reshape(a.size, 2 * half), nd, True
+
+
+def _splice(n: int, parts: list) -> bytes:
+    """n rows of a template -> their concatenated bytes. A part is either
+    constant bytes, the same in every row, or a (uint8[n, w], lengths[n],
+    right_aligned) column of which each row keeps `lengths` bytes. One
+    [n, W] byte matrix, one row-major boolean selection: per-row byte
+    order holds."""
+    width = sum(
+        len(p) if isinstance(p, bytes) else p[0].shape[1] for p in parts
+    )
+    mat = np.empty((n, width), dtype=np.uint8)
+    mask = np.empty((n, width), dtype=bool)
+    at = 0
+    for p in parts:
+        if isinstance(p, bytes):
+            mat[:, at:at + len(p)] = np.frombuffer(p, dtype=np.uint8)
+            mask[:, at:at + len(p)] = True
+            at += len(p)
+            continue
+        col, lengths, right = p
+        w = col.shape[1]
+        mat[:, at:at + w] = col
+        if right:
+            np.greater_equal(
+                np.arange(w), (w - lengths)[:, None], out=mask[:, at:at + w]
+            )
+        else:
+            np.less(np.arange(w), lengths[:, None], out=mask[:, at:at + w])
+        at += w
+    return mat[mask].tobytes()
+
+
+def encode_uints(a: np.ndarray) -> bytes:
+    """Non-negative integer array -> ASCII b"1, 2, 3" (no brackets),
+    byte-identical to ", ".join(str(int(v))...): every value renders
+    fixed-width, then one selection strips the leading zeros and splices
+    the ", " separators."""
+    a = np.asarray(a)
+    if a.size == 0:
+        return b""
+    return _splice(a.size, [_digits(a), b", "])[:-2]
 
 
 def encode_varints(a: np.ndarray) -> bytes:
@@ -159,12 +189,52 @@ def _group_count(gc) -> bytes:
     return b'{"group": [' + b", ".join(rows) + b'], "count": %d}' % gc.count
 
 
+def _strings(ss: list[bytes]) -> tuple:
+    """Per-row byte strings -> a left-aligned _splice column."""
+    col = np.array(ss, dtype=np.bytes_)
+    return (
+        col.view(np.uint8).reshape(len(ss), col.dtype.itemsize),
+        np.char.str_len(col),
+        False,
+    )
+
+
+def _group_counts(r) -> bytes:
+    """A columnar GroupBy answer (exec/result.py GroupCounts) -> the JSON
+    list _group_count gives group by group: per field one constant
+    prefix and the id column's digits, spliced for all groups at once. A
+    field that carries row keys renders its value row by row (a key is a
+    Python string to escape; a row without one keeps its rowID)."""
+    n = len(r)
+    if n == 0:
+        return b"[]"
+    global_stats.with_tags("path:columnar").count("group_rows_encoded_total", n)
+    parts: list = []
+    lead = b'{"group": [{"field": '
+    for j, name in enumerate(r.fields):
+        ids = r.rows[:, j]
+        if r.keys[j] is None:
+            parts += [lead + _string(name) + b', "rowID": ', _digits(ids)]
+        else:
+            parts += [
+                lead + _string(name) + b", ",
+                _strings([
+                    b'"rowKey": ' + _string(k) if k else b'"rowID": %d' % i
+                    for k, i in zip(r.keys[j], ids)
+                ]),
+            ]
+        lead = b'}, {"field": '
+    parts += [b'}], "count": ', _digits(r.counts), b"}, "]
+    return b"[" + _splice(n, parts)[:-2] + b"]"
+
+
 def encode_result(r: Any, exclude_columns: bool = False) -> bytes:
     """One executor result -> its JSON fragment, byte-identical to
     json.dumps(server/api.py _encode_result(r, exclude_columns))."""
     from pilosa_tpu.core.row import Row
     from pilosa_tpu.exec.result import (
         GroupCount,
+        GroupCounts,
         PairField,
         PairsField,
         RowIDs,
@@ -195,6 +265,8 @@ def encode_result(r: Any, exclude_columns: bool = False) -> bytes:
             + encode_uints(np.asarray(list(r), dtype=np.uint64))
             + b"]}"
         )
+    if isinstance(r, GroupCounts):
+        return _group_counts(r)
     if isinstance(r, GroupCount):
         return _group_count(r)
     from pilosa_tpu.exec.result import result_to_json

@@ -220,6 +220,41 @@ class TestQueryBytesByteCompat:
         got = api.query_bytes("i", q)
         assert got == want, q
 
+    @pytest.mark.parametrize("q", [
+        "GroupBy(Rows(a), Rows(b), Rows(c))",
+        "GroupBy(Rows(a), Rows(b), Rows(c), limit=500, offset=1200)",
+        "GroupBy(Rows(a), Rows(b))TopN(a)GroupBy(Rows(c))",
+    ])
+    def test_device_groupby_of_thousands_of_groups(self, q):
+        """The device path's columnar GroupBy answer (PR 31): a few
+        thousand groups spliced by template, against the dict path."""
+        from pilosa_tpu.exec.result import GroupCounts
+        from pilosa_tpu.exec.tpu import TPUBackend
+
+        h = Holder(None).open()
+        try:
+            idx = h.create_index("i")
+            rng = np.random.default_rng(31)
+            for name, nrows, per_row in (("a", 6, 60000), ("b", 9, 60000),
+                                         ("c", 48, 30000)):
+                f = idx.create_field(name)
+                for row in range(nrows):
+                    cols = np.unique(rng.integers(
+                        0, 2 * SHARD_WIDTH, per_row, dtype=np.uint64
+                    ))
+                    f.import_bits(
+                        np.full(cols.size, row, dtype=np.uint64), cols
+                    )
+            api = API(h, Executor(h, backend=TPUBackend(h)))
+            want = (json.dumps(api.query("i", q)) + "\n").encode()
+            assert api.query_bytes("i", q) == want
+            first = api.query_results("i", q)[0][0]
+            assert isinstance(first, GroupCounts)
+            assert q.startswith("GroupBy(Rows(a), Rows(b))") or len(first) >= 500
+            assert api.query_bytes("i", q) == API(h, Executor(h)).query_bytes("i", q)
+        finally:
+            h.close()
+
     def test_exclude_columns(self, holder):
         api = API(holder, Executor(holder))
         kw = dict(exclude_columns=True)

@@ -174,6 +174,9 @@ ALLOWED_TAG_KEYS = {
     "shape",   # canonical-PQL shape fingerprint (pql/ast.py shape_key:
                # structure only — call vocabulary x schema field names;
                # literals never survive into the key)
+    "path",    # which of two code paths did the work (two literals at
+               # the call sites: columnar/objects); never a file or URL
+               # path
 }
 
 #: Variable names that smell like raw request content. A tag VALUE
