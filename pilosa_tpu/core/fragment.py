@@ -476,7 +476,7 @@ class Fragment:
         # Ring of recent single-bit mutations (version, row, local_col,
         # sign) — the exact deltas the TPU backend's host stats tables
         # apply per write epoch instead of re-deriving whole shard slabs
-        # (exec/tpu.py _pair_try_incremental). Lazy: bulk-loaded
+        # (exec/tiers.py shard_delta). Lazy: bulk-loaded
         # fragments that never see point writes pay nothing.
         self.bit_ops: Optional[deque] = None
         # BSI twin: recent value mutations (version, old_present,

@@ -140,7 +140,7 @@ class View:
         # shards the walk cost ~1.8 ms x3 aggregate kinds per write
         # epoch, the bench minmax churn leg's dominant cost (r5).
         # Journal-complete since r7: every serving tier consumes it
-        # (Sum/Min/Max, pair, TopN, GroupN — exec/tpu.py
+        # (Sum/Min/Max, pair, TopN, GroupN — exec/tiers.py
         # _epoch_versions). Run-compacted since r8 (ISSUE r8 tentpole
         # 4): contiguous bumps of the SAME shard extend one run instead
         # of appending entries, so a sustained per-fragment import storm

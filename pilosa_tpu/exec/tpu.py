@@ -59,6 +59,19 @@ from pilosa_tpu.core.row import Row
 from pilosa_tpu.core.timequantum import parse_time, views_by_time_range
 from pilosa_tpu.core.view import VIEW_STANDARD, bsi_view_name
 from pilosa_tpu.exec.cpu import CPUBackend, NotFoundError, QueryError
+from pilosa_tpu.exec.tiers import (  # noqa: F401 — the _names: tests import them from here
+    GroupNRows,
+    PairRows,
+    RowCountRows,
+    TierEntry,
+    TierTable,
+    VersionWalks,
+    _host_slab_groupn,
+    _host_slab_pair_flat,
+    _pack_confirmed,
+    fingerprint,
+    refresh_entry,
+)
 from pilosa_tpu.ops.blocks import (
     ROW_PAD,
     WORDS_PER_SHARD,
@@ -972,144 +985,6 @@ class _StackedBlocks:
             self._ledger.clear()
 
 
-class _PairEntry:
-    """One field pair's cached sufficient statistics.
-
-    stats: in-flight device array right after a sweep (per-shard
-    [S, D] — sharded over the mesh axis when meshed — or summed totals
-    [D] past the retention gate), replaced by the int64 host totals on
-    first resolve. pershard: the resident int32[S, D] table that makes
-    write epochs cheap on one chip or many
-    — see _pair_try_incremental. gen_*: the views' O(1) data generations
-    at derivation time — the fast freshness gate (unchanged generation
-    means no write anywhere under the view, so hits skip the O(shards)
-    version walk). vers_*: per-shard (uid, version) the stats were
-    derived from — the fine-grained diff consulted only when a
-    generation moved; freshness never requires touching the device
-    stack."""
-
-    __slots__ = ("shards", "rf", "rg", "stats", "pershard",
-                 "gen_f", "gen_g", "vers_f", "vers_g")
-
-    def __init__(self, shards, rf, rg, stats, pershard,
-                 gen_f, gen_g, vers_f, vers_g):
-        self.shards = shards
-        self.rf = rf
-        self.rg = rg
-        self.stats = stats
-        self.pershard = pershard
-        self.gen_f = gen_f
-        self.gen_g = gen_g
-        self.vers_f = vers_f
-        self.vers_g = vers_g
-
-
-class _GroupNEntry:
-    """One N>=3 field tuple's cached group tensor: totals int64[K,rf,rg]
-    served to queries, the per-shard int32[S, K*rf*rg] table that
-    absorbs write epochs, the per-field per-shard (uid, version) tuples
-    the table was derived from, and the row counts (padded stack
-    heights) fixing the tensor geometry."""
-
-    __slots__ = ("cfp", "stats", "pershard", "rs", "vers")
-
-    def __init__(self, cfp, stats, pershard, rs, vers):
-        self.cfp = cfp
-        self.stats = stats
-        self.pershard = pershard
-        self.rs = rs
-        self.vers = vers
-
-
-def _host_slab_pair_flat(fslab: np.ndarray, gslab: np.ndarray) -> np.ndarray:
-    """One shard's pair-stats row [rf*rg + rf + rg] from host-packed
-    slabs — must agree bit-for-bit with ops.kernels.pair_stats_pershard
-    on the same slabs (differentially tested in test_tpu.py), because a
-    host-updated table row sits next to device-swept rows.
-
-    The broadcast AND is chunked over the word axis so the temporary
-    stays ~64 MiB: unchunked it is rf*rg*W*4 bytes — 8 GiB per shard at
-    the rf*rg = 2^16 bound the dispatch path allows."""
-    rf, w = fslab.shape
-    rg = gslab.shape[0]
-    chunk = max(1, (64 << 20) // max(1, rf * rg * 4))
-    pair = np.zeros((rf, rg), dtype=np.int64)
-    for c0 in range(0, w, chunk):
-        blk = fslab[:, None, c0 : c0 + chunk] & gslab[None, :, c0 : c0 + chunk]
-        pair += np.bitwise_count(blk).sum(axis=-1, dtype=np.int64)
-    cf = np.bitwise_count(fslab).sum(axis=-1, dtype=np.int64)
-    cg = np.bitwise_count(gslab).sum(axis=-1, dtype=np.int64)
-    return np.concatenate([pair.ravel(), cf, cg]).astype(np.int32)
-
-
-def _host_slab_row_counts(slab: np.ndarray) -> np.ndarray:
-    """Per-row popcounts of one packed shard slab (the TopN rank-vector
-    contribution of that shard)."""
-    return np.bitwise_count(slab).sum(axis=-1, dtype=np.int64)
-
-
-def _host_slab_groupn(slabs: list, rs: list) -> np.ndarray:
-    """One shard's N-field group tensor row, flat int32[K*rf*rg] — must
-    agree bit-for-bit with ops.kernels.nary_stats_pershard on the same
-    slabs (differentially tested in test_tpu.py) because a host-updated
-    table row sits next to device-swept rows. Same k decomposition as
-    the kernel: odometer over extras, LAST field fastest."""
-    rf, rg = rs[0], rs[1]
-    extra_rs = rs[2:]
-    k_total = 1
-    for rh in extra_rs:
-        k_total *= rh
-    fslab, gslab = slabs[0], slabs[1]
-    w = fslab.shape[1]
-    out = np.empty((k_total, rf, rg), dtype=np.int64)
-    chunk = max(1, (64 << 20) // max(1, rf * rg * 4))
-    for k in range(k_total):
-        m = None
-        rem = k
-        for t in range(len(extra_rs) - 1, -1, -1):
-            row = slabs[2 + t][rem % extra_rs[t]]
-            rem //= extra_rs[t]
-            m = row if m is None else (m & row)
-        fm = fslab & m[None, :]
-        pair = np.zeros((rf, rg), dtype=np.int64)
-        for c0 in range(0, w, chunk):
-            blk = fm[:, None, c0 : c0 + chunk] & gslab[None, :, c0 : c0 + chunk]
-            pair += np.bitwise_count(blk).sum(axis=-1, dtype=np.int64)
-        out[k] = pair
-    return out.reshape(-1).astype(np.int32)
-
-
-#: Recorded-version sentinel: never equal to any live (uid, version), so
-#: the next epoch's diff marks the shard dirty and the delta tier's
-#: uid check routes it to a slab re-derive. Stored whenever captured
-#: content could not be confirmed against a version (a write raced the
-#: capture) — recording an OLDER version than the content would make
-#: the non-idempotent delta replay double-apply ops.
-_VERS_STALE = ("stale", -1)
-
-
-def _pack_confirmed(fr, n_rows: int):
-    """Pack a fragment slab with its (uid, version) CONFIRMED unchanged
-    across the pack — a mid-pack write re-packs, so the returned version
-    describes exactly the returned content (the delta tier replays ops
-    on top of it and must not double-apply).
-
-    The recheck holds fr.lock: writers mutate storage BEFORE bumping
-    version inside their fr.lock critical section (fragment.py set_bit),
-    so an unlocked recheck could observe the pre-write version for
-    content the pack already saw. Acquiring the lock serializes with
-    the writer — a mid-pack write has bumped version by the time the
-    locked recheck runs, forcing the retry."""
-    while True:
-        with fr.lock:
-            v = (fr.uid, fr.version)
-        slab = pack_fragment(fr, n_rows=n_rows)
-        with fr.lock:
-            confirmed = (fr.uid, fr.version) == v
-        if confirmed:
-            return slab, v
-
-
 # ---------------------------------------------------------------------------
 # trace-time evaluation of a spec tree
 # ---------------------------------------------------------------------------
@@ -1562,7 +1437,7 @@ class _ProgramLedger:
         }
 
 
-class TPUBackend:
+class TPUBackend(VersionWalks):
     """Drop-in replacement for CPUBackend with device execution.
 
     Anything not device-lowered falls back to the CPU oracle — results are
@@ -1584,7 +1459,7 @@ class TPUBackend:
         # Fallback-counter state before the block store: _StackedBlocks
         # routes its mesh-tier degradations (reason=mesh_*) through
         # _count_device_fallback, which reads these.
-        self.stats = global_stats
+        VersionWalks.__init__(self, global_stats)
         self._fallback_logged: set = set()
         self.logger = None
         self.blocks = _StackedBlocks(
@@ -1597,41 +1472,45 @@ class TPUBackend:
         # fed by _counted_launch, so it covers exactly the launch stream
         # device_launches_total counts.
         self.programs = _ProgramLedger(self.stats)
-        # Host-resident pair-stats cache: (index, fa, fb, shards) ->
-        # (fblock, gblock, flat stats). Block identity is the freshness
-        # token (see _pair_batch_dispatch); one entry per field pair, so
-        # replacing it also drops the strong ref keeping a stale stack
-        # alive. Guarded: resolvers run on server worker threads.
-        self._pair_cache: dict = {}
-        # Host TopN rank-vector cache: (index, field) -> ((shards, view
-        # generation), counts[R]) — the reference's rank cache idea with
-        # exact device recompute per write epoch (cache.go:136).
-        self._topn_cache: dict = {}
-        # Unfiltered BSI aggregate results (Sum/Min/Max): tiny scalars
-        # cached per (kind, index, field) against the BSI view's write
-        # epoch — same invalidation discipline as the pair/TopN caches.
-        self._agg_cache: dict = {}
+        # The serving tiers (exec/tiers.py): host tables that answer
+        # with no device work and absorb write epochs on the host.
+        # Pair statistics, (index, fa, fb) -> the flat
+        # [rf*rg | cf | cg] totals: one entry per field pair, so
+        # replacing it also drops the in-flight device array of the
+        # sweep before.
+        self._pair_cache = TierTable(
+            MAX_PAIR_CACHE_ENTRIES,
+            on_hit=lambda: self.stats.count("pair_stats_cache_hits_total"),
+        )
+        # TopN rank vector, (index, field) -> counts[R] — the
+        # reference's rank cache idea with exact device recompute per
+        # write epoch (cache.go:136).
+        self._topn_cache = TierTable(
+            MAX_PAIR_CACHE_ENTRIES,
+            on_hit=lambda: self.stats.count("topn_cache_hits_total"),
+        )
+        # Unfiltered BSI aggregates (Sum/Min/Max), tiny scalars per
+        # (kind, index, field) against the BSI view's write epoch, and
+        # the group tensors of GroupBys no maintained table answers.
+        self._agg_cache = TierTable(
+            MAX_PAIR_CACHE_ENTRIES,
+            on_hit=lambda: self.stats.count("agg_cache_hits_total"),
+            on_change=self._agg_cache_charge,
+        )
         # Maintained N>=3 group tensors (VERDICT r4 #1b): per-shard
         # [S, K*Rf*Rg] tables + per-field versions, so a write epoch
         # splices the affected shard rows on the host instead of
-        # re-dispatching the nary sweep — same two-tier (delta/slab)
-        # design as the pair table. _GroupNEntry values.
-        self._groupn_cache: dict = {}
-        # Single-flight latches for stats refreshes (pair + TopN keys):
-        # under write churn, 16 serving threads missing the same epoch
-        # would each redo the same host update on this one-core host (a
-        # 16x thundering herd that ran the dirty set away into repeated
-        # device sweeps); instead one thread refreshes, the rest wait
-        # and re-check.
-        self._stats_updating: dict = {}
-        self._pair_lock = threading.Lock()
+        # re-dispatching the tiled sweep.
+        self._groupn_cache = TierTable(
+            MAX_PAIR_CACHE_ENTRIES,
+            on_hit=lambda: self.stats.count("groupn_cache_hits_total"),
+        )
         # Pair-plan memo: parse-cache hits serve SHARED call trees, so a
         # batch's plan is keyed by the calls' identities. Cached entries
         # pin the call objects, so a key match implies the same objects
         # (a live object's id cannot be reused). Re-planning every
         # request cost ~12% of serving CPU.
-        self._plan_cache: dict = {}
-        self._plan_lock = threading.Lock()
+        self._plan_cache = TierTable(512)
         # Background-compile the fixed-shape sparse-upload programs so
         # a cold stack build never pays their XLA compile on its
         # critical path (ops/sparse.py; idempotent per device). Under a
@@ -1729,120 +1608,6 @@ class TPUBackend:
         if f is None:
             raise NotFoundError(f"field not found: {name}")
         return f
-
-    def _count_version_walk(self, kind: str, tier: str, n_shards: int) -> None:
-        """Freshness-walk attribution (ISSUE r6): every per-shard version
-        read is counted so the O(S) full walks at 954 shards are visible
-        on /metrics (version_walk_total / version_walk_shards_total,
-        tagged kind=full|journal and the stats tier that paid for it)
-        and in the active query's /debug/queries counters. The journal
-        tier's shard count is the dirty set — the O(dirty) claim the
-        bench and tests assert instead of assuming."""
-        st = self.stats.with_tags(f"kind:{kind}", f"tier:{tier}")
-        st.count("version_walk_total")
-        st.count("version_walk_shards_total", n_shards)
-        prof = current_profile()
-        prof.incr(f"version_walk_{kind}")
-        prof.incr(f"version_walk_{kind}_shards", n_shards)
-        ex = getattr(prof, "explain", None)
-        if ex is not None:
-            ex._node().setdefault("freshness", []).append(
-                {"walk": kind, "tier": tier, "shards": n_shards}
-            )
-
-    def _confirm_vers(self, field_obj, shards_t, recorded,
-                      view_name=VIEW_STANDARD, tier="other"):
-        """Post-capture version confirmation: any shard whose live
-        (uid, version) moved past the recorded capture version gets
-        _VERS_STALE, so the next epoch slab-rederives it instead of
-        delta-replaying ops onto content that may already include them
-        (sweeps/stack builds read fragment content after reading
-        versions; the window is small but real under churn)."""
-        live = self._live_versions(field_obj, shards_t, view_name, tier=tier)
-        if live == recorded:
-            return recorded
-        return tuple(
-            r if r == l else _VERS_STALE for r, l in zip(recorded, live)
-        )
-
-    def _confirm_vers_journal(self, field_obj, shards_t, recorded,
-                              gen_recorded, view_name=VIEW_STANDARD,
-                              tier="other"):
-        """Journal-backed post-capture confirmation: same staleness
-        contract as _confirm_vers, but O(dirty) instead of O(S) locked
-        reads (ISSUE 17 satellite — the groupn tier paid 12 full walks
-        per bench leg through _confirm_vers). Exactness: writers journal
-        the shard before bumping the fragment version inside the same
-        critical section, so any write that could make a recorded
-        version stale after generation `gen_recorded` is in
-        dirty_shards_since(gen_recorded); shards outside the dirty set
-        are untouched since capture and their recorded version is live
-        by construction. Only dirty shards take the locked read."""
-        v = field_obj.view(view_name)
-        if v is None:
-            self._count_version_walk("journal", tier, 0)
-            return tuple(None for _ in shards_t)
-        dirty = v.dirty_shards_since(gen_recorded)
-        if dirty is None:
-            # Journal horizon passed (compaction): fall back to the full
-            # locked walk — correctness over the O(dirty) fast path.
-            return self._confirm_vers(
-                field_obj, shards_t, recorded, view_name, tier=tier
-            )
-        out = list(recorded)
-        n_read = 0
-        for i, s in enumerate(shards_t):
-            if s not in dirty:
-                continue
-            fr = v.fragment(s)
-            if fr is None:
-                live = None
-            else:
-                n_read += 1
-                with fr.lock:
-                    live = (fr.uid, fr.version)
-            if out[i] != live:
-                out[i] = _VERS_STALE
-        self._count_version_walk("journal", tier, n_read)
-        return tuple(out)
-
-    def _live_versions(self, field_obj, shards_t, view_name=VIEW_STANDARD,
-                       tier="other"):
-        """Per-shard (uid, version) read straight from the live fragments
-        — the write-epoch key the host stats caches compare against.
-        Reading the LIVE versions (not the resident stack's) is what lets
-        pair/TopN batches resolve entirely on the host under write churn:
-        the device stack can stay stale until a query actually needs it
-        (every stack consumer re-checks its own fingerprint).
-
-        Each read holds fr.lock: writers mutate storage before bumping
-        version inside their critical section, so an unlocked read can
-        return a pre-write version for post-write content. Locked reads
-        serialize with the writer, which makes _confirm_vers (built on
-        this) a true post-capture barrier — a capture that raced a write
-        is always seen as moved and recorded _VERS_STALE.
-
-        This is the FULL walk — O(len(shards_t)) locked reads — and is
-        counted as such per tier (by locked reads actually taken, so a
-        missing view or absent fragments don't inflate the accounting);
-        _epoch_versions is the journal-backed O(dirty) alternative for
-        epoch updates."""
-        v = field_obj.view(view_name)
-        if v is None:
-            self._count_version_walk("full", tier, 0)
-            return tuple(None for _ in shards_t)
-        out = []
-        n_read = 0
-        for s in shards_t:
-            fr = v.fragment(s)
-            if fr is None:
-                out.append(None)
-            else:
-                n_read += 1
-                with fr.lock:
-                    out.append((fr.uid, fr.version))
-        self._count_version_walk("full", tier, n_read)
-        return tuple(out)
 
     def _build(self, index: str, c: Call, shards: tuple[int, ...],
                blocks: list, scalars: list):
@@ -2539,17 +2304,11 @@ class TPUBackend:
         idx = self.holder.index(index)
         fields_key = tuple(idx.fields) if idx is not None else ()
         key = (index, fields_key, tuple(map(id, calls)))
-        with self._plan_lock:
-            hit = self._plan_cache.get(key)
-            if hit is not None:
-                self._plan_cache[key] = self._plan_cache.pop(key)  # LRU
-                return hit[0]
+        hit = self._plan_cache.hit(key, None)
+        if hit is not None:
+            return hit.value
         plan = self._pair_batch_plan(index, calls)
-        with self._plan_lock:
-            self._plan_cache.pop(key, None)
-            self._plan_cache[key] = (plan, tuple(calls))
-            while len(self._plan_cache) > 512:
-                self._plan_cache.pop(next(iter(self._plan_cache)))
+        self._plan_cache.store(key, TierEntry(None, plan, extra=tuple(calls)))
         return plan
 
     def _pair_batch_plan(self, index: str, calls: list[Call]):
@@ -2603,7 +2362,7 @@ class TPUBackend:
         [pair_i.ravel() | cf_i | cg_i]) — one readback (~300 KiB at the
         954-shard bench shape, still a single round trip) buys the
         host table that absorbs write epochs without re-sweeping
-        (_pair_try_incremental). Under a mesh the kernel runs on each
+        (exec/tiers.py refresh_entry). Under a mesh the kernel runs on each
         device's local shard chunk and the output stays sharded
         (out_specs P(axis)); the readback gathers it so multi-chip
         serving gets the same host-maintained tables. pershard=False:
@@ -2691,108 +2450,50 @@ class TPUBackend:
         entries, fa, fb = plan
         f_obj = self._field(index, fa)
         g_obj = self._field(index, fb)
-
         # Host stats cache (the reference's rank-cache idea, cache.go:136:
         # materialize counts once, serve queries from them until writes
         # invalidate). Freshness is the LIVE per-shard fragment versions:
-        # a vers-equal hit — or a small-epoch host table update — resolves
-        # with ZERO device work, including no stack refresh; the device
-        # stack is only (re)built when a sweep is actually needed, so
-        # write churn costs O(dirty shards) numpy instead of a device
+        # a generation-equal hit — or a small-epoch host table update —
+        # resolves with ZERO device work, including no stack refresh; the
+        # device stack is only (re)built when a sweep is actually needed,
+        # so write churn costs O(dirty shards) numpy instead of a device
         # round trip per epoch. The LRU cap bounds the pair-combination
         # count for many-field indexes.
+        views = (f_obj.view(VIEW_STANDARD), g_obj.view(VIEW_STANDARD))
         ckey = (index, fa, fb)
-        # Hit gate + single-flight admission. Generations are read
-        # INSIDE the loop so a waiter re-checks against the freshest
-        # epoch; reading them before the vers walk keeps recorded keys
-        # conservatively old (a spurious re-check next batch, never
-        # staleness). Single flight: under churn, 16 serving threads
-        # missing the same epoch would each redo the same host update on
-        # this one-core host — the herd ran the dirty set away into
-        # repeated device sweeps at 100 writes/s.
-        with current_profile().phase("freshness"):
-            while True:
-                fv = f_obj.view(VIEW_STANDARD)
-                gv = g_obj.view(VIEW_STANDARD)
-                gen_f = fv.generation if fv is not None else -1
-                gen_g = gv.generation if gv is not None else -1
-                with self._pair_lock:
-                    hit = self._pair_cache.get(ckey)
-                    if (
-                        hit is not None
-                        and hit.shards == shards_t
-                        and hit.gen_f == gen_f
-                        and hit.gen_g == gen_g
-                    ):
-                        self._pair_cache[ckey] = self._pair_cache.pop(ckey)  # LRU
-                        self.stats.count("pair_stats_cache_hits_total")
-                        return functools.partial(
-                            self._pair_fetch, entries, hit, hit.rf, hit.rg
-                        )
-                    latch = self._stats_updating.get(ckey)
-                    if latch is None:
-                        self._stats_updating[ckey] = threading.Event()
-                        break
-                latch.wait(timeout=60)
-        try:
-            return self._pair_refresh(
-                index, entries, fa, fb, f_obj, g_obj, shards_t,
-                ckey, hit, gen_f, gen_g,
-            )
-        finally:
-            with self._pair_lock:
-                ev = self._stats_updating.pop(ckey, None)
-            if ev is not None:
-                ev.set()
+        ent = self._pair_cache.serve(
+            ckey, shards_t, views,
+            lambda stale, fp: self._pair_refresh(
+                index, ckey, f_obj, g_obj, views, stale, fp
+            ),
+            gate_phase="freshness",
+        )
+        return functools.partial(self._pair_fetch, entries, ent, *ent.extra)
 
-    def _pair_refresh(self, index, entries, fa, fb, f_obj, g_obj,
-                      shards_t, ckey, hit, gen_f, gen_g):
+    def _pair_refresh(self, index, ckey, f_obj, g_obj, views, stale,
+                      fp) -> TierEntry:
         """The single-flight body: host table update when possible, full
-        stack fetch + device sweep otherwise. Runs WITHOUT _pair_lock
-        (slab packing / stack builds are the slow part); the exclusive
-        updater role makes store-time re-validation unnecessary."""
-        # Per-shard version diff that tells dirty shards apart from
-        # writes outside the queried set. Journal-complete (ISSUE r7):
-        # when a previous entry recorded versions at a known generation,
-        # the view journal names the dirtied shards and only THOSE pay a
-        # locked fragment read — O(dirty), not O(all shards). The full
-        # walk remains only for cold pairs (no recorded versions) and
-        # journal-eviction windows.
+        stack fetch + device sweep otherwise."""
+        shards_t = fp[0]
         prof = current_profile()
-        hit_ok = hit is not None and hit.shards == shards_t
         with prof.phase("freshness"):
-            vers_f = self._epoch_versions(
-                f_obj, shards_t, VIEW_STANDARD,
-                hit.vers_f if hit_ok else None,
-                hit.gen_f if hit_ok else -1,
-                tier="pair",
-            )
-            vers_g = (
-                vers_f if fb == fa
-                else self._epoch_versions(
-                    g_obj, shards_t, VIEW_STANDARD,
-                    hit.vers_g if hit_ok else None,
-                    hit.gen_g if hit_ok else -1,
-                    tier="pair",
-                )
-            )
-            ent = self._pair_try_incremental(
-                hit, f_obj, g_obj, shards_t, gen_f, gen_g, vers_f, vers_g
+            live = self._tier_versions(stale, (f_obj, g_obj), shards_t, "pair")
+            ent = refresh_entry(
+                stale, fp, views, live, PairRows, self.stats,
+                self.MAX_PAIR_HOST_UPDATE_SHARDS,
             )
         if ent is not None:
-            with self._pair_lock:
-                self._pair_cache.pop(ckey, None)
-                self._pair_cache[ckey] = ent
-            return functools.partial(
-                self._pair_fetch, entries, ent, ent.rf, ent.rg
-            )
+            # Already resolved: its resolver never touches the device.
+            self._pair_cache.store(ckey, ent)
+            return ent
+        vers_f, vers_g = live
 
         # Sweep path: fetch (build/splice) the stacks, then one dispatch.
         with prof.phase("stack_fetch"):
             fblock, _, bvers_f = self._get_block_with_versions(
                 index, f_obj, shards_t
             )
-            if fb == fa:
+            if g_obj is f_obj:
                 gblock, bvers_g = fblock, bvers_f
             else:
                 gblock, _, bvers_g = self._get_block_with_versions(
@@ -2808,9 +2509,6 @@ class TPUBackend:
         # redundant re-update next epoch, never staleness).
         vers_f = bvers_f if bvers_f is not None else vers_f
         vers_g = bvers_g if bvers_g is not None else vers_g
-        # The in-flight device array is cached right away — pipelined
-        # batches and the single-flight waiters share this one sweep
-        # instead of each missing until the first resolver lands.
         self.stats.count("pair_stats_sweeps_total")
         with prof.phase("dispatch", span="pilosa.pair_stats"):
             flat = self._pair_program(pershard=pershard_ok)(fblock, gblock)
@@ -2820,17 +2518,15 @@ class TPUBackend:
         with prof.phase("freshness"):
             vers_f = self._confirm_vers(f_obj, shards_t, vers_f, tier="pair")
             vers_g = (
-                vers_f if fb == fa
+                vers_f if g_obj is f_obj
                 else self._confirm_vers(g_obj, shards_t, vers_g, tier="pair")
             )
-        ent = _PairEntry(shards_t, rf, rg, flat, None,
-                         gen_f, gen_g, vers_f, vers_g)
-        with self._pair_lock:
-            self._pair_cache.pop(ckey, None)
-            self._pair_cache[ckey] = ent
-            while len(self._pair_cache) > MAX_PAIR_CACHE_ENTRIES:
-                self._pair_cache.pop(next(iter(self._pair_cache)))
-        return functools.partial(self._pair_fetch, entries, ent, rf, rg)
+        # The in-flight device array is cached right away — pipelined
+        # batches and the single-flight waiters share this one sweep
+        # instead of each missing until the first resolver lands.
+        ent = TierEntry(fp, flat, None, (vers_f, vers_g), (rf, rg))
+        self._pair_cache.store(ckey, ent)
+        return ent
 
     def _pair_gates(self, s_pad, rf, rg):
         """Serving-path size gates for a pair sweep, shared with
@@ -2850,201 +2546,18 @@ class TPUBackend:
             return "pair sweep exceeds int32 shard bound", False
         return None, pershard_ok
 
-    def _pair_try_incremental(self, hit, f_obj, g_obj, shards_t,
-                              gen_f, gen_g, vers_f, vers_g):
-        """Absorb a write epoch on the host (VERDICT r3 #1 follow-through:
-        serving under churn must not be device-round-trip bound). When
-        the previous entry's per-shard table is resident and the epoch
-        dirtied few shards, re-derive JUST those shards' stats rows from
-        host-packed slabs and re-sum the totals — the same incremental
-        maintenance the reference's rank cache does per write
-        (cache.go:136-301), so a Set costs O(1 shard) host work instead
-        of a full stack sweep + device round trip. Returns the updated
-        _PairEntry (already resolved — its resolver never touches the
-        device), or None when a real sweep is needed (cold pair, row
-        growth past the table height, shard-set change, or too many
-        dirty shards). Host tables are mesh-agnostic — multi-chip
-        serving absorbs churn the same way (the sweep's per-shard
-        output is gathered over ICI once, cold). Runs WITHOUT
-        _pair_lock (slab packing is the slow part); the single-flight
-        updater role makes store-time re-validation unnecessary."""
-        if (
-            hit is None
-            or hit.shards != shards_t
-            or hit.pershard is None
-            or hit.vers_f is None
-            or hit.vers_g is None
-        ):
-            return None
-        dirty = [
-            i for i in range(len(shards_t))
-            if hit.vers_f[i] != vers_f[i] or hit.vers_g[i] != vers_g[i]
-        ]
-        if not dirty:
-            # Generation moved but no queried shard changed (writes
-            # outside the queried set, or under another view): re-key the
-            # same stats so the O(1) generation gate hits again.
-            return _PairEntry(shards_t, hit.rf, hit.rg, hit.stats,
-                              hit.pershard, gen_f, gen_g, vers_f, vers_g)
-        rf, rg = hit.rf, hit.rg
-        fv = f_obj.view(VIEW_STANDARD)
-        gv = g_obj.view(VIEW_STANDARD)
-        pershard = hit.pershard.copy()
-        # Two tiers per dirty shard, exact either way:
-        # 1. DELTA — the fragment's bit-op ring explains the whole epoch
-        #    as point writes on ONE side of the pair: apply each op as
-        #    cf/cg ±1 plus Rg (or Rf) membership probes against the
-        #    UNCHANGED side. ~20 us per write, so thousands of writes/s
-        #    cost nothing (the scalable tier; the slab tier's ~5 ms per
-        #    shard ran away under random-shard churn at W>=100 — dirty
-        #    sets grew faster than they drained).
-        # 2. SLAB — re-pack + popcount the whole shard slab. Bounded by
-        #    MAX_PAIR_HOST_UPDATE_SHARDS; beyond that, a device sweep
-        #    wins.
-        # Recorded versions must describe EXACTLY the content captured:
-        # slab packs are version-confirmed (_pack_confirmed), delta
-        # shards keep the walk values their op windows end at, and any
-        # unconfirmable capture records _VERS_STALE so the next epoch
-        # slab-rederives instead of delta-replaying on ambiguous
-        # baselines (replay is non-idempotent; an older-than-content
-        # version would double-apply ops).
-        vers_f_rec = list(vers_f)
-        vers_g_rec = list(vers_g)
-        slab_dirty: list[int] = []
-        n_delta_ops = 0
-        for i in dirty:
-            ops = self._pair_shard_delta(
-                hit, i, shards_t[i], fv, gv, f_obj is g_obj, pershard,
-                vers_f, vers_g,
-            )
-            if ops is None:
-                slab_dirty.append(i)
-            else:
-                n_delta_ops += ops
-        if len(slab_dirty) > self.MAX_PAIR_HOST_UPDATE_SHARDS:
-            return None
-        for i in slab_dirty:
-            s = shards_t[i]
-            fr = fv.fragment(s) if fv is not None else None
-            if fr is None:
-                fslab = np.zeros((rf, WORDS_PER_SHARD), dtype=np.uint32)
-                vers_f_rec[i] = None
-            else:
-                fslab, vers_f_rec[i] = _pack_confirmed(fr, rf)
-                if fr.max_row_id >= rf:
-                    return None  # row grew past the table height: re-sweep
-            if g_obj is f_obj:
-                gslab, vers_g_rec[i] = fslab, vers_f_rec[i]
-            else:
-                gr = gv.fragment(s) if gv is not None else None
-                if gr is None:
-                    gslab = np.zeros((rg, WORDS_PER_SHARD), dtype=np.uint32)
-                    vers_g_rec[i] = None
-                else:
-                    gslab, vers_g_rec[i] = _pack_confirmed(gr, rg)
-                    if gr.max_row_id >= rg:
-                        return None
-            pershard[i] = _host_slab_pair_flat(fslab, gslab)
-        totals = pershard.sum(axis=0, dtype=np.int64)
-        self.stats.count("pair_stats_incremental_updates_total")
-        self.stats.count("pair_stats_incremental_shards_total", len(dirty))
-        if n_delta_ops:
-            self.stats.count("pair_stats_delta_ops_total", n_delta_ops)
-        return _PairEntry(shards_t, rf, rg, totals, pershard,
-                          gen_f, gen_g, tuple(vers_f_rec), tuple(vers_g_rec))
-
-    def _pair_shard_delta(self, hit, i, shard, fv, gv, self_pair,
-                          pershard, vers_f, vers_g):
-        """Try to apply one dirty shard's epoch as exact point-write
-        deltas to pershard[i] (flat row [pair(rf*rg) | cf | cg]).
-        DUPLICATED DISCIPLINE: _groupn_shard_delta generalizes this
-        protocol to N fields — mirror any locking/version fix there.
-        Returns the op count applied, or None when the slab tier must
-        handle it: self-pair (ordering against a changing self), BOTH
-        sides changed in the window (probes against the other side must
-        see its state at op time), fragment created/recreated, row grew
-        past the table, or the ring doesn't cover the window."""
-        if self_pair:
-            return None
-        rf, rg = hit.rf, hit.rg
-        ov_f, nv_f = hit.vers_f[i], vers_f[i]
-        ov_g, nv_g = hit.vers_g[i], vers_g[i]
-        f_changed = ov_f != nv_f
-        g_changed = ov_g != nv_g
-        if f_changed and g_changed:
-            return None
-        if f_changed:
-            ov, nv = ov_f, nv_f
-            frag = fv.fragment(shard) if fv is not None else None
-            other = gv.fragment(shard) if gv is not None else None
-            n_rows, other_vers = rf, nv_g
-        else:
-            ov, nv = ov_g, nv_g
-            frag = gv.fragment(shard) if gv is not None else None
-            other = fv.fragment(shard) if fv is not None else None
-            n_rows, other_vers = rg, nv_f
-        if frag is None or ov is None or nv is None or ov[0] != nv[0]:
-            return None  # created/recreated fragment: no delta history
-        ops = frag.bit_ops_between(ov[1], nv[1])
-        if ops is None:
-            return None
-        # The probes below read the OTHER side's live storage, which the
-        # entry will record at its WALK version (other_vers): confirm
-        # the live fragment still matches it before AND after applying —
-        # a write racing the walk or the probes would bake its bit into
-        # a pair cell that the other side's own delta replays again next
-        # epoch. On conflict, revert this shard's row and let the slab
-        # tier (version-confirmed pack) capture a clean snapshot.
-        if other is None:
-            if other_vers is not None:
-                return None  # fragment vanished since the walk
-        else:
-            with other.lock:  # serialize with a mid-write bump (see _pack_confirmed)
-                moved = other_vers is None or \
-                    (other.uid, other.version) != other_vers
-            if moved:
-                return None
-        row_flat = pershard[i]
-        sw = SHARD_WIDTH
-        for _, r, c, sign in ops:
-            if r >= n_rows:
-                row_flat[:] = hit.pershard[i]
-                return None  # table height exceeded mid-window
-            if f_changed:
-                row_flat[rf * rg + r] += sign  # cf[r]
-                if other is not None:
-                    base = r * rg
-                    st = other.storage
-                    for b in range(rg):
-                        if st.contains(b * sw + c):
-                            row_flat[base + b] += sign
-            else:
-                row_flat[rf * rg + rf + r] += sign  # cg[r]
-                if other is not None:
-                    st = other.storage
-                    for a in range(rf):
-                        if st.contains(a * sw + c):
-                            row_flat[a * rg + r] += sign
-        if other is not None:
-            with other.lock:  # post-probe confirm must see any racing writer
-                moved = (other.uid, other.version) != other_vers
-            if moved:
-                row_flat[:] = hit.pershard[i]
-                return None
-        return len(ops)
-
     def _pair_fetch(self, entries, ent, rf, rg) -> list[int]:
         """Resolve stats (device array on first touch, host np after) and
         derive the batch's counts."""
         prof = current_profile()
-        if not isinstance(ent.stats, np.ndarray):
+        if not isinstance(ent.value, np.ndarray):
             with prof.phase("device_wait"):
-                self.programs.block_ready(ent.stats)
+                self.programs.block_ready(ent.value)
         with prof.phase("readback"):
             return self._pair_fetch_inner(entries, ent, rf, rg)
 
     def _pair_fetch_inner(self, entries, ent, rf, rg) -> list[int]:
-        stats = ent.stats
+        stats = ent.value
         if not isinstance(stats, np.ndarray):
             raw = np.asarray(stats)  # ONE readback for all stats
             if raw.ndim == 2:  # per-shard [S, D] (gathered when meshed)
@@ -3053,10 +2566,7 @@ class TPUBackend:
             else:  # summed totals [D] (retention gate; psum'd on mesh)
                 pershard = None
                 totals = raw.astype(np.int64)
-            with self._pair_lock:
-                if ent.stats is stats:  # idempotent: racers read back too
-                    ent.stats = totals
-                    ent.pershard = pershard
+            self._pair_cache.settle(ent, stats, totals, pershard)
         else:
             totals = stats
         return self._pair_resolve(entries, totals, rf, rg)
@@ -3141,7 +2651,8 @@ class TPUBackend:
     def _group_tile_program(self, shapes, t_slots: int, filtered: bool,
                             pershard: bool):
         """AOT-compiled tiled N-field GroupBy sweep (ISSUE 17 tentpole,
-        replacing the one-shot nary_stats whole-tensor program). Each of
+        replacing a one-shot whole-tensor program whose grid compiled
+        the combination count in). Each of
         the t_slots slots sweeps ONE live extra-row combination — picked
         in-kernel from rows_idx, with padded slots replaying slot 0
         under a zero `active` lane mask — against the full [Rf, Rg]
@@ -3149,7 +2660,7 @@ class TPUBackend:
         exact stack shapes, so the compiled-program set is
         O(log K · shapes) and device_recompiles_total stays flat across
         cardinality changes. AOT (.lower().compile()) so the cold-path
-        prewarm thread in _groupn_tensor truly compiles concurrently
+        prewarm thread in _groupn_refresh truly compiles concurrently
         with the stack fetch instead of racing jit's first-call lock."""
         assert not (pershard and filtered)
         key = ("group_tile", shapes, t_slots, filtered, pershard)
@@ -3480,15 +2991,9 @@ class TPUBackend:
         ckey = cfp = hit = payload = None
         if filter_call is None or fkey is not None:
             ckey = ("groupby", index, tuple(f for f, _ in fields), fkey)
-            cfp = (
-                shards_t,
-                tuple(
-                    (fo.view(VIEW_STANDARD).generation
-                     if fo.view(VIEW_STANDARD) is not None else -1)
-                    for _, fo in fields
-                ),
-                ffp,
-            )
+            cfp = fingerprint(
+                shards_t, [fo.view(VIEW_STANDARD) for _, fo in fields]
+            ) + (ffp,)
         try:
             stacks = [self._get_block(index, fo, shards_t)[0] for _, fo in fields]
             filt = None
@@ -3513,15 +3018,9 @@ class TPUBackend:
         if n <= 2 and int(np.prod(rs)) > (1 << 16):
             return None
         if ckey is not None:
-            with self._pair_lock:
-                hit = self._agg_cache.get(ckey)
-                if hit is not None and hit[0] == cfp:
-                    self._agg_cache[ckey] = self._agg_cache.pop(ckey)  # LRU
-            if hit is not None and hit[0] == cfp:
-                self.stats.count("agg_cache_hits_total")
-                payload = hit[1]
-            else:
-                hit = None
+            hit = self._agg_cache.hit(ckey, cfp)
+            if hit is not None:
+                payload = hit.value
         if hit is None:
             with current_profile().phase("dispatch", span="pilosa.group_by"):
                 if n >= 3:
@@ -3543,11 +3042,7 @@ class TPUBackend:
                         self._group_program(n, filt is not None)(*args)
                     ))
             if ckey is not None:
-                with self._pair_lock:
-                    self._agg_cache[ckey] = (cfp, payload)
-                    while len(self._agg_cache) > MAX_PAIR_CACHE_ENTRIES:
-                        self._agg_cache.pop(next(iter(self._agg_cache)))
-                    self._agg_cache_charge()
+                self._agg_cache.store(ckey, TierEntry(cfp, payload))
         if payload[0] == "dense":
             return self._group_enumerate(
                 fields, starts, child_rows, rs, payload[1], n, cap
@@ -3597,29 +3092,29 @@ class TPUBackend:
             out.append((fn, vs))
         return tuple(out)
 
-    def _agg_cache_charge(self) -> None:
+    def _agg_cache_charge(self, entries) -> None:
         """Ledger charge for the aggregate/group-tensor cache: total
-        host bytes pinned by cached payload arrays. Called under
-        _pair_lock after every store/evict so the gauge tracks the LRU
-        exactly."""
+        host bytes pinned by cached payload arrays. The table calls it
+        under its lock after every store/evict so the gauge tracks the
+        LRU exactly."""
         total = 0
-        for ent in self._agg_cache.values():
-            for payload in ent[1:]:  # (cfp, payload[, extra]) entries
-                if isinstance(payload, tuple):
-                    total += sum(
-                        p.nbytes for p in payload if isinstance(p, np.ndarray)
-                    )
-                elif isinstance(payload, np.ndarray):
-                    total += payload.nbytes
+        for ent in entries:
+            payload = ent.value
+            if isinstance(payload, tuple):
+                total += sum(
+                    p.nbytes for p in payload if isinstance(p, np.ndarray)
+                )
+            elif isinstance(payload, np.ndarray):
+                total += payload.nbytes
         self.stats.gauge("agg_cache_bytes", total)
 
-    def _group_tiled_sweep(self, stacks, filt, rs):
-        """Prune + tile + sweep the n>=3 group tensor: returns the
-        ("live", live_rows, stats_live) payload, or None when the live
-        combination product exceeds the host cell budget. live_rows is
-        a tuple (one per extra field) of globally-live row ids;
-        stats_live is [K_live, Rf, Rg] in odometer order over the live
-        rows (last field fastest)."""
+    def _group_live_plan(self, stacks, rs, max_cells=None):
+        """Popcount pruning (ISSUE 17): a combination containing a
+        globally-empty row is all-zero in EVERY cell, so only live
+        combinations are swept. Returns (live_rows — per extra field the
+        globally-live row ids —, combos int32[K_live, E] in odometer
+        order, last field fastest, the slot bucket, the EXPLAIN note),
+        or None when the live product exceeds `max_cells`."""
         live_rows = self._group_live_rows(stacks)
         k_nominal = 1
         for r in rs[2:]:
@@ -3630,12 +3125,11 @@ class TPUBackend:
         pruned = k_nominal - k_live
         if pruned:
             self.stats.count("groupby_pruned_groups_total", pruned)
-        if k_live * rs[0] * rs[1] > MAX_GROUP_RESULT_CELLS:
+        if max_cells is not None and k_live * rs[0] * rs[1] > max_cells:
             return None
         t_slots = (
             _slot_bucket(min(k_live, MAX_GROUP_TILE_SLOTS)) if k_live else 0
         )
-        n_tiles = (k_live + t_slots - 1) // t_slots if k_live else 0
         if k_live:
             grids = np.meshgrid(*live_rows, indexing="ij")
             combos = np.stack(
@@ -3643,16 +3137,31 @@ class TPUBackend:
             ).astype(np.int32)
         else:
             combos = np.zeros((0, len(rs) - 2), np.int32)
-        stats_live = self._group_tiles(stacks, filt, combos, t_slots)
-        prof = current_profile()
-        ex = getattr(prof, "explain", None)
+        note = {
+            "liveGroups": k_live,
+            "prunedGroups": pruned,
+            "slots": t_slots,
+            "tiles": (k_live + t_slots - 1) // t_slots if k_live else 0,
+        }
+        return live_rows, combos, t_slots, note
+
+    @staticmethod
+    def _explain_tiles(note) -> None:
+        ex = getattr(current_profile(), "explain", None)
         if ex is not None:
-            ex._node().setdefault("groupbyTiles", []).append({
-                "liveGroups": k_live,
-                "prunedGroups": pruned,
-                "slots": t_slots,
-                "tiles": n_tiles,
-            })
+            ex._node().setdefault("groupbyTiles", []).append(note)
+
+    def _group_tiled_sweep(self, stacks, filt, rs):
+        """Prune + tile + sweep the n>=3 group tensor: returns the
+        ("live", live_rows, stats_live) payload, or None when the live
+        combination product exceeds the host cell budget. stats_live is
+        [K_live, Rf, Rg] in odometer order over the live rows."""
+        plan = self._group_live_plan(stacks, rs, MAX_GROUP_RESULT_CELLS)
+        if plan is None:
+            return None
+        live_rows, combos, t_slots, note = plan
+        stats_live = self._group_tiles(stacks, filt, combos, t_slots)
+        self._explain_tiles(note)
         return (
             "live",
             tuple(tuple(int(r) for r in lr) for lr in live_rows),
@@ -3699,16 +3208,15 @@ class TPUBackend:
         except _Unsupported:
             return None
         resolver()  # force readback so the entry's stats are host np
-        with self._pair_lock:
-            ent = self._pair_cache.get((index, fa, fb))
+        ent = self._pair_cache.get((index, fa, fb))
         if (
             ent is None
-            or ent.shards != shards_t
-            or not isinstance(ent.stats, np.ndarray)
+            or ent.fp[0] != shards_t
+            or not isinstance(ent.value, np.ndarray)
         ):
             return None
-        rf, rg = ent.rf, ent.rg
-        return ent.stats[: rf * rg].reshape(rf, rg), rf, rg
+        rf, rg = ent.extra
+        return ent.value[: rf * rg].reshape(rf, rg), rf, rg
 
     #: Slab-tier budget for host groupN re-derives: words ANDed per
     #: epoch (K*rf*rg*W per shard). Past this a device re-dispatch is
@@ -3738,7 +3246,7 @@ class TPUBackend:
     def _groupn_tensor(self, index, fields, shards_t):
         """(stats int64[K,rf,rg], rs) for an unfiltered N>=3 GroupBy from
         the maintained per-shard table (VERDICT r4 #1b), or None when
-        this path can't serve (mesh, repeated field, bounds) and the
+        this path can't serve (repeated field, bounds) and the
         generic tensor path should run. Write epochs resolve on the
         host: point writes delta-apply against probes of the other
         fields, anything else re-derives just the dirty shards' rows —
@@ -3753,100 +3261,70 @@ class TPUBackend:
         fobjs = [fo for _, fo in fields]
         if len({id(f) for f in fobjs}) != len(fobjs):
             return None  # repeated field: delta ordering is ambiguous
-        fnames = tuple(fn for fn, _ in fields)
-        ckey = ("groupn", index, fnames)
+        ckey = ("groupn", index, tuple(fn for fn, _ in fields))
         views = [f.view(VIEW_STANDARD) for f in fobjs]
-        while True:
-            gens = tuple(v.generation if v is not None else -1 for v in views)
-            cfp = (shards_t, gens)
-            with self._pair_lock:
-                hit = self._groupn_cache.get(ckey)
-                if hit is not None and hit.cfp == cfp:
-                    self.stats.count("groupn_cache_hits_total")
-                    return hit.stats, hit.rs
-                latch = self._stats_updating.get(ckey)
-                if latch is None:
-                    self._stats_updating[ckey] = threading.Event()
-                    break
-            latch.wait(timeout=60)
-        try:
-            # Fingerprint missed: a dispatch MAY be coming — start the
-            # sweep's AOT compile now (predicted shapes, background
-            # thread) so it overlaps the stack fetch on a cold path.
-            # Costs one cheap fragment-height walk; if the incremental
-            # tier absorbs the epoch the thread just warms the cache.
-            prewarm = None
-            shapes = self._groupn_predicted_shapes(fobjs, views, shards_t)
-            d_pred = 1
-            for sh in shapes:
-                d_pred *= sh[1]
-            if shapes[0][0] * d_pred * 4 > self.MAX_PAIR_PERSHARD_BYTES:
-                # A table at this geometry could never be retained
-                # (dispatch would bail on the same bound after packing
-                # everything): bail BEFORE the prewarm compile and the
-                # stack fetch — the generic tiled path (pruned, and
-                # cacheable since ISSUE 17) serves instead.
-                return None
-            k_pred = 1
-            for sh in shapes[2:]:
-                k_pred *= sh[1]
-            t_pred = _slot_bucket(min(k_pred, MAX_GROUP_TILE_SLOTS))
-            with self._fns_lock:
-                compiled = (
-                    "group_tile", shapes, t_pred, False, True
-                ) in self._fns
-            if not compiled:
-                from pilosa_tpu.utils.threads import spawn
+        ent = self._groupn_cache.serve(
+            ckey, shards_t, views,
+            lambda stale, fp: self._groupn_refresh(
+                index, ckey, fobjs, views, stale, fp
+            ),
+        )
+        return None if ent is None else (ent.value, ent.extra)
 
-                spawn(
-                    "groupby-prewarm",
-                    lambda: self._group_tile_program(
-                        shapes, t_pred, False, True
-                    ),
-                    name="groupn-prewarm",
-                )
-            # Journal-complete freshness (ISSUE r7): a retained entry's
-            # recorded per-field versions + the views' journals make the
-            # walk O(dirty shards) per field; only cold tuples (or an
-            # evicted journal window) pay the full locked walk.
-            hit_ok = (
-                hit is not None
-                and hit.cfp[0] == shards_t
-                and hit.vers is not None
-            )
-            live = [
-                self._epoch_versions(
-                    f, shards_t, VIEW_STANDARD,
-                    hit.vers[t] if hit_ok else None,
-                    hit.cfp[1][t] if hit_ok else -1,
-                    tier="groupn",
-                )
-                for t, f in enumerate(fobjs)
-            ]
-            upd = self._groupn_try_incremental(hit, fobjs, views, shards_t, live)
-            if upd is not None:
-                pershard, vers_rec, rs, totals = upd
-                if totals is None:
-                    k_total = pershard.shape[1] // (rs[0] * rs[1])
-                    totals = (
-                        pershard.sum(axis=0, dtype=np.int64)
-                        .reshape(k_total, rs[0], rs[1])
-                    )
-                ent = _GroupNEntry(cfp, totals, pershard, rs, vers_rec)
-                with self._pair_lock:
-                    self._groupn_cache[ckey] = ent
-                return totals, rs
-            return self._groupn_dispatch(
-                index, fobjs, shards_t, ckey, cfp, live, prewarm
-            )
-        finally:
-            with self._pair_lock:
-                ev = self._stats_updating.pop(ckey, None)
-            if ev is not None:
-                ev.set()
+    def _groupn_refresh(self, index, ckey, fobjs, views, stale, fp):
+        """The single-flight body: the stored entry, or None when no
+        table can be kept at this geometry."""
+        shards_t = fp[0]
+        # Fingerprint missed: a dispatch MAY be coming — start the
+        # sweep's AOT compile now (predicted shapes, background
+        # thread) so it overlaps the stack fetch on a cold path.
+        # Costs one cheap fragment-height walk; if the incremental
+        # tier absorbs the epoch the thread just warms the cache.
+        shapes = self._groupn_predicted_shapes(fobjs, views, shards_t)
+        d_pred = 1
+        for sh in shapes:
+            d_pred *= sh[1]
+        if shapes[0][0] * d_pred * 4 > self.MAX_PAIR_PERSHARD_BYTES:
+            # A table at this geometry could never be retained
+            # (dispatch would bail on the same bound after packing
+            # everything): bail BEFORE the prewarm compile and the
+            # stack fetch — the generic tiled path (pruned, and
+            # cacheable since ISSUE 17) serves instead.
+            return None
+        k_pred = 1
+        for sh in shapes[2:]:
+            k_pred *= sh[1]
+        t_pred = _slot_bucket(min(k_pred, MAX_GROUP_TILE_SLOTS))
+        with self._fns_lock:
+            compiled = (
+                "group_tile", shapes, t_pred, False, True
+            ) in self._fns
+        if not compiled:
+            from pilosa_tpu.utils.threads import spawn
 
-    def _groupn_dispatch(self, index, fobjs, shards_t, ckey, cfp, live,
-                         prewarm=None):
+            spawn(
+                "groupby-prewarm",
+                lambda: self._group_tile_program(
+                    shapes, t_pred, False, True
+                ),
+                name="groupn-prewarm",
+            )
+        live = self._tier_versions(stale, fobjs, shards_t, "groupn")
+        ent = refresh_entry(
+            stale, fp, views, live, GroupNRows, self.stats,
+            self.MAX_PAIR_HOST_UPDATE_SHARDS,
+            self.MAX_GROUPN_HOST_SLAB_WORDS,
+        )
+        if ent is None:
+            ent = self._groupn_dispatch(index, fobjs, fp, live)
+        if ent is not None:
+            self._groupn_cache.store(ckey, ent)
+        return ent
+
+    def _groupn_dispatch(self, index, fobjs, cfp, live):
+        """The cold sweep: the entry to store, or None when the generic
+        path must decide (HBM budget, bounds, a device failure)."""
+        shards_t = cfp[0]
         stacks = []
         verss = []
         try:
@@ -3877,32 +3355,10 @@ class TPUBackend:
             return None
         if s_pad * d_stats * 4 > self.MAX_PAIR_PERSHARD_BYTES:
             return None  # table too big to retain: generic path sweeps
-        # Popcount pruning (ISSUE 17): a combination containing a
-        # globally-empty row is all-zero in EVERY per-shard cell, so
-        # only live combinations are swept and scattered; pruned slots
+        # Only live combinations are swept and scattered; pruned slots
         # of the dense retained table stay exactly zero.
-        live_rows = self._group_live_rows(stacks)
-        k_live = 1
-        for lr in live_rows:
-            k_live *= len(lr)
-        pruned = k_total - k_live
-        if pruned:
-            self.stats.count("groupby_pruned_groups_total", pruned)
-        if prewarm is not None:
-            # Joined ONLY here, on the dispatch path: calling the
-            # program while the prewarm still compiles it would race
-            # into a duplicate compile.
-            prewarm.join()
-        if k_live:
-            grids = np.meshgrid(*live_rows, indexing="ij")
-            combos = np.stack(
-                [g.ravel() for g in grids], axis=1
-            ).astype(np.int32)
-        else:
-            combos = np.zeros((0, len(rs) - 2), np.int32)
-        t_slots = (
-            _slot_bucket(min(k_live, MAX_GROUP_TILE_SLOTS)) if k_live else 0
-        )
+        _, combos, t_slots, note = self._group_live_plan(stacks, rs)
+        k_live = combos.shape[0]
         try:
             with current_profile().phase("dispatch", span="pilosa.groupn"):
                 tiles = self._group_tiles(
@@ -3912,15 +3368,7 @@ class TPUBackend:
             # real hardware hits; the generic path answers instead.
             self._count_device_fallback("group_tile_pershard", tuple(rs), e)
             return None
-        prof = current_profile()
-        ex = getattr(prof, "explain", None)
-        if ex is not None:
-            ex._node().setdefault("groupbyTiles", []).append({
-                "liveGroups": k_live,
-                "prunedGroups": pruned,
-                "slots": t_slots,
-                "tiles": (k_live + t_slots - 1) // t_slots if k_live else 0,
-            })
+        self._explain_tiles(note)
         # Scatter the live tiles [K_live, S_pad, rf, rg] into the dense
         # retained table rows [S_real, K*rf*rg] at their odometer slots
         # (combos carry row IDS; flat k = odometer over rs[2:]).
@@ -3949,167 +3397,7 @@ class TPUBackend:
             )
             for i, f in enumerate(fobjs)
         )
-        ent = _GroupNEntry(cfp, totals, pershard, rs, vers_rec)
-        with self._pair_lock:
-            self._groupn_cache[ckey] = ent
-            while len(self._groupn_cache) > MAX_PAIR_CACHE_ENTRIES:
-                self._groupn_cache.pop(next(iter(self._groupn_cache)))
-        return totals, rs
-
-    def _groupn_try_incremental(self, hit, fobjs, views, shards_t, live):
-        """Host-side epoch update of the per-shard group tensor table.
-        Returns (pershard int32[S, D], per-field recorded versions, rs,
-        totals-or-None — the cached totals when nothing in the queried
-        shard set actually changed) or None when a dispatch is needed.
-        Exactness discipline: delta
-        shards record the walk versions their op windows end at (probes
-        of the other fields confirm pre AND post under the fragment
-        lock); slab shards are _pack_confirmed; anything ambiguous
-        re-dispatches."""
-        n = len(fobjs)
-        if (
-            hit is None
-            or hit.pershard is None
-            or hit.cfp[0] != shards_t
-        ):
-            return None
-        rs = hit.rs
-        rf, rg = rs[0], rs[1]
-        k_total = 1
-        for rh in rs[2:]:
-            k_total *= rh
-        dirty = [
-            i for i in range(len(shards_t))
-            if any(hit.vers[t][i] != live[t][i] for t in range(n))
-        ]
-        if not dirty:
-            # Writes outside the queried shard set bumped a generation:
-            # counts unchanged — re-key with the CACHED totals instead
-            # of re-summing the whole table per query (code review r5).
-            return hit.pershard, tuple(live), rs, hit.stats
-        pershard = hit.pershard.copy()
-        vers_rec = [list(lv) for lv in live]
-        slab_dirty: list[int] = []
-        n_delta_ops = 0
-        for i in dirty:
-            ops_applied = self._groupn_shard_delta(
-                hit, i, shards_t[i], fobjs, views, live, pershard, rs, k_total
-            )
-            if ops_applied is None:
-                slab_dirty.append(i)
-            else:
-                n_delta_ops += ops_applied
-        if len(slab_dirty) > self.MAX_PAIR_HOST_UPDATE_SHARDS:
-            return None
-        slab_cost = len(slab_dirty) * k_total * rf * rg * WORDS_PER_SHARD
-        if slab_cost > self.MAX_GROUPN_HOST_SLAB_WORDS:
-            return None
-        for i in slab_dirty:
-            slabs = []
-            for t, f in enumerate(fobjs):
-                fr = views[t].fragment(shards_t[i]) if views[t] is not None else None
-                if fr is None:
-                    slabs.append(
-                        np.zeros((rs[t], WORDS_PER_SHARD), dtype=np.uint32)
-                    )
-                    vers_rec[t][i] = None
-                else:
-                    slab, vers_rec[t][i] = _pack_confirmed(fr, rs[t])
-                    if fr.max_row_id >= rs[t]:
-                        return None  # row grew past the tensor: re-dispatch
-                    slabs.append(slab[: rs[t]])
-            pershard[i] = _host_slab_groupn(slabs, rs)
-        self.stats.count("groupn_incremental_updates_total")
-        self.stats.count("groupn_incremental_shards_total", len(dirty))
-        if n_delta_ops:
-            self.stats.count("groupn_delta_ops_total", n_delta_ops)
-        return pershard, tuple(tuple(v) for v in vers_rec), rs, None
-
-    def _groupn_shard_delta(self, hit, i, shard, fobjs, views, live,
-                            pershard, rs, k_total):
-        """Apply one dirty shard's epoch as exact point-write deltas to
-        pershard[i], or None for the slab tier: more than one field
-        changed (probe ordering against changing peers is ambiguous),
-        no delta history, row growth, or a probe-version conflict.
-
-        DUPLICATED DISCIPLINE: this is the N-field generalization of
-        _pair_shard_delta's probe/confirm/revert protocol. Any fix to
-        the version-capture or probe-locking rules in EITHER method
-        must be mirrored in the other (they are kept separate because
-        the pair tier carries batcher/device-stack coupling this tier
-        deliberately avoids)."""
-        n = len(fobjs)
-        changed = [
-            t for t in range(n) if hit.vers[t][i] != live[t][i]
-        ]
-        if len(changed) != 1:
-            return None
-        t = changed[0]
-        ov, nv = hit.vers[t][i], live[t][i]
-        frag = views[t].fragment(shard) if views[t] is not None else None
-        if frag is None or ov is None or nv is None or ov[0] != nv[0]:
-            return None
-        ops = frag.bit_ops_between(ov[1], nv[1])
-        if ops is None:
-            return None
-        # The probes below read the OTHER fields' live storage, recorded
-        # at their walk versions (live[u][i]): confirm each matches
-        # before AND after (under its lock — a mid-write bump must be
-        # seen; see _pack_confirmed). On any conflict, revert the row.
-        others = []
-        for u in range(n):
-            if u == t:
-                continue
-            fru = views[u].fragment(shard) if views[u] is not None else None
-            if fru is None:
-                if live[u][i] is not None:
-                    return None  # vanished since the walk
-            else:
-                with fru.lock:
-                    moved = live[u][i] is None or \
-                        (fru.uid, fru.version) != live[u][i]
-                if moved:
-                    return None
-            others.append((u, fru))
-        import itertools
-
-        sw = SHARD_WIDTH
-        row_flat = pershard[i]
-        extra_rs = rs[2:]
-        for _, r, c, sign in ops:
-            if r >= rs[t]:
-                row_flat[:] = hit.pershard[i]
-                return None  # tensor height exceeded mid-window
-            row_sets = [None] * n
-            row_sets[t] = (r,)
-            empty = False
-            for u, fru in others:
-                if fru is None:
-                    empty = True
-                    break
-                st = fru.storage
-                rows_u = tuple(
-                    b for b in range(rs[u]) if st.contains(b * sw + c)
-                )
-                if not rows_u:
-                    empty = True
-                    break
-                row_sets[u] = rows_u
-            if empty:
-                continue  # some field has no bit at c: no cell changes
-            for combo in itertools.product(*row_sets):
-                k = 0
-                for tt in range(2, n):
-                    k = k * extra_rs[tt - 2] + combo[tt]
-                row_flat[(k * rs[0] + combo[0]) * rs[1] + combo[1]] += sign
-        for u, fru in others:
-            if fru is not None:
-                with fru.lock:
-                    moved = (fru.uid, fru.version) != live[u][i]
-                if moved:
-                    row_flat[:] = hit.pershard[i]
-                    return None
-        return len(ops)
+        return TierEntry(cfp, totals, pershard, vers_rec, rs)
 
     @staticmethod
     def _group_candidates(starts, child_rows, rs):
@@ -4470,10 +3758,7 @@ class TPUBackend:
         """Exact TopN in one dispatch: per-row popcounts of the stacked
         field block (optionally masked by a src tree), reduced over the
         shard axis on device; the counts vector reads back once."""
-        idx = self.holder.index(index)
-        f = idx.field(field_name) if idx else None
-        if f is None:
-            raise NotFoundError(f"field not found: {field_name}")
+        f = self._field(index, field_name)
         if f.view(VIEW_STANDARD) is None:
             return []
         shards_t = tuple(shards)
@@ -4488,8 +3773,8 @@ class TPUBackend:
             return self._topn_pairs(counts, n)
         return self._topn_pairs(
             self._topn_dispatch(
-                index, f, shards_t, (spec, blocks, scalars), None, None, None
-            ),
+                index, f, shards_t, (spec, blocks, scalars), None
+            )[0],
             n,
         )
 
@@ -4499,62 +3784,46 @@ class TPUBackend:
         generation is the write epoch, so repeats serve without a
         dispatch; a SMALL epoch refreshes the resident per-shard table
         on the host (same incremental maintenance as the pair cache).
-        Single-flight admission: one refresher per field, waiters
-        re-check. Serves TopN, unfiltered Rows, and 1-field GroupBy
-        (which wants the raw vector — no sort, no Pair objects)."""
+        Serves TopN, unfiltered Rows, and 1-field GroupBy (which wants
+        the raw vector — no sort, no Pair objects)."""
         ckey = (index, field_name)
-        ukey = ("topn", index, field_name)
-        v = f.view(VIEW_STANDARD)
-        while True:
-            cfp = (shards_t, v.generation if v is not None else -1)
-            with self._pair_lock:
-                hit = self._topn_cache.get(ckey)
-                if hit is not None and hit[0] == cfp:
-                    self.stats.count("topn_cache_hits_total")
-                    return hit[1]
-                latch = self._stats_updating.get(ukey)
-                if latch is None:
-                    self._stats_updating[ukey] = threading.Event()
-                    break
-            latch.wait(timeout=60)
-        try:
-            # Generation moved: try the host table update against LIVE
-            # fragment versions — no stack fetch, no device round trip.
-            # Journal-complete freshness (ISSUE r7): the retained entry's
-            # recorded versions + the view journal make this O(dirty
-            # shards); the full locked walk remains only for cold fields
-            # and journal-eviction windows.
-            hit_ok = (
-                hit is not None
-                and len(hit) >= 4
-                and hit[3] is not None
-                and hit[0][0] == shards_t
-            )
-            with current_profile().phase("freshness"):
-                live_vers = self._epoch_versions(
-                    f, shards_t, VIEW_STANDARD,
-                    hit[3] if hit_ok else None,
-                    hit[0][1] if hit_ok else -1,
-                    tier="topn",
-                )
-                upd = self._topn_try_incremental(f, hit, shards_t, live_vers)
-            if upd is not None:
-                pershard, vers_rec = upd
-                counts = pershard.sum(axis=0).astype(np.uint64)
-                with self._pair_lock:
-                    self._topn_cache[ckey] = (cfp, counts, pershard, vers_rec)
-                return counts
-            return self._topn_dispatch(
-                index, f, shards_t, None, ckey, cfp, live_vers
-            )
-        finally:
-            with self._pair_lock:
-                ev = self._stats_updating.pop(ukey, None)
-            if ev is not None:
-                ev.set()
+        views = (f.view(VIEW_STANDARD),)
+        return self._topn_cache.serve(
+            ckey, shards_t, views,
+            lambda stale, fp: self._topn_refresh(
+                index, ckey, f, views, stale, fp
+            ),
+        ).value
 
-    def _topn_dispatch(self, index, f, shards_t, src, ckey, cfp,
-                       live_vers) -> np.ndarray:
+    def _topn_refresh(self, index, ckey, f, views, stale, fp) -> TierEntry:
+        """The single-flight body. Generation moved: try the host table
+        update against LIVE fragment versions — no stack fetch, no
+        device round trip — and dispatch when it cannot absorb the
+        epoch (cold field, row growth, shard-set change, too many slab
+        shards)."""
+        shards_t = fp[0]
+        with current_profile().phase("freshness"):
+            live = self._tier_versions(stale, (f,), shards_t, "topn")
+            ent = refresh_entry(
+                stale, fp, views, live, RowCountRows, self.stats,
+                self.MAX_PAIR_HOST_UPDATE_SHARDS,
+            )
+        if ent is None:
+            counts, pershard, vers, rp = self._topn_dispatch(
+                index, f, shards_t, None, live[0]
+            )
+            # Dispatch read the stack content after the versions: stale
+            # out any shard that moved meanwhile (see _confirm_vers).
+            vers = self._confirm_vers(f, shards_t, vers, tier="topn")
+            ent = TierEntry(fp, counts, pershard, (vers,), (rp,))
+        self._topn_cache.store(ckey, ent)
+        return ent
+
+    def _topn_dispatch(self, index, f, shards_t, src, live_vers):
+        """One sweep of the field's per-row counts (masked by the src
+        tree when given): (counts[R], the int64[S, R] per-shard table
+        or None, the versions the swept stack was packed from, the
+        stack's row count)."""
         src_call = src is not None
         block, rp, vers = self.blocks.get_with_versions(index, f, shards_t)
         if vers is None:
@@ -4586,15 +3855,7 @@ class TPUBackend:
             if counts.ndim == 2:  # [S, R] per-shard partials
                 pershard = counts.astype(np.int64)
                 counts = counts.sum(axis=0)
-        if ckey is not None:
-            # Dispatch read the stack content after the versions: stale
-            # out any shard that moved meanwhile (see _confirm_vers).
-            vers = self._confirm_vers(f, shards_t, vers, tier="topn")
-            with self._pair_lock:
-                self._topn_cache[ckey] = (cfp, counts, pershard, vers)
-                while len(self._topn_cache) > MAX_PAIR_CACHE_ENTRIES:
-                    self._topn_cache.pop(next(iter(self._topn_cache)))
-        return counts
+        return counts, pershard, vers, rp
 
     def _topn_gates(self, s_pad, rp, src_call):
         """(pershard_ok, reduce_dev) for a TopN dispatch — shared with
@@ -4613,67 +3874,6 @@ class TPUBackend:
             False if pershard_ok else s_pad <= MAX_DEVICE_SUM_SHARDS
         )
         return pershard_ok, reduce_dev
-
-    def _topn_try_incremental(self, f, hit, shards_t, vers):
-        """Host-side epoch update of the TopN per-shard row-count table:
-        delta-apply ring-covered point writes, slab-rederive the rest
-        (no device work at all — same discipline as
-        _pair_try_incremental). Returns (int64[S, R] table, recorded
-        versions), or None when a dispatch is needed (cold field, row
-        growth past the table height, shard-set change, too many slab
-        shards)."""
-        if (
-            hit is None
-            or len(hit) < 4
-            or hit[2] is None
-            or hit[3] is None
-            or hit[0][0] != shards_t
-        ):
-            return None
-        old_vers = hit[3]
-        rp = hit[2].shape[1]
-        dirty = [i for i in range(len(shards_t)) if old_vers[i] != vers[i]]
-        if not dirty:
-            # Generation bumped by writes OUTSIDE the queried shard set
-            # (e.g. ingest on another node's shards): counts unchanged —
-            # re-key the entry instead of degrading to a stack fetch +
-            # dispatch on every query for as long as that ingest runs.
-            return hit[2], vers
-        v = f.view(VIEW_STANDARD)
-        pershard = hit[2].copy()
-        vers_rec = list(vers)
-        # Delta tier first (same two tiers as the pair table): an epoch
-        # fully explained by the fragment's bit-op ring is cf[row] ± 1
-        # per op — no slab pack at all. Slab packs are version-confirmed
-        # so recorded versions never describe older content than
-        # captured (delta replay is non-idempotent).
-        slab_dirty: list[int] = []
-        for i in dirty:
-            ov, nv = old_vers[i], vers[i]
-            fr = v.fragment(shards_t[i]) if v is not None else None
-            ops = None
-            if fr is not None and ov is not None and nv is not None and ov[0] == nv[0]:
-                ops = fr.bit_ops_between(ov[1], nv[1])
-            if ops is None or any(r >= rp for _, r, _, _ in ops):
-                slab_dirty.append(i)
-                continue
-            for _, r, _, sign in ops:
-                pershard[i][r] += sign
-        if len(slab_dirty) > self.MAX_PAIR_HOST_UPDATE_SHARDS:
-            return None
-        for i in slab_dirty:
-            fr = v.fragment(shards_t[i]) if v is not None else None
-            if fr is None:
-                slab = np.zeros((rp, WORDS_PER_SHARD), dtype=np.uint32)
-                vers_rec[i] = None
-            else:
-                slab, vers_rec[i] = _pack_confirmed(fr, rp)
-                if fr.max_row_id >= rp:
-                    return None  # row grew past the table: re-dispatch
-            pershard[i] = _host_slab_row_counts(slab)
-        self.stats.count("topn_incremental_updates_total")
-        self.stats.count("topn_incremental_shards_total", len(dirty))
-        return pershard, tuple(vers_rec)
 
     def rows_field(self, index: str, field_name: str, shards: list[int],
                    start: int = 0) -> Optional[list[int]]:
@@ -4755,10 +3955,7 @@ class TPUBackend:
     # -- BSI aggregates (device fast path; fragment.go:1111-1268) ----------
 
     def _bsi_setup(self, index, field_name, shards, filter_call):
-        idx = self.holder.index(index)
-        f = idx.field(field_name) if idx else None
-        if f is None:
-            raise NotFoundError(f"field not found: {field_name}")
+        f = self._field(index, field_name)
         if f.options.type != FIELD_TYPE_INT:
             raise _Unsupported("not an int field")
         opts = f.bsi_group()
@@ -4775,38 +3972,43 @@ class TPUBackend:
         )
         return f, opts, spec, blocks, scalars, bsi_block
 
-    def bsi_sum(self, index, field_name, shards, filter_call=None):
-        """Distributed Sum(field): per-plane popcounts fused on device
-        (+psum over ICI with a mesh), exact host weighting. Returns
-        (sum, count) or None when not lowerable.
-
-        Unfiltered sums absorb point-value churn on the host: set/clear
-        value ops are recorded per BSI fragment (fragment.value_ops),
-        and an epoch fully explained by them updates the cached raw
-        total/count as exact deltas — no plane re-sweep."""
-        # Fingerprint BEFORE the data snapshot: a write racing this query
-        # must produce a never-matching cache entry, never a stale serve.
-        hit = self._agg_lookup("sum", index, field_name, shards, filter_call)
-        if hit is not None and hit[1] is not None:
-            return hit[1]
-        if hit is not None:
-            with current_profile().phase("freshness"):
-                upd = self._sum_try_incremental(
-                    index, field_name, shards, hit[0]
-                )
-            if upd is not None:
-                return upd
-        pre_vers = None
-        if hit is not None:
-            idx0 = self.holder.index(index)
-            f0 = idx0.field(field_name) if idx0 else None
-            if f0 is not None:
-                pre_vers = self._live_versions(
-                    f0, tuple(shards), bsi_view_name(field_name), tier="sum"
-                )
-        prof = current_profile()
+    def _bsi_aggregate(self, kind, tier, index, field_name, shards,
+                       filter_call, incremental, sweep):
+        """What Sum, Min and Max share. Unfiltered (a filtered aggregate
+        depends on other fields' epochs): the cached result while the
+        BSI view's generation holds; else the kind's host tier
+        (`incremental(f, view name, previous entry, fingerprint)` -> the
+        entry to store, or None when the epoch is not one it can
+        absorb). Else the device (`sweep(opts, spec, bsi_block, blocks,
+        scalars)` -> (result, per-shard table or None, extra)), stored
+        with the versions it was swept at. None when not lowerable."""
+        cacheable = filter_call is None
+        if cacheable:
+            key = (kind, index, field_name)
+            shards_t = tuple(shards)
+            f = self._field(index, field_name)
+            vn = bsi_view_name(field_name)
+            # Fingerprint BEFORE the data snapshot: a write racing this
+            # query must produce a never-matching cache entry, never a
+            # stale serve.
+            cfp = fingerprint(shards_t, (f.view(vn),))
+            ent = self._agg_cache.hit(key, cfp)
+            if ent is not None:
+                return ent.value
+            stale = self._agg_cache.get(key)
+            if (
+                stale is not None
+                and stale.vers is not None
+                and stale.fp[0] == shards_t
+            ):
+                with current_profile().phase("freshness"):
+                    ent = incremental(f, vn, stale, cfp)
+                if ent is not None:
+                    self._agg_cache.store(key, ent)
+                    return ent.value
+            pre_vers = self._live_versions(f, shards_t, vn, tier=tier)
         try:
-            with prof.phase("stack_fetch"):
+            with current_profile().phase("stack_fetch"):
                 f, opts, spec, blocks, scalars, bsi_block = self._bsi_setup(
                     index, field_name, shards, filter_call
                 )
@@ -4816,6 +4018,33 @@ class TPUBackend:
         if bsi_block.shape[0] > MAX_DEVICE_SUM_SHARDS:
             self._host_path("bsi", "shard axis past the device-sum bound")
             return None
+        result, pershard, extra = sweep(opts, spec, bsi_block, blocks, scalars)
+        if cacheable:
+            # Pre-read versions confirmed post-sweep (moved shards get
+            # _VERS_STALE): recorded versions never describe older
+            # content than swept — the delta tiers require it.
+            vers = self._confirm_vers(f, shards_t, pre_vers, vn, tier=tier)
+            self._agg_cache.store(
+                key, TierEntry(cfp, result, pershard, (vers,), extra)
+            )
+        return result
+
+    def bsi_sum(self, index, field_name, shards, filter_call=None):
+        """Distributed Sum(field): per-plane popcounts fused on device
+        (+psum over ICI with a mesh), exact host weighting. Returns
+        (sum, count) or None when not lowerable.
+
+        Unfiltered sums absorb point-value churn on the host: set/clear
+        value ops are recorded per BSI fragment (fragment.value_ops),
+        and an epoch fully explained by them updates the cached raw
+        total/count as exact deltas — no plane re-sweep."""
+        return self._bsi_aggregate(
+            "sum", "sum", index, field_name, shards, filter_call,
+            self._sum_try_incremental, self._sum_sweep,
+        )
+
+    def _sum_sweep(self, opts, spec, bsi_block, blocks, scalars):
+        prof = current_profile()
         depth = opts.bit_depth
         with prof.phase("dispatch", span="pilosa.bsi_sum"):
             pos_c, neg_c, cnt = self._program(
@@ -4830,43 +4059,19 @@ class TPUBackend:
                 (int(pos_c[i]) - int(neg_c[i])) << i for i in range(depth)
             )
             count = int(cnt)
-        result = (total + opts.base * count, count)
-        if hit is not None:
-            extra = None
-            if pre_vers is not None:
-                # Pre-read versions confirmed post-sweep (moved shards
-                # get _VERS_STALE): recorded versions never describe
-                # older content than swept — the delta tier requires it.
-                vers = self._confirm_vers(
-                    f, tuple(shards), pre_vers, bsi_view_name(field_name),
-                    tier="sum",
-                )
-                extra = (total, count, vers)
-            self._agg_store("sum", index, field_name, hit[0], result, extra)
-        return result
+        return (total + opts.base * count, count), None, (total, count)
 
-    def _sum_try_incremental(self, index, field_name, shards, cfp_now):
-        """Apply a value-write epoch to the cached unfiltered Sum as
-        exact deltas from the BSI fragments' value-op rings. Returns the
-        fresh (sum, count) (already re-cached), or None when the epoch
-        isn't delta-coverable (bulk import_value, ring eviction, shard
-        set change, no prior entry with version info)."""
-        shards_t = tuple(shards)
-        with self._pair_lock:
-            ent = self._agg_cache.get(("sum", index, field_name))
-        if ent is None or len(ent) < 3 or ent[2] is None:
-            return None
-        raw_total, count, vers_old = ent[2]
-        if ent[0][0] != shards_t:
-            return None
-        idx = self.holder.index(index)
-        f = idx.field(field_name) if idx else None
-        if f is None:
-            return None
-        vn = bsi_view_name(field_name)
+    def _sum_try_incremental(self, f, vn, ent, cfp_now):
+        """Apply a value-write epoch to the cached unfiltered Sum `ent`
+        (same shard set, versions recorded) as exact deltas from the
+        BSI fragments' value-op rings. Returns the entry of the fresh
+        (sum, count), or None when the epoch isn't delta-coverable
+        (bulk import_value, ring eviction)."""
+        shards_t = cfp_now[0]
+        (raw_total, count), vers_old = ent.extra, ent.vers[0]
         v = f.view(vn)
         vers_new = self._epoch_versions(
-            f, shards_t, vn, vers_old, ent[0][1], tier="sum"
+            f, shards_t, vn, vers_old, ent.fp[1][0], tier="sum"
         )
         d_sum = 0
         d_cnt = 0
@@ -4885,92 +4090,11 @@ class TPUBackend:
                 d_cnt += (1 if nok else 0) - (1 if ook else 0)
         raw_total += d_sum
         count += d_cnt
-        result = (raw_total + f.bsi_group().base * count, count)
-        self._agg_store(
-            "sum", index, field_name, cfp_now, result,
-            (raw_total, count, vers_new),
-        )
         self.stats.count("sum_incremental_updates_total")
-        return result
-
-    def _epoch_versions(self, f, shards_t, vn, vers_old, gen_recorded,
-                        tier="agg"):
-        """Per-shard live versions for an epoch update, built from the
-        view's mutation journal when it fully explains
-        (gen_recorded, now]: only the dirtied shards pay a locked
-        fragment read; every other shard carries its RECORDED version
-        forward (exact — an unjournaled shard had no _mutated, so its
-        (uid, version) is unchanged). Falls back to the full locked walk
-        (_live_versions) when the journal can't explain. At 954 shards
-        the walk cost ~1.8 ms x3 aggregate kinds per write epoch — the
-        minmax churn leg's dominant serving cost. Counted per tier as a
-        kind=journal walk whose shard count is the DIRTY set (the
-        O(dirty) invariant tests/test_telemetry.py asserts).
-
-        Every serving-path freshness consumer routes through here
-        (ISSUE r7 journal-complete): Sum/Min/Max value epochs, the pair
-        tier (_pair_refresh), the TopN rank table (_topn_counts), and
-        the GroupN tensor (_groupn_tensor). _VERS_STALE entries recorded
-        by a racing capture self-heal: the write that staled them bumped
-        the generation AFTER gen_recorded was read, so the journal names
-        that shard dirty and the locked re-read replaces the sentinel."""
-        v = f.view(vn)
-        if v is None or vers_old is None:
-            return self._live_versions(f, shards_t, vn, tier=tier)
-        dirty = v.dirty_shards_since(gen_recorded)
-        if dirty is None or len(vers_old) != len(shards_t):
-            return self._live_versions(f, shards_t, vn, tier=tier)
-        out = list(vers_old)
-        n_read = 0
-        for i, s in enumerate(shards_t):
-            if s in dirty:
-                fr = v.fragment(s)
-                if fr is None:
-                    out[i] = None
-                else:
-                    n_read += 1  # counted like _live_versions: locked reads
-                    with fr.lock:  # serialize with a mid-write bump
-                        out[i] = (fr.uid, fr.version)
-        self._count_version_walk("journal", tier, n_read)
-        return tuple(out)
-
-    def _agg_fingerprint(self, index, field_name, shards):
-        idx = self.holder.index(index)
-        f = idx.field(field_name) if idx else None
-        v = f.view(bsi_view_name(field_name)) if f is not None else None
-        return (tuple(shards), v.generation if v is not None else -1)
-
-    def _agg_lookup(self, kind, index, field_name, shards, filter_call):
-        """(fingerprint, result) cache hit for an UNFILTERED aggregate,
-        else None (filtered aggregates depend on other fields' epochs).
-        The returned fingerprint is captured BEFORE any sweep so a write
-        racing the compute can only produce a never-matching entry,
-        never a stale serve — pass it unchanged to _agg_store."""
-        if filter_call is not None:
-            return None
-        cfp = self._agg_fingerprint(index, field_name, shards)
-        with self._pair_lock:
-            hit = self._agg_cache.get((kind, index, field_name))
-            if hit is not None and hit[0] == cfp:
-                # LRU touch (mirrors the pair cache): hot aggregates must
-                # outlive cold entries under the shared cap.
-                self._agg_cache[(kind, index, field_name)] = self._agg_cache.pop(
-                    (kind, index, field_name)
-                )
-        if hit is not None and hit[0] == cfp:
-            self.stats.count("agg_cache_hits_total")
-            return hit
-        return (cfp, None)
-
-    def _agg_store(self, kind, index, field_name, cfp, result, extra=None):
-        """extra: the kind's churn-absorption state — Sum's (raw_total,
-        count, per-shard versions) for the value-delta tier, Min/Max's
-        (per-shard (val, cnt) table, per-shard versions) for the
-        monotone-delta/re-derive tiers. None when unavailable."""
-        with self._pair_lock:
-            self._agg_cache[(kind, index, field_name)] = (cfp, result, extra)
-            while len(self._agg_cache) > MAX_PAIR_CACHE_ENTRIES:
-                self._agg_cache.pop(next(iter(self._agg_cache)))
+        return TierEntry(
+            cfp_now, (raw_total + f.bsi_group().base * count, count),
+            None, (vers_new,), (raw_total, count),
+        )
 
     def bsi_min(self, index, field_name, shards, filter_call=None):
         return self._bsi_minmax("bsi_min", index, field_name, shards, filter_call)
@@ -4991,38 +4115,15 @@ class TPUBackend:
         fragment's own host plane-narrowing (Fragment.min/max), no
         device dispatch at all. The reference recomputes per query
         (fragment.go:1147-1191)."""
-        # Fingerprint BEFORE the data snapshot (see bsi_sum).
-        hit = self._agg_lookup(kind, index, field_name, shards, filter_call)
-        if hit is not None and hit[1] is not None:
-            return hit[1]
-        if hit is not None:
-            with current_profile().phase("freshness"):
-                upd = self._minmax_try_incremental(
-                    kind, index, field_name, shards, hit[0]
-                )
-            if upd is not None:
-                return upd
-        pre_vers = None
-        if hit is not None:
-            idx0 = self.holder.index(index)
-            f0 = idx0.field(field_name) if idx0 else None
-            if f0 is not None:
-                pre_vers = self._live_versions(
-                    f0, tuple(shards), bsi_view_name(field_name),
-                    tier="minmax",
-                )
+        return self._bsi_aggregate(
+            kind, "minmax", index, field_name, shards, filter_call,
+            functools.partial(self._minmax_try_incremental, kind),
+            functools.partial(self._minmax_sweep, kind, len(shards)),
+        )
+
+    def _minmax_sweep(self, kind, n_shards, opts, spec, bsi_block, blocks,
+                      scalars):
         prof = current_profile()
-        try:
-            with prof.phase("stack_fetch"):
-                f, opts, spec, blocks, scalars, bsi_block = self._bsi_setup(
-                    index, field_name, shards, filter_call
-                )
-        except _Unsupported as e:
-            self._host_path("bsi", e)
-            return None
-        if bsi_block.shape[0] > MAX_DEVICE_SUM_SHARDS:
-            self._host_path("bsi", "shard axis past the device-sum bound")
-            return None
         depth = opts.bit_depth
         with prof.phase("dispatch", span="pilosa." + kind):
             outs = self._program(kind, spec, True, extra=depth)(
@@ -5042,7 +4143,7 @@ class TPUBackend:
             return sum(1 << i for i in range(depth) if bits[i])
 
         pershard: list[tuple[int, int]] = []
-        for s in range(len(shards)):
+        for s in range(n_shards):
             if not consider_any[s]:
                 pershard.append((0, 0))
                 continue
@@ -5057,17 +4158,7 @@ class TPUBackend:
                 else:  # all negative: max = -minUnsigned(consider)
                     val, cnt = -assemble_min(bits_b[s]), int(cnt_b[s])
             pershard.append((val + opts.base, cnt) if cnt else (0, 0))
-        result = self._minmax_reduce(kind, pershard)
-        if hit is not None:
-            extra = None
-            if pre_vers is not None:
-                vers = self._confirm_vers(
-                    f, tuple(shards), pre_vers, bsi_view_name(field_name),
-                    tier="minmax",
-                )
-                extra = (tuple(pershard), vers)
-            self._agg_store(kind, index, field_name, hit[0], result, extra)
-        return result
+        return self._minmax_reduce(kind, pershard), tuple(pershard), None
 
     @staticmethod
     def _minmax_reduce(kind, pershard) -> tuple[int, int]:
@@ -5088,32 +4179,23 @@ class TPUBackend:
                 best_cnt += cnt
         return best_val, best_cnt
 
-    def _minmax_try_incremental(self, kind, index, field_name, shards,
-                                cfp_now):
+    def _minmax_try_incremental(self, kind, f, vn, ent, cfp_now):
         """Apply a value-write epoch to the cached per-shard extremum
-        table: O(1) monotone updates; a shard whose incumbent was
-        cleared (or whose op window isn't ring-covered) re-derives via
-        the fragment's HOST plane narrowing under its lock — exact, no
-        device work. Returns the fresh (val, count) (already re-cached)
-        or None when the whole entry must re-dispatch."""
-        shards_t = tuple(shards)
-        with self._pair_lock:
-            ent = self._agg_cache.get((kind, index, field_name))
-        if ent is None or len(ent) < 3 or ent[2] is None:
-            return None
-        pershard_old, vers_old = ent[2]
-        if ent[0][0] != shards_t:
-            return None
-        idx = self.holder.index(index)
-        f = idx.field(field_name) if idx else None
-        if f is None or f.options.type != FIELD_TYPE_INT:
+        table of `ent` (same shard set, versions recorded): O(1)
+        monotone updates; a shard whose incumbent was cleared (or whose
+        op window isn't ring-covered) re-derives via the fragment's
+        HOST plane narrowing under its lock — exact, no device work.
+        Returns the entry of the fresh (val, count), or None when the
+        whole entry must re-dispatch."""
+        shards_t = cfp_now[0]
+        pershard_old, vers_old = ent.pershard, ent.vers[0]
+        if f.options.type != FIELD_TYPE_INT:
             return None
         bg = f.bsi_group()
         base, depth = bg.base, bg.bit_depth
-        vn = bsi_view_name(field_name)
         v = f.view(vn)
         vers_new = self._epoch_versions(
-            f, shards_t, vn, vers_old, ent[0][1], tier="minmax"
+            f, shards_t, vn, vers_old, ent.fp[1][0], tier="minmax"
         )
         better = (
             (lambda a, b: a < b) if kind == "bsi_min" else (lambda a, b: a > b)
@@ -5174,12 +4256,10 @@ class TPUBackend:
                 pershard[i] = (raw[0] + base, raw[1]) if raw[1] else (0, 0)
                 vers_rec[i] = vv
                 n_rederived += 1
-        result = self._minmax_reduce(kind, pershard)
-        self._agg_store(
-            kind, index, field_name, cfp_now, result,
-            (tuple(pershard), tuple(vers_rec)),
-        )
         self.stats.count("minmax_incremental_updates_total")
         if n_rederived:
             self.stats.count("minmax_shard_rederives_total", n_rederived)
-        return result
+        return TierEntry(
+            cfp_now, self._minmax_reduce(kind, pershard), tuple(pershard),
+            (tuple(vers_rec),),
+        )

@@ -169,7 +169,7 @@ def pair_stats_pershard(f_stack, g_stack, interpret: bool = False):
     The per-shard table is what makes write churn cheap: the host keeps
     it resident, totals are its int64 sum, and a write epoch that dirtied
     D shards replaces D rows of the table from host-packed slabs
-    (tpu.py _host_slab_pair_flat) instead of re-sweeping the stacks on
+    (exec/tiers.py _host_slab_pair_flat) instead of re-sweeping the stacks on
     device — the reference's incremental rank-cache maintenance
     (cache.go:136-301) applied to the pair matrix. Per-shard counts are
     <= 2^20 so int32 is exact for ANY shard count (the summed kernel's
@@ -203,170 +203,6 @@ def pair_stats_pershard(f_stack, g_stack, interpret: bool = False):
         compiler_params=_sequential_grid(2),
         interpret=interpret,
     )(f_stack, g_stack)
-
-
-def _make_nary_kernel(n_extra: int, extra_rows: tuple, filtered: bool):
-    """Kernel for the N-field group tensor: 2 'pair' fields broadcast in
-    VMEM + n_extra mask fields whose row combination is selected by the
-    grid's k axis (k decomposes by static div/mod over extra_rows, last
-    field fastest — odometer order). One body generated per
-    (n_extra, extra_rows, filtered) — a copy-pasted twin per arity would
-    have to track every fix in lockstep."""
-
-    def kernel(f_ref, g_ref, *rest):
-        h_refs = rest[:n_extra]
-        if filtered:
-            filt_ref = rest[n_extra]
-        pair_ref = rest[-1]
-        # Grid order is (k, s, w): the reduction dims (shards, word
-        # tiles) MUST be the innermost grid dims so each output block's
-        # visits are consecutive — with shards outermost, Pallas flushes
-        # the accumulator when k advances and never restores it.
-        k = pl.program_id(0)
-        s = pl.program_id(1)
-        w = pl.program_id(2)
-
-        @pl.when(jnp.logical_and(s == 0, w == 0))
-        def _():
-            pair_ref[...] = jnp.zeros_like(pair_ref)
-
-        # Extra blocks span ALL their rows (Mosaic block dims must divide
-        # (8,128) or equal the array dim); the grid's k axis selects the
-        # row combination in-kernel via static div/mod.
-        m = None
-        rem = k
-        for t in range(n_extra - 1, -1, -1):
-            rh = extra_rows[t]
-            row = h_refs[t][0, rem % rh]  # [LT, 128]
-            rem = rem // rh
-            m = row if m is None else (m & row)
-        if filtered:
-            m = m & filt_ref[0, 0]
-        f = f_ref[0] & m[None]
-        g = g_ref[0]
-        pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
-        pair_ref[0] += _word_counts(pc)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def tri_stats(f_stack, g_stack, h_stack, filt=None, interpret: bool = False):
-    """The whole 3-field GroupBy tensor in ONE sweep — the 1-extra-field
-    case of nary_stats (kept as the named entry point the backend and
-    tests compile against): -> int32[Rh, Rf, Rg]."""
-    return nary_stats(f_stack, g_stack, (h_stack,), filt, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def nary_stats(f_stack, g_stack, extras, filt=None, interpret: bool = False):
-    """The whole N-field GroupBy tensor in ONE sweep (VERDICT r3 #4 —
-    removes the 3-field cliff):
-
-    (uint32[S, Rf, L, 128], uint32[S, Rg, L, 128],
-    (uint32[S, Rh1, L, 128], ...) [, uint32[S, L, 128]])
-    -> int32[K, Rf, Rg] with K = prod(Rhi) and
-    out[k, a, b] = popcount(F_a & G_b & H1_{k1} & ... & Hm_{km} [& filt])
-    where k = odometer over (k1..km), LAST extra field fastest.
-
-    3-D grid (row-combination k, shards, word tiles); the [Rf, Rg]
-    accumulator block is revisited per k, so one dispatch replaces K
-    masked pair sweeps (each its own dispatch round trip). f/g tiles are
-    re-read per k — the same HBM traffic the separate sweeps paid.
-    Accumulator bound: same MAX_PAIR_SHARDS int32 argument."""
-    s, rf, lines, lanes = f_stack.shape
-    rg = g_stack.shape[1]
-    extra_rows = tuple(h.shape[1] for h in extras)
-    k_total = 1
-    for rh in extra_rows:
-        k_total *= rh
-    # Tile budget must cover the [rf,rg,lt,128] broadcast AND every extra
-    # field's full-rows block that stays VMEM-resident.
-    lt = _line_tile(rf * rg + sum(extra_rows), lines, lanes)
-    row_block = lambda r: pl.BlockSpec(  # noqa: E731
-        (1, r, lt, lanes), lambda k, i, j: (i, 0, j, 0)
-    )
-    in_specs = [row_block(r) for r in (rf, rg, *extra_rows)]
-    operands = [f_stack, g_stack, *extras]
-    if filt is not None:
-        in_specs.append(row_block(1))
-        operands.append(filt[:, None])  # a stack of one row
-    kernel = _make_nary_kernel(len(extras), extra_rows, filt is not None)
-    return pl.pallas_call(
-        kernel,
-        # k outermost; shard + word-tile reduction dims innermost (see
-        # kernel comment — accumulator-visit contiguity).
-        grid=(k_total, s, lines // lt),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rf, rg), lambda k, i, j: (k, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k_total, rf, rg), jnp.int32),
-        compiler_params=_sequential_grid(3),
-        interpret=interpret,
-    )(*operands)
-
-
-def _make_nary_pershard_kernel(n_extra: int, extra_rows: tuple):
-    """nary kernel without the shard reduction: the [1, 1, rf, rg]
-    output block is indexed by (k, shard) and accumulates only over
-    word tiles. Unfiltered by design — the per-shard table exists to
-    absorb write churn for the UNFILTERED group tensor (a filter
-    changes per query, so its sweeps are not maintainable)."""
-
-    def kernel(f_ref, g_ref, *rest):
-        h_refs = rest[:n_extra]
-        pair_ref = rest[-1]
-        w = pl.program_id(2)
-
-        @pl.when(w == 0)
-        def _():
-            pair_ref[...] = jnp.zeros_like(pair_ref)
-
-        m = None
-        rem = pl.program_id(0)
-        for t in range(n_extra - 1, -1, -1):
-            rh = extra_rows[t]
-            row = h_refs[t][0, rem % rh]  # [LT, 128]
-            rem = rem // rh
-            m = row if m is None else (m & row)
-        f = f_ref[0] & m[None]
-        g = g_ref[0]
-        pc = jax.lax.population_count(f[:, None] & g[None]).astype(jnp.int32)
-        pair_ref[0, 0] += _word_counts(pc)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def nary_stats_pershard(f_stack, g_stack, extras, interpret: bool = False):
-    """nary_stats WITHOUT the shard reduction:
-    -> int32[K, S, Rf, Rg] (k odometer over extras, last fastest).
-
-    The per-shard group tensor is what lets N>=3 GroupBy absorb write
-    churn on the host (exec/tpu.py _groupn_try_incremental): totals are
-    its int64 sum over shards, and a write epoch that dirtied D shards
-    replaces D rows instead of re-sweeping the stacks — the same design
-    as pair_stats_pershard for the 2-field case."""
-    s, rf, lines, lanes = f_stack.shape
-    rg = g_stack.shape[1]
-    extra_rows = tuple(h.shape[1] for h in extras)
-    k_total = 1
-    for rh in extra_rows:
-        k_total *= rh
-    lt = _line_tile(rf * rg + sum(extra_rows), lines, lanes)
-    in_specs = [
-        pl.BlockSpec((1, r, lt, lanes), lambda k, i, j: (i, 0, j, 0))
-        for r in (rf, rg, *extra_rows)
-    ]
-    kernel = _make_nary_pershard_kernel(len(extras), extra_rows)
-    return pl.pallas_call(
-        kernel,
-        grid=(k_total, s, lines // lt),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rf, rg), lambda k, i, j: (k, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k_total, s, rf, rg), jnp.int32),
-        compiler_params=_sequential_grid(3),
-        interpret=interpret,
-    )(f_stack, g_stack, *extras)
 
 
 # ---------------------------------------------------------------------------
@@ -529,10 +365,11 @@ def masked_lane_counts(slab, active):
 
 # ---------------------------------------------------------------------------
 # Tiled GroupBy slot programs (ISSUE 17): the N-field group tensor cut
-# into fixed-shape slot arrays. Where nary_stats bakes the row
-# combination into the grid (K is a COMPILED dimension, so every
-# cardinality change is a recompile and the whole product tensor ships
-# in one piece), these take the combination as a traced int32[T, E]
+# into fixed-shape slot arrays. A kernel that bakes the row
+# combination into its grid makes K a COMPILED dimension (every
+# cardinality change a recompile, the whole product tensor shipped in
+# one piece; the one-shot sweep PR 17 retired did); these take the
+# combination as a traced int32[T, E]
 # operand: one compiled signature per (stack shapes, slot bucket)
 # serves ANY row combination, so the scheduler in exec/tpu.py can prune
 # empty rows, cut the live product into tiles, and launch each tile
@@ -599,8 +436,8 @@ def _tile_chunk_counts(fm, g_stack, pershard: bool):
 def _group_tile(f_stack, g_stack, extras, rows_idx, active, filt, pershard):
     """Shared body of the tile programs: lax.scan over the slot axis, so
     T appears only as a scan length (one compiled signature per slot
-    bucket) and every slot re-reads the stacks exactly once — the same
-    HBM traffic discipline as nary_stats's k axis."""
+    bucket) and every slot re-reads the stacks exactly once, whatever
+    the number of live combinations."""
 
     def slot(carry, xs):
         idx, act = xs
@@ -629,18 +466,23 @@ def group_tile_stats(f_stack, g_stack, extras, rows_idx, active, filt=None):
     out[q, a, b] = popcount(F_a & G_b & H1_{rows_idx[q,0]} & ... [& filt])
     for active[q] == 1, exactly 0 for padded slots.
 
-    Must agree bit-for-bit with nary_stats on the matching k slots
-    (differentially tested in tests/test_groupby_tiles.py). Accumulator
-    bound: same MAX_PAIR_SHARDS int32 argument as pair_stats."""
+    Slot q must agree bit-for-bit with pair_stats of the stack
+    pre-masked by the slot's rows (differentially tested in
+    tests/test_tpu.py TestTriStatsKernel). Accumulator bound: same
+    MAX_PAIR_SHARDS int32 argument as pair_stats."""
     return _group_tile(f_stack, g_stack, extras, rows_idx, active, filt, False)
 
 
 def group_tile_stats_pershard(f_stack, g_stack, extras, rows_idx, active):
     """group_tile_stats WITHOUT the shard reduction:
     -> int32[T, S, Rf, Rg]. Unfiltered by design — the per-shard table
-    absorbs write churn for the UNFILTERED group tensor only (same
-    contract as nary_stats_pershard, which this replaces on the
-    single-shot dispatch path)."""
+    exists to absorb write churn for the UNFILTERED group tensor (a
+    filter changes per query, so its sweeps are not maintainable). It
+    is what lets N>=3 GroupBy absorb writes on the host (exec/tiers.py
+    refresh_entry): totals are its int64 sum over shards, and a write
+    epoch that dirtied D shards replaces D rows, from host slabs that
+    must agree with it (_host_slab_groupn), instead of re-sweeping the
+    stacks — the same design as pair_stats_pershard for two fields."""
     return _group_tile(f_stack, g_stack, extras, rows_idx, active, None, True)
 
 

@@ -689,7 +689,7 @@ class Bitmap:
         # pack under churn) could otherwise lose its dirty mark — reader
         # sorts, writer inserts + sets dirty, reader stores its stale
         # sort AND clears the flag — and the missing container would
-        # survive every _pack_confirmed retry (exec/tpu.py), silently
+        # survive every _pack_confirmed retry (exec/tiers.py), silently
         # breaking the host tables' exactness invariant.
         self._keys_gen = 0     # bumped by every container insert/delete
         self._keys_built = 0   # generation the cached sort was built at
