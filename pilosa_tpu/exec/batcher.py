@@ -85,6 +85,15 @@ serially per connection (executor.go:231) because its per-shard loop is
 already CPU-parallel. On a TPU the economics invert — dispatches are
 expensive, device sweeps are cheap — so coalescing across requests is
 what makes the serving path reach the batched-kernel throughput.
+
+Within a request too (ISSUE 33): a trip (`submit`) takes any number of
+legs. The executor hands over a request's run of device reads that stand
+side by side (a page's TopN and ten Sums: exec/executor.py `_device_read`)
+as the legs of ONE trip: queued under one visit to the lock, so one drain
+takes them together, and awaited once, where one leg a trip made the
+page eleven drain cycles long. `count()`, `row()`, `bsi()` and `topn()`
+are trips of one leg through the same code. `batch_trips_total` counts
+trips, whatever their legs; `batch_legs_total{kind}` the legs.
 """
 
 from __future__ import annotations
@@ -100,16 +109,24 @@ from pilosa_tpu.utils.stats import global_stats
 from pilosa_tpu.utils.threads import spawn
 
 #: Leg kinds the plane coalesces. count/row/topn legs are built only by
-#: their own submit methods; bsi() takes the kind as an argument and
+#: their own methods; bsi_leg() takes the kind as an argument and
 #: validates it against the bsi_ subset below.
 LEG_KINDS = ("count", "row", "bsi_sum", "bsi_min", "bsi_max", "topn")
+
+
+def topn_trim(pairs, n: int):
+    """A topn leg's ranked vector cut to one submitter's n (0: all)."""
+    if pairs is None:
+        return None
+    return pairs[:n] if n else list(pairs)
 
 
 class _Leg:
     """One enqueued shard-leg: a typed descriptor plus its rendezvous."""
 
     __slots__ = ("kind", "index", "shards", "payload", "event", "result",
-                 "error", "explain", "explain_rec", "queued_at")
+                 "error", "explain", "explain_rec", "queued_at",
+                 "resolved_at")
 
     def __init__(self, kind: str, index: str, shards, payload):
         self.kind = kind
@@ -119,7 +136,8 @@ class _Leg:
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
-        self.queued_at = 0.0  # perf_counter at _submit's append
+        self.queued_at = 0.0  # perf_counter at submit's append
+        self.resolved_at = 0.0  # perf_counter as the event was set
         # EXPLAIN (ISSUE 16): the submitter's plan leg-sink, captured at
         # construction ON THE SUBMITTING THREAD so the leader can
         # attribute this leg's group record into the right plan. None
@@ -128,6 +146,23 @@ class _Leg:
         ex = getattr(current_profile(), "explain", None)
         self.explain = ex.leg_sink() if ex is not None else None
         self.explain_rec: Optional[dict] = None
+
+    def resolve(self, result) -> None:
+        self.result = result
+        self.resolved_at = time.perf_counter()
+        self.event.set()
+
+    def fail(self, error: BaseException) -> None:
+        self.error = error
+        self.resolved_at = time.perf_counter()
+        self.event.set()
+
+    def value(self):
+        """The leg's answer once `submit` has returned: the backend's
+        result, or its error raised on the submitter's thread."""
+        if self.error is not None:
+            raise self.error
+        return self.result
 
 
 class ShardLegBatcher:
@@ -158,67 +193,86 @@ class ShardLegBatcher:
         # GIL-atomic, no lock).
         self._group_ids = itertools.count(1)
 
-    # -- public submit API (one method per leg kind) -----------------------
+    # -- public submit API --------------------------------------------------
+    # One method a leg kind, each a trip of one leg; `bsi_leg` / `topn_leg`
+    # build the same legs unsubmitted, for a caller that has several to
+    # hand over in one trip (`submit`).
 
     def count(self, index: str, calls: list, shards: list[int]) -> list[int]:
         """Block until the batch containing these Count calls resolves;
         returns one count per call. Thread-safe; any thread may become
         leader."""
-        return self._submit(_Leg("count", index, tuple(shards), list(calls)))
+        return self._one(_Leg("count", index, tuple(shards), list(calls)))
 
     def row(self, index: str, call, shards: list[int]):
         """Bitmap materialization (Row/Intersect/Union/... resolve):
         returns the merged Row for the shard set."""
-        return self._submit(_Leg("row", index, tuple(shards), call))
+        return self._one(_Leg("row", index, tuple(shards), call))
+
+    def bsi_leg(self, kind: str, index: str, field_name: str,
+                shards: list[int], filter_call=None) -> _Leg:
+        """BSI aggregate (kind: bsi_sum | bsi_min | bsi_max). The leg's
+        value is the backend's (value, count) tuple, or None when not
+        lowerable (the executor then runs its map-reduce path)."""
+        if kind not in LEG_KINDS or not kind.startswith("bsi_"):
+            raise ValueError(f"unknown bsi leg kind: {kind!r}")
+        return _Leg(kind, index, tuple(shards), (field_name, filter_call))
 
     def bsi(self, kind: str, index: str, field_name: str, shards: list[int],
             filter_call=None):
-        """BSI aggregate (kind: bsi_sum | bsi_min | bsi_max). Returns the
-        backend's (value, count) tuple, or None when not lowerable (the
-        executor then runs its map-reduce path)."""
-        if kind not in LEG_KINDS or not kind.startswith("bsi_"):
-            raise ValueError(f"unknown bsi leg kind: {kind!r}")
-        return self._submit(
-            _Leg(kind, index, tuple(shards), (field_name, filter_call))
+        return self._one(
+            self.bsi_leg(kind, index, field_name, shards, filter_call)
         )
+
+    def topn_leg(self, index: str, field_name: str, shards: list[int],
+                 src_call=None) -> _Leg:
+        """Exact TopN. The leg's value is the FULL ranked vector (or None
+        when not device-servable), computed once per unique (field, src)
+        leg; each submitter trims to its own n (`topn_trim`), so TopN(n=5)
+        and TopN(n=50) on the same field share one launch."""
+        return _Leg("topn", index, tuple(shards), (field_name, src_call))
 
     def topn(self, index: str, field_name: str, shards: list[int], n: int,
              src_call=None):
-        """Exact TopN pairs (or None when not device-servable). The
-        backend computes the FULL ranked vector once per unique
-        (field, src) leg; n trims per submitter at scatter time, so
-        TopN(n=5) and TopN(n=50) on the same field share one launch."""
-        pairs = self._submit(
-            _Leg("topn", index, tuple(shards), (field_name, src_call))
+        return topn_trim(
+            self._one(self.topn_leg(index, field_name, shards, src_call)), n
         )
-        if pairs is None:
-            return None
-        return pairs[:n] if n else list(pairs)
+
+    def _one(self, leg: _Leg):
+        self.submit((leg,))
+        return leg.value()
 
     # -- leader/follower drain ---------------------------------------------
 
-    def _submit(self, leg: _Leg):
+    def submit(self, legs) -> None:
+        """One trip of a request's thread: queue `legs` (one, or a run of
+        a request's reads that stand side by side) in one visit to the
+        lock, lead a drain if no thread is draining, and return when
+        every one of them has resolved; `leg.value()` then gives each
+        answer or raises its error. Legs queued together are taken by
+        one drain together."""
         idle = None
         with self._lock:
-            leg.queued_at = time.perf_counter()
-            self._pending.append(leg)
+            now = time.perf_counter()
+            for leg in legs:
+                leg.queued_at = now
+            self._pending.extend(legs)
             am_leader = not self._leader_active
             if am_leader:
                 self._leader_active = True
                 if self._idle_since is not None:
-                    idle = leg.queued_at - self._idle_since
+                    idle = now - self._idle_since
+        self.stats.count("batch_trips_total")
         if am_leader:
             if idle is not None:
                 self.stats.count("batch_idle_seconds_total", idle)
             self._drain(leader_call=True)
         # Telemetry: a follower's whole cost is this wait (the leader's
         # dispatch work self-attributes inside the backend calls); for
-        # the leader the event is already set and the phase is ~0.
+        # the leader the events are already set and the phase is ~0.
         with current_profile().phase("batch_wait"):
-            leg.event.wait()
-        if leg.error is not None:
-            raise leg.error
-        return leg.result
+            for leg in legs:
+                leg.event.wait()
 
     def _drain(self, leader_call: bool) -> None:
         """Serve queued batches. A leader (client thread) serves exactly
@@ -274,8 +328,7 @@ class ShardLegBatcher:
                         self._release_leadership()
                     for leg in batch + stranded:
                         if not leg.event.is_set():
-                            leg.error = err
-                            leg.event.set()
+                            leg.fail(err)
                     raise
                 with plane.phase("handoff"):
                     # One visit to the lock a turn, as before the plane
@@ -390,9 +443,8 @@ class ShardLegBatcher:
                 off = 0
                 for leg in legs:
                     n = len(leg.payload)
-                    leg.result = [int(v) for v in values[off : off + n]]
+                    leg.resolve([int(v) for v in values[off : off + n]])
                     off += n
-                    leg.event.set()
 
         return resolve
 
@@ -414,8 +466,7 @@ class ShardLegBatcher:
             rows = resolver()
             with current_profile().phase("scatter"):
                 for leg, row in zip(legs, rows):
-                    leg.result = row
-                    leg.event.set()
+                    leg.resolve(row)
 
         return resolve
 
@@ -452,13 +503,11 @@ class ShardLegBatcher:
                     )
             except Exception as e:  # noqa: BLE001 — delivered to waiters
                 for leg in members:
-                    leg.error = e
-                    leg.event.set()
+                    leg.fail(e)
                 continue
             with current_profile().phase("scatter"):
                 for leg in members:
-                    leg.result = result
-                    leg.event.set()
+                    leg.resolve(result)
 
     # -- error isolation ----------------------------------------------------
 
@@ -471,9 +520,9 @@ class ShardLegBatcher:
                     resolver = self.backend.count_batch_async(
                         leg.index, leg.payload, list(leg.shards)
                     )
-                    leg.result = [int(v) for v in resolver()]
+                    result = [int(v) for v in resolver()]
                 elif leg.kind == "row":
-                    leg.result = self.backend.bitmap_call(
+                    result = self.backend.bitmap_call(
                         leg.index, leg.payload, list(leg.shards)
                     )
                 else:  # bsi_*/topn legs retry through _serve_sync directly
@@ -482,8 +531,9 @@ class ShardLegBatcher:
                     )
                     continue
             except Exception as e:  # noqa: BLE001 — delivered to waiter
-                leg.error = e
-            leg.event.set()
+                leg.fail(e)
+                continue
+            leg.resolve(result)
 
 
 #: Backward-compatible name: the plane grew out of the Count-only

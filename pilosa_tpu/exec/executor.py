@@ -1,8 +1,10 @@
 """The PQL executor (reference executor.go).
 
 Entry point execute() mirrors the reference's flow (executor.go:113):
-translate keys to ids, execute each top-level call (serially — later calls
-may read earlier writes), translate result ids back to keys. Per-call
+translate keys to ids, execute each top-level call (in order — later calls
+read earlier writes; device reads that stand side by side, with no write
+between them, go to the batcher together and are awaited once, see
+`_device_read`), translate result ids back to keys. Per-call
 evaluation fans shards out through map_reduce(), whose local form is a
 plain loop/thread-pool (reference mapperLocal worker pool :2578) and whose
 cluster form is wired in by the cluster layer. Per-shard bitmap evaluation
@@ -12,6 +14,7 @@ is delegated to a backend (CPU oracle or the TPU device backend).
 from __future__ import annotations
 
 import datetime as dt
+import operator
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Union
 
@@ -21,6 +24,7 @@ from pilosa_tpu.core.field import FIELD_TYPE_BOOL, FIELD_TYPE_INT, FIELD_TYPE_TI
 from pilosa_tpu.core.row import Row
 from pilosa_tpu.core.timequantum import parse_time, views_by_time_range
 from pilosa_tpu.core.view import VIEW_STANDARD
+from pilosa_tpu.exec.batcher import topn_trim
 from pilosa_tpu.exec.cpu import CPUBackend, NotFoundError, QueryError
 from pilosa_tpu.exec.result import (
     FieldRow,
@@ -63,6 +67,26 @@ class ExecOptions:
     # serialization layer can serve/attach pre-encoded response bytes
     # on the entry (exec/rescache.py wire_for/attach_wire).
     wire_sink: Optional[list] = None
+
+
+#: The batcher leg kind (and the backend's method) of a BSI aggregate.
+_BSI_KIND = {"Sum": "bsi_sum", "Min": "bsi_min", "Max": "bsi_max"}
+
+
+class _Read:
+    """One device read of a request's body, prepared (deadline, key
+    translation, result-cache token, EXPLAIN node) and waiting for the
+    run it belongs to to be submitted: `leg` is its batcher leg, or None
+    where the result cache answered (`token.value`)."""
+
+    __slots__ = ("call", "node", "token", "shards", "leg")
+
+    def __init__(self, call, node, token, shards=None, leg=None):
+        self.call = call
+        self.node = node
+        self.token = token
+        self.shards = shards
+        self.leg = leg
 
 
 class Executor:
@@ -178,6 +202,60 @@ class Executor:
         ):
             cache = None
 
+        # Device reads prepared and not yet submitted (`_Read`): a run of
+        # a request's Sum / Min / Max / TopN calls that stand side by
+        # side goes to the batcher as the legs of one trip.
+        reads: list[_Read] = []
+
+        def finish(call, node, token, result):
+            """What every executed call ends with: its EXPLAIN route, its
+            result's translation, the cache commit, its place in the
+            answer."""
+            if node is not None:
+                node["route"] = "execute"
+                node["devices"] = self._explain_devices()
+                if prof.shards is not None:
+                    node["shards"] = prof.shards
+            if not opt.remote:
+                check_deadline("key_translate")
+                with prof.phase("key_translate"):
+                    result = self._translate_result(idx, call, result)
+            if token is not None:
+                cache.commit(token, result)
+            results.append(result)
+            if opt.wire_sink is not None:
+                opt.wire_sink.append(token)
+
+        def flush():
+            """One trip to the batcher with the pending reads' legs, then
+            every read finished in call order: a leg's error is raised,
+            and a leg that was not lowerable falls to its map-reduce
+            path, at its call's place."""
+            if not reads:
+                return
+            run = reads[:]
+            reads.clear()
+            legs = [r.leg for r in run if r.leg is not None]
+            if legs:
+                submitted = _time.perf_counter()
+                self.batcher.submit(legs)
+            for r in run:
+                if r.leg is None:  # the result cache's answer
+                    results.append(r.token.value)
+                    if opt.wire_sink is not None:
+                        opt.wire_sink.append(r.token)
+                    continue
+                # The call's latency is its own leg's, from the run's
+                # submission: the calls of a run overlap.
+                with prof.call_timer(
+                    r.call.name, waited=r.leg.resolved_at - submitted
+                ):
+                    with self.tracer.start_span(
+                        f"executor.execute{r.call.name}"
+                    ):
+                        result = self._read_result(index, r, opt)
+                    finish(r.call, r.node, r.token, result)
+
         with self.tracer.start_span("executor.Execute") as span:
             span.set_tag("index", index)
             calls = query.calls
@@ -199,6 +277,7 @@ class Executor:
                     ):
                         run += 1
                 if run > 1 or (run == 1 and self.batcher is not None):
+                    flush()
                     check_deadline("plan")
                     batch = calls[i : i + run]
                     stats.count("query_Count_total", run)
@@ -280,81 +359,93 @@ class Executor:
                     i += run
                     continue
                 call = calls[i]
-                check_deadline("plan")
-                stats.count(f"query_{call.name}_total")
-                ex = getattr(prof, "explain", None)
-                node = ex.begin_call(call.name) if ex is not None else None
-                # Remote (peer-issued) requests arrive pre-translated and
-                # are returned raw; translation happens only at the
-                # coordinator (reference executor.go:121-127).
-                if not opt.remote and (translate or call.has_str_args):
-                    with prof.phase("key_translate"):
-                        call = self._translate_call(idx, call)
-                # Cache consult AFTER key translation (keys share the
-                # translated-ids spelling; id->key maps are append-only
-                # so cached key-translated results stay valid) and
-                # BEFORE planning/dispatch. The miss's answer commits
-                # fully translated, so a hit skips the whole pipeline.
-                token = None
-                if cache is not None and not opt.cache_bypass:
-                    token = cache.begin(
-                        index, call, self._shards(index, shards),
-                        exclude_row_attrs=opt.exclude_row_attrs,
-                        remote=opt.remote,
-                    )
-                    if token is not None:
-                        prof.incr("cache_lookups")
-                        if token.hit:
-                            prof.incr("cache_hits")
+                try:
+                    check_deadline("plan")
+                    stats.count(f"query_{call.name}_total")
+                    ex = getattr(prof, "explain", None)
+                    node = ex.begin_call(call.name) if ex is not None else None
+                    # Remote (peer-issued) requests arrive pre-translated
+                    # and are returned raw; translation happens only at
+                    # the coordinator (reference executor.go:121-127).
+                    if not opt.remote and (translate or call.has_str_args):
+                        with prof.phase("key_translate"):
+                            call = self._translate_call(idx, call)
+                    # A device read (`_device_read`) joins the reads
+                    # before it; any other call is a barrier: what is
+                    # pending is submitted and answered first, so a write
+                    # stands between the reads on either side of it.
+                    member = self._device_read(index, call)
+                    if member is None and reads:
+                        flush()
+                    # Cache consult AFTER key translation (keys share the
+                    # translated-ids spelling; id->key maps are
+                    # append-only so cached key-translated results stay
+                    # valid) and BEFORE planning/dispatch. The miss's
+                    # answer commits fully translated, so a hit skips the
+                    # whole pipeline.
+                    token = None
+                    if cache is not None and not opt.cache_bypass:
+                        token = cache.begin(
+                            index, call, self._shards(index, shards),
+                            exclude_row_attrs=opt.exclude_row_attrs,
+                            remote=opt.remote,
+                        )
+                        if token is not None:
+                            prof.incr("cache_lookups")
+                            if token.hit:
+                                prof.incr("cache_hits")
+                                if node is not None:
+                                    node["route"] = "rescache"
+                                    node["cache"] = {
+                                        "verdict": "hit",
+                                        "staleBy": getattr(
+                                            token, "stale_by", 0
+                                        ),
+                                    }
+                                # Answered: it queues nothing, and keeps
+                                # its place among the reads around it.
+                                reads.append(_Read(call, node, token))
+                                i += 1
+                                continue
                             if node is not None:
-                                node["route"] = "rescache"
-                                node["cache"] = {
-                                    "verdict": "hit",
-                                    "staleBy": getattr(
-                                        token, "stale_by", 0
-                                    ),
-                                }
-                            results.append(token.value)
-                            if opt.wire_sink is not None:
-                                opt.wire_sink.append(token)
-                            i += 1
-                            continue
+                                node["cache"] = {"verdict": "miss"}
+                        else:
+                            # Fresh-computed answer the cache never held
+                            # (uncacheable call/coverage): the response
+                            # marker must not claim a pure cache serve.
+                            prof.incr("cache_uncached")
+                            if node is not None:
+                                node["cache"] = {"verdict": "uncacheable"}
+                    elif cache is not None and call.name in cache.CACHEABLE:
+                        cache.count_bypass(index)
+                        prof.incr("cache_bypass")
                         if node is not None:
-                            node["cache"] = {"verdict": "miss"}
-                    else:
-                        # Fresh-computed answer the cache never held
-                        # (uncacheable call/coverage): the response
-                        # marker must not claim a pure cache serve.
-                        prof.incr("cache_uncached")
-                        if node is not None:
-                            node["cache"] = {"verdict": "uncacheable"}
-                elif cache is not None and call.name in cache.CACHEABLE:
-                    cache.count_bypass(index)
-                    prof.incr("cache_bypass")
-                    if node is not None:
-                        node["cache"] = {"verdict": "bypass"}
-                check_deadline("device_dispatch")
+                            node["cache"] = {"verdict": "bypass"}
+                    check_deadline("device_dispatch")
+                    if member is not None:
+                        sh = self._shards(index, shards)
+                        reads.append(_Read(
+                            call, node, token, sh,
+                            self._read_leg(index, call, sh, *member),
+                        ))
+                        i += 1
+                        continue
+                except Exception:
+                    # The reads before a call that fails in preparation
+                    # are answered before it fails, as one after the
+                    # other they would have been: an error of theirs
+                    # comes first.
+                    flush()
+                    raise
                 # A call's own latency, whichever place it has in the
                 # request's body: `query_seconds{call}` is the request's,
                 # under its first call's name.
                 with prof.call_timer(call.name):
                     with self.tracer.start_span(f"executor.execute{call.name}"):
                         result = self.execute_call(index, call, shards, opt)
-                    if node is not None:
-                        node["route"] = "execute"
-                        node["devices"] = self._explain_devices()
-                        if prof.shards is not None:
-                            node["shards"] = prof.shards
-                    if not opt.remote:
-                        check_deadline("key_translate")
-                        with prof.phase("key_translate"):
-                            result = self._translate_result(idx, call, result)
-                if token is not None:
-                    cache.commit(token, result)
-                results.append(result)
-                if opt.wire_sink is not None:
-                    opt.wire_sink.append(token)
+                    finish(call, node, token, result)
                 i += 1
+            flush()
             # Phase breakdown on the executor span so /debug/traces shows
             # where each trace's time went (serialize happens above this
             # span and lands only in /metrics + /debug/queries).
@@ -541,9 +632,9 @@ class Executor:
 
     def execute_call(self, index: str, c: Call, shards: Optional[list[int]], opt: ExecOptions) -> Any:
         handlers = {
-            "Sum": self._execute_sum,
-            "Min": self._execute_min,
-            "Max": self._execute_max,
+            "Sum": self._execute_bsi,
+            "Min": self._execute_bsi,
+            "Max": self._execute_bsi,
             "MinRow": self._execute_min_row,
             "MaxRow": self._execute_max_row,
             "Count": self._execute_count,
@@ -702,6 +793,12 @@ class Executor:
             r = self.batcher.bsi(kind, index, f.name, shards, filter_call)
         else:
             r = getattr(self.backend, kind)(index, f.name, shards, filter_call)
+        return self._val_count(r)
+
+    @staticmethod
+    def _val_count(r) -> Optional[ValCount]:
+        """The backend's (value, count) as the call's result; None stays
+        None (not lowerable)."""
         if r is None:
             return None
         val, cnt = r
@@ -720,41 +817,40 @@ class Executor:
             raise NotFoundError(f"field not found: {field_name}")
         return f
 
-    def _execute_sum(self, index, c, shards, opt) -> ValCount:
-        """reference executor.go executeSum :406."""
+    def _execute_bsi(self, index, c, shards, opt) -> ValCount:
+        """Sum, Min or Max (reference executor.go executeSum :406)."""
         f = self._agg_field(index, c)
         if len(c.children) > 1:
-            raise QueryError("Sum() only accepts a single bitmap input")
-
-        fast = self._bsi_fast("bsi_sum", index, f, c, shards)
+            raise QueryError(f"{c.name}() only accepts a single bitmap input")
+        fast = self._bsi_fast(_BSI_KIND[c.name], index, f, c, shards)
         if fast is not None:
             return fast
+        return self._bsi_shards(index, f, c, shards, opt)
+
+    def _bsi_shards(self, index, f, c, shards, opt) -> ValCount:
+        """Sum / Min / Max by map-reduce over the shards' fragments: the
+        path of a call the device does not lower."""
+        if c.name == "Sum":
+            def map_fn(shard):
+                filt = self._filter_row_shard(index, c, shard)
+                s, cnt = f.sum(filt, shard)
+                return ValCount(s, cnt)
+
+            def reduce_fn(a, b):
+                return ValCount(a.val + b.val, a.count + b.count)
+
+            out = self.map_reduce(index, shards, c, opt, map_fn, reduce_fn)
+            return out if out is not None and out.count else ValCount()
+
+        # Min keeps the smaller value, Max the larger; equal values add
+        # their counts.
+        field_extreme, better = (
+            (f.min, operator.lt) if c.name == "Min" else (f.max, operator.gt)
+        )
 
         def map_fn(shard):
             filt = self._filter_row_shard(index, c, shard)
-            s, cnt = f.sum(filt, shard)
-            return ValCount(s, cnt)
-
-        def reduce_fn(a, b):
-            return ValCount(a.val + b.val, a.count + b.count)
-
-        out = self.map_reduce(index, shards, c, opt, map_fn, reduce_fn) or ValCount()
-        if out.count == 0:
-            return ValCount()
-        return out
-
-    def _execute_min(self, index, c, shards, opt) -> ValCount:
-        f = self._agg_field(index, c)
-        if len(c.children) > 1:
-            raise QueryError("Min() only accepts a single bitmap input")
-
-        fast = self._bsi_fast("bsi_min", index, f, c, shards)
-        if fast is not None:
-            return fast
-
-        def map_fn(shard):
-            filt = self._filter_row_shard(index, c, shard)
-            v, cnt = f.min(filt, shard)
+            v, cnt = field_extreme(filt, shard)
             return ValCount(v, cnt)
 
         def reduce_fn(a, b):
@@ -762,36 +858,9 @@ class Executor:
                 return b
             if b.count == 0:
                 return a
-            if a.val < b.val:
+            if better(a.val, b.val):
                 return a
-            if b.val < a.val:
-                return b
-            return ValCount(a.val, a.count + b.count)
-
-        return self.map_reduce(index, shards, c, opt, map_fn, reduce_fn) or ValCount()
-
-    def _execute_max(self, index, c, shards, opt) -> ValCount:
-        f = self._agg_field(index, c)
-        if len(c.children) > 1:
-            raise QueryError("Max() only accepts a single bitmap input")
-
-        fast = self._bsi_fast("bsi_max", index, f, c, shards)
-        if fast is not None:
-            return fast
-
-        def map_fn(shard):
-            filt = self._filter_row_shard(index, c, shard)
-            v, cnt = f.max(filt, shard)
-            return ValCount(v, cnt)
-
-        def reduce_fn(a, b):
-            if a.count == 0:
-                return b
-            if b.count == 0:
-                return a
-            if a.val > b.val:
-                return a
-            if b.val > a.val:
+            if better(b.val, a.val):
                 return b
             return ValCount(a.val, a.count + b.count)
 
@@ -860,18 +929,25 @@ class Executor:
     # TopN (two-pass, reference executor.go:860-997)
     # ------------------------------------------------------------------
 
+    @staticmethod
+    def _topn_plain(c) -> bool:
+        """No rank-cache-only option in play: the device's exact
+        single-pass TopN (popcount-per-row + top_k) can answer."""
+        return not any(
+            k in c.args for k in ("ids", "threshold", "tanimotoThreshold", "attrName")
+        )
+
     def _execute_topn(self, index, c, shards, opt) -> PairsField:
         field_name = c.args.get("_field")
         if not field_name:
             raise QueryError("TopN() field required")
         n, _ = c.uint64_arg("n")
 
-        # Device fast path: exact single-pass TopN (popcount-per-row +
-        # top_k) when no rank-cache-only options are in play.
-        plain = not any(
-            k in c.args for k in ("ids", "threshold", "tanimotoThreshold", "attrName")
-        )
-        if plain and self.mapper is None and hasattr(self.backend, "topn_field"):
+        if (
+            self._topn_plain(c)
+            and self.mapper is None
+            and hasattr(self.backend, "topn_field")
+        ):
             src_call = c.children[0] if c.children else None
             if self.batcher is not None:
                 # Concurrent TopN legs on the same (field, src) share one
@@ -881,7 +957,9 @@ class Executor:
                 exact = self.backend.topn_field(index, field_name, shards, n, src_call)
             if exact is not None:
                 return PairsField(exact, field_name)
+        return self._topn_two_pass(index, c, n, shards, opt)
 
+    def _topn_two_pass(self, index, c, n, shards, opt) -> PairsField:
         # Pass 1: approximate candidates from rank caches.
         pairs = self._execute_topn_shards(index, c, shards, opt)
 
@@ -897,6 +975,57 @@ class Executor:
         if not opt.remote:
             pairs.pairs = top_n_pairs(pairs.pairs, n)
         return pairs
+
+    # ------------------------------------------------------------------
+    # a request's run of device reads (ISSUE 33)
+    # ------------------------------------------------------------------
+
+    def _device_read(self, index, c) -> Optional[tuple]:
+        """(leg kind, field name) where `c` is a call that becomes exactly
+        one synchronous batcher leg, under the conditions `_bsi_fast` and
+        `_execute_topn` go to the batcher: a Sum, Min or Max, or a plain
+        TopN. None for everything else, and for a call that would raise
+        (no such field, two children): it raises where it stands, on the
+        path every other call takes. Such reads write nothing, so those
+        that stand side by side in a request are independent."""
+        if self.batcher is None or self.mapper is not None:
+            return None
+        try:
+            if c.name == "TopN":
+                field_name = c.args.get("_field")
+                c.uint64_arg("n")
+                if (
+                    field_name
+                    and self._topn_plain(c)
+                    and hasattr(self.backend, "topn_field")
+                ):
+                    return "topn", field_name
+                return None
+            kind = _BSI_KIND.get(c.name)
+            if kind is None or len(c.children) > 1 or not hasattr(self.backend, kind):
+                return None
+            return kind, self._agg_field(index, c).name
+        except (QueryError, ValueError):
+            return None
+
+    def _read_leg(self, index, c, shards, kind, field_name):
+        sub = c.children[0] if c.children else None
+        if kind == "topn":
+            return self.batcher.topn_leg(index, field_name, shards, sub)
+        return self.batcher.bsi_leg(kind, index, field_name, shards, sub)
+
+    def _read_result(self, index, r: _Read, opt):
+        """The result of a read whose leg has resolved; the leg's error
+        is raised here, at its call's turn."""
+        c, raw = r.call, r.leg.value()
+        if c.name == "TopN":
+            n, _ = c.uint64_arg("n")
+            if raw is not None:
+                return PairsField(topn_trim(raw, n), c.args["_field"])
+            return self._topn_two_pass(index, c, n, r.shards, opt)
+        if raw is not None:
+            return self._val_count(raw)
+        return self._bsi_shards(index, self._agg_field(index, c), c, r.shards, opt)
 
     def _execute_topn_shards(self, index, c, shards, opt) -> PairsField:
         field_name = c.args["_field"]

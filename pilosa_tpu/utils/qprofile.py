@@ -224,13 +224,20 @@ class _CallTimer:
     stretches in which its thread is in no phase and serves no drain: a
     phase and a drain step carry spans of their own, and a wait carries
     none (WAITING_PHASES), so the call's span names what is left, the
-    thread's own work between them."""
+    thread's own work between them. A member of a run of reads
+    (exec/executor.py `_device_read`) opens its timer when its turn
+    comes after the run's one wait, with `waited` = the run's submission
+    to its own leg's resolution: one observation a call all the same,
+    and the calls of a run overlap."""
 
-    __slots__ = ("profile", "name", "span", "covered", "t0")
+    __slots__ = ("profile", "name", "span", "covered", "t0", "waited")
 
-    def __init__(self, profile: "QueryProfile", name: str):
+    def __init__(self, profile: "QueryProfile", name: str,
+                 waited: float = 0.0):
         self.profile = profile
         self.name = name
+        #: Seconds the call had already taken when the timer opens.
+        self.waited = waited
         self.span = None
         #: Phases and drains open inside the call (and, outside `with`,
         #: the call's own being shut): the span is open while this is 0.
@@ -246,7 +253,8 @@ class _CallTimer:
         from pilosa_tpu.utils.stats import global_stats
 
         global_stats.with_tags(f"call:{self.name}").timing(
-            "query_call_seconds", time.perf_counter() - self.t0
+            "query_call_seconds",
+            self.waited + time.perf_counter() - self.t0,
         )
         self.suspend()
         self.profile.in_call = None
@@ -310,9 +318,9 @@ class QueryProfile:
         #: The call of the request's body being executed (`call`).
         self.in_call: Optional[_CallTimer] = None
 
-    def call_timer(self, name: str) -> _CallTimer:
+    def call_timer(self, name: str, waited: float = 0.0) -> _CallTimer:
         """Time one call of the request's body (see _CallTimer)."""
-        return _CallTimer(self, name)
+        return _CallTimer(self, name, waited)
 
     def phase(self, name: str, span: Optional[str] = None,
               **meta) -> _PhaseTimer:
@@ -425,7 +433,7 @@ class NopProfile:
         # shows in a trace: the span alone.
         return _span(name, span, meta) or self._PHASE
 
-    def call_timer(self, name: str):
+    def call_timer(self, name: str, waited: float = 0.0):
         return self._PHASE
 
     def add_phase(self, name: str, seconds: float) -> None:

@@ -184,6 +184,50 @@ class TestLegComposition:
     def test_countbatcher_alias(self):
         assert CountBatcher is ShardLegBatcher
 
+    @pytest.mark.parametrize("n_sums", [0, 1, 10])
+    def test_a_run_of_legs_is_one_trip_and_one_drain(self, n_sums):
+        """`submit` queues a request's run of legs (a TopN and n Sums) in
+        one visit to the lock: one trip, one drain that takes them all,
+        every leg resolved when it returns, each with its own answer."""
+        be = StubBackend()
+        b = ShardLegBatcher(be)
+        b.stats = StatsClient()
+        filters = [object() for _ in range(n_sums)]
+        legs = [b.topn_leg("i", "f", [0, 1])] + [
+            b.bsi_leg("bsi_sum", "i", "v" * (k + 1), [0, 1], filters[k])
+            for k in range(n_sums)
+        ]
+        b.submit(legs)
+        assert b._pending == [] and not b._leader_active
+        assert all(leg.event.is_set() for leg in legs)
+        assert [leg.value() for leg in legs[1:]] == [
+            (100 * (k + 1), 7) for k in range(n_sums)
+        ]
+        assert legs[0].value() == [(r, 50 - r) for r in range(5)]
+        assert all(leg.resolved_at >= leg.queued_at > 0 for leg in legs)
+        assert len({leg.queued_at for leg in legs}) == 1  # stamped together
+        assert be.bsi_calls == [
+            ("bsi_sum", "v" * (k + 1), filters[k]) for k in range(n_sums)
+        ]
+        assert b.stats.counter_totals("batch_trips_total", "batch_drains_total") == {
+            "batch_trips_total": 1.0, "batch_drains_total": 1.0,
+        }
+        legs_by_kind = b.stats.counter_totals("batch_legs_total")
+        assert legs_by_kind.get('batch_legs_total{kind="bsi_sum"}', 0) == n_sums
+        assert legs_by_kind['batch_legs_total{kind="topn"}'] == 1
+
+    def test_every_one_leg_method_is_one_trip(self):
+        be = StubBackend()
+        b = ShardLegBatcher(be)
+        b.stats = StatsClient()
+        b.count("i", [4, 5], [0])
+        b.row("i", "r", [0])
+        b.bsi("bsi_min", "i", "v", [0])
+        b.topn("i", "f", [0], 2)
+        assert b.stats.counter_totals("batch_trips_total") == {
+            "batch_trips_total": 4.0
+        }
+
 
 class TestErrorIsolation:
     def test_bad_count_leg_fails_only_its_submitter(self):
@@ -232,6 +276,42 @@ class TestErrorIsolation:
         bads = [g for g in got if isinstance(g, ValueError)]
         assert len(bads) == 1
         assert (100, 7) in got
+
+
+    @pytest.mark.parametrize("bad_at", [0, 1, 2])
+    def test_bad_leg_of_a_run_reaches_only_its_request(self, bad_at):
+        """Two requests' runs share a drain (identical legs share one
+        backend call); the leg that fails raises in its own request, at
+        its own place, and nowhere else."""
+        be = StubBackend()
+        b = ShardLegBatcher(be, window=0.25)
+        b.stats = StatsClient()
+        fields = ["v", "vv", "vvv"]
+
+        def run(names):
+            def go():
+                legs = [b.bsi_leg("bsi_sum", "i", n, [0]) for n in names]
+                b.submit(legs)
+                out = []
+                for leg in legs:
+                    try:
+                        out.append(leg.value())
+                    except ValueError as e:
+                        out.append(e)
+                return out
+            return go
+
+        with_bad = list(fields)
+        with_bad.insert(bad_at, "boom")
+        good, mixed = _run_threads([run(fields), run(with_bad)])
+        assert good == [(100, 7), (200, 7), (300, 7)]
+        assert isinstance(mixed.pop(bad_at), ValueError)
+        assert mixed == good
+        # One drain served both runs, and each distinct Sum once.
+        assert b.stats.counter_totals("batch_drains_total", "batch_trips_total") == {
+            "batch_drains_total": 1.0, "batch_trips_total": 2.0,
+        }
+        assert sorted(c[1] for c in be.bsi_calls) == fields
 
 
 class TestTelemetry:
@@ -403,6 +483,43 @@ class TestBatchedDifferential:
             assert result_to_json(ex.execute("i", q)[0]) == result_to_json(
                 oracle.execute("i", q)[0]
             ), q
+
+
+    @pytest.mark.parametrize("window, clients", [(0.0, 1), (0.15, 4)])
+    def test_a_body_of_reads_through_the_device_backend_matches(
+            self, window, clients, holder, rng):
+        """A request's run of reads (ISSUE 33), alone and from several
+        clients whose runs share drains: every result of every body is
+        the oracle's, in call order; a write between two reads is read
+        by the second."""
+        from pilosa_tpu.exec import Executor
+        from pilosa_tpu.exec.result import result_to_json
+        from pilosa_tpu.exec.tpu import TPUBackend
+
+        _build_index(holder, rng)
+        be = TPUBackend(holder)
+        ex = Executor(holder, backend=be)
+        ex.batcher = ShardLegBatcher(be, window=window)
+        ex.batcher.stats = StatsClient()
+        oracle = Executor(holder)
+        body = ("TopN(f) Sum(Row(f=1), field=v) Sum(Row(f=2), field=v) "
+                "Min(field=v) Max(Row(g=9), field=v) TopN(f, Row(g=9), n=1) "
+                "Count(Row(f=1)) Sum(field=v) GroupBy(Rows(f))")
+        want = [result_to_json(r) for r in oracle.execute("i", body)]
+        got = _run_threads([
+            lambda: [result_to_json(r) for r in ex.execute("i", body)]
+        ] * clients)
+        assert got == [want] * clients
+        # Each client: the six reads before the Count in one trip, the
+        # Count's, the Sum's after it.
+        assert ex.batcher.stats.counter_totals("batch_trips_total") == {
+            "batch_trips_total": 3.0 * clients
+        }
+        if clients == 1:
+            col = 7
+            a, _, b = ex.execute("i", f"Sum(field=v) Set({col}, v=333) Sum(field=v)")
+            assert oracle.execute("i", "Sum(field=v)")[0] == b
+            assert (b.val, b.count) != (a.val, a.count)
 
 
 # -- the plane's own profile (ISSUE 26) ----------------------------------

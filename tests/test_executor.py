@@ -1,6 +1,8 @@
 """Executor tests — the PQL op coverage mirrors the reference's
 executor_test.go (every op, keyed variants, existence, GroupBy)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -13,8 +15,16 @@ from pilosa_tpu.core.field import (
 )
 from pilosa_tpu.core.index import IndexOptions
 from pilosa_tpu.exec import Executor
-from pilosa_tpu.exec.cpu import QueryError
+from pilosa_tpu.exec import executor as executor_module
+from pilosa_tpu.exec.batcher import ShardLegBatcher
+from pilosa_tpu.exec.cpu import CPUBackend, NotFoundError, QueryError
+from pilosa_tpu.exec.executor import ExecOptions
+from pilosa_tpu.exec.rescache import ResultCache
+from pilosa_tpu.exec.result import result_to_json
+from pilosa_tpu.pql import Call
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils.deadline import DeadlineExceeded
+from pilosa_tpu.utils.stats import global_stats
 
 
 @pytest.fixture
@@ -422,3 +432,254 @@ class TestReviewRegressions:
         assert rows.to_json() == {"keys": ["red", "blue"]} or set(
             rows.to_json()["keys"]
         ) == {"red", "blue"}
+
+
+# -- a request's run of device reads goes to the batcher in one trip
+#    (ISSUE 33) -----------------------------------------------------------
+
+
+class LegBackend(CPUBackend):
+    """The host oracle with the device backend's synchronous leg methods
+    on top (each answered by a plain executor over the same holder), so
+    that the executor takes the serving path: Sum / Min / Max / TopN go
+    to a batcher as legs. Every backend call is recorded; a field named
+    in `not_lowerable` answers None, one named in `broken` raises."""
+
+    def __init__(self, holder):
+        super().__init__(holder)
+        self.oracle = Executor(holder)
+        self.calls = []
+        self.not_lowerable = set()
+        self.broken = set()
+        self.delay = 0.0  # seconds a BSI leg takes to serve
+
+    def _bsi(self, name, index, field, shards, filt):
+        self.calls.append((name, field))
+        time.sleep(self.delay)
+        if field in self.broken:
+            raise RuntimeError(f"device lost under {name}({field})")
+        if (name, field) in self.not_lowerable:
+            return None
+        c = Call(name, {"field": field}, [filt] if filt is not None else [])
+        vc = self.oracle._execute_bsi(index, c, shards, ExecOptions())
+        return vc.val, vc.count
+
+    def bsi_sum(self, index, field, shards, filt=None):
+        return self._bsi("Sum", index, field, shards, filt)
+
+    def bsi_min(self, index, field, shards, filt=None):
+        return self._bsi("Min", index, field, shards, filt)
+
+    def bsi_max(self, index, field, shards, filt=None):
+        return self._bsi("Max", index, field, shards, filt)
+
+    def topn_field(self, index, field, shards, n, src=None):
+        self.calls.append(("TopN", field))
+        if ("TopN", field) in self.not_lowerable:
+            return None
+        c = Call("TopN", {"_field": field, "n": n}, [src] if src is not None else [])
+        return self.oracle._execute_topn(index, c, shards, ExecOptions()).pairs
+
+
+def build_page_index(holder, name="i"):
+    idx = holder.create_index(name)
+    f = idx.create_field("f")
+    g = idx.create_field("g")
+    v = idx.create_field("v", options_for_int(-1000, 1000))
+    w = idx.create_field("w", options_for_int(0, 1000))
+    cols = np.arange(0, 3 * SHARD_WIDTH, SHARD_WIDTH // 8, dtype=np.uint64)
+    f.import_bits(cols % 10, cols)
+    g.import_bits(cols % 3, cols)
+    v.import_value(cols, (cols // 7 % 1800).astype(np.int64) - 900)
+    w.import_value(cols[::2], (cols[::2] // 5 % 1000).astype(np.int64))
+    return idx
+
+
+@pytest.fixture
+def served(holder):
+    """(executor on the serving path with a batcher, its backend, the
+    legs of every trip to the batcher)."""
+    build_page_index(holder)
+    be = LegBackend(holder)
+    ex = Executor(holder, backend=be)
+    ex.batcher = ShardLegBatcher(be)
+    trips = []
+    submit = ex.batcher.submit
+
+    def recorded(legs):
+        trips.append([(leg.kind, leg.payload[0]) for leg in legs])
+        assert ex.batcher._pending == []  # nothing of an earlier trip is left
+        submit(legs)
+
+    ex.batcher.submit = recorded
+    return ex, be, trips
+
+
+PAGE = "TopN(f) " + " ".join(f"Sum(Row(f={k}), field=v)" for k in range(10))
+PAGE_LEGS = [("topn", "f")] + [("bsi_sum", "v")] * 10
+
+#: body -> the legs of each trip it makes, in order
+RUN_BODIES = {
+    "page": (PAGE, [PAGE_LEGS]),
+    "page_then_groupbys": (
+        PAGE + " GroupBy(Rows(g), Rows(f)) GroupBy(Rows(f))", [PAGE_LEGS]),
+    "every_member_kind": (
+        "Min(field=v) Max(Row(g=1), field=v) TopN(f, n=2) TopN(g, Row(f=1)) Sum(field=w)",
+        [[("bsi_min", "v"), ("bsi_max", "v"), ("topn", "f"), ("topn", "g"),
+          ("bsi_sum", "w")]]),
+    "one_read": ("Sum(field=v)", [[("bsi_sum", "v")]]),
+    "write_is_a_barrier": (
+        "Sum(field=v) Set(1, v=777) Sum(field=v) Clear(1, f=1) TopN(f)",
+        [[("bsi_sum", "v")], [("bsi_sum", "v")], [("topn", "f")]]),
+    "groupby_ends_the_run": (
+        "Sum(field=v) Sum(field=w) GroupBy(Rows(f)) Sum(field=v)",
+        [[("bsi_sum", "v"), ("bsi_sum", "w")], [("bsi_sum", "v")]]),
+    "bitmap_call_and_rows_end_it": (
+        "Min(field=v) Row(f=1) Max(field=v) Rows(f) MinRow(field=f) Sum(field=v)",
+        [[("bsi_min", "v")], [("bsi_max", "v")], [("bsi_sum", "v")]]),
+    "topn_with_ids_is_no_member": (
+        "Sum(field=v) TopN(f, ids=[1, 2]) Sum(field=w)",
+        [[("bsi_sum", "v")], [("bsi_sum", "w")]]),
+    "options_ends_it_and_makes_its_own_trip": (
+        "Sum(field=v) Options(Sum(field=v), shards=[0]) Sum(field=w)",
+        [[("bsi_sum", "v")], [("bsi_sum", "v")], [("bsi_sum", "w")]]),
+}
+
+
+def as_json(results):
+    return [result_to_json(r) for r in results]
+
+
+class TestReadRuns:
+    @pytest.mark.parametrize("case", sorted(RUN_BODIES))
+    def test_a_run_is_one_trip_answered_in_call_order(self, case, served, tmp_path):
+        ex, be, trips = served
+        body, want_trips = RUN_BODIES[case]
+        # The serial loop over the host path, on a holder of its own (a
+        # body may write).
+        other = Holder(str(tmp_path / "serial")).open()
+        try:
+            build_page_index(other)
+            want = as_json(Executor(other).execute("i", body))
+        finally:
+            other.close()
+        trips0 = global_stats.counter_totals("batch_trips_total")
+        got = as_json(ex.execute("i", body))
+        assert got == want
+        assert trips == want_trips
+        grown = (global_stats.counter_totals("batch_trips_total")["batch_trips_total"]
+                 - trips0.get("batch_trips_total", 0.0))
+        assert grown == len(want_trips)
+
+    def test_sum_set_sum_reads_the_write_in_the_second_sum_only(self, served):
+        ex, be, trips = served
+        before, = ex.execute("i", "Sum(field=w)")
+        col = 3 * SHARD_WIDTH - 1  # no value of w yet
+        first, changed, second = ex.execute(
+            "i", f"Sum(field=w) Set({col}, w=999) Sum(field=w)")
+        assert changed is True
+        assert (first.val, first.count) == (before.val, before.count)
+        assert (second.val, second.count) == (before.val + 999, before.count + 1)
+
+    @pytest.mark.parametrize("bad, error, match", [
+        ("Sum(field=nope)", NotFoundError, "field not found: nope"),
+        ("Sum(Row(f=1), Row(f=2), field=v)", QueryError,
+         r"Sum\(\) only accepts a single bitmap input"),
+        ("Min(field=3)", ValueError, "could not convert 3 to string"),
+        ("TopN(f, n=true)", ValueError, "could not convert True to uint64"),
+        ("deadline", DeadlineExceeded, "plan"),
+    ])
+    def test_a_member_that_fails_in_preparation(
+            self, bad, error, match, served, monkeypatch):
+        """The serial loop's error, after the reads before it were
+        answered, and no leg queued behind it."""
+        ex, be, trips = served
+        if bad == "deadline":
+            bad = "Sum(field=w)"
+            plans = []
+
+            def check(phase):
+                plans.append(phase)
+                if plans.count("plan") == 3 and phase == "plan":
+                    raise DeadlineExceeded("deadline exceeded before plan", phase)
+
+            monkeypatch.setattr(executor_module, "check_deadline", check)
+        else:
+            with pytest.raises(error, match=match):  # the serial loop's
+                Executor(ex.holder).execute("i", bad)
+        body = f"Sum(field=v) TopN(f) {bad} Sum(field=w) Max(field=v)"
+        with pytest.raises(error, match=match):
+            ex.execute("i", body)
+        assert trips == [[("bsi_sum", "v"), ("topn", "f")]]
+        assert be.calls == [("Sum", "v"), ("TopN", "f")]
+        assert ex.batcher._pending == []
+
+    @pytest.mark.parametrize("missing", [
+        ("Sum", "v"), ("Min", "v"), ("Max", "w"), ("TopN", "f")])
+    def test_a_leg_that_is_not_lowerable_falls_to_map_reduce_at_its_place(
+            self, missing, served):
+        ex, be, trips = served
+        body = "Sum(field=w) Min(field=v) TopN(f, n=3) Sum(field=v) Max(field=w) TopN(g)"
+        want = as_json(Executor(ex.holder).execute("i", body))
+        be.not_lowerable.add(missing)
+        assert as_json(ex.execute("i", body)) == want
+        assert len(trips) == 1 and len(trips[0]) == 6
+
+    def test_a_legs_error_is_raised_at_its_calls_place(self, served):
+        ex, be, trips = served
+        be.broken.add("w")
+        sink = []
+        with pytest.raises(RuntimeError, match=r"device lost under Sum\(w\)"):
+            ex.execute("i", "Sum(field=v) Sum(field=w) TopN(f)",
+                       opt=ExecOptions(wire_sink=sink))
+        assert sink == [None]  # the Sum before it was answered, nothing after
+        assert len(trips) == 1
+        # ... and the executor serves on.
+        assert as_json(ex.execute("i", "Sum(field=v) TopN(f)")) == as_json(
+            Executor(ex.holder).execute("i", "Sum(field=v) TopN(f)"))
+
+    def test_a_cache_hit_inside_a_run_queues_nothing_and_keeps_its_token(self, served):
+        ex, be, trips = served
+        ex.rescache = ResultCache(ex.holder, max_bytes=1 << 20)
+        body = "Sum(field=v) TopN(f, n=2) Sum(Row(f=1), field=v)"
+        first = as_json(ex.execute("i", body))
+        assert [len(t) for t in trips] == [3]
+        sink = []
+        again = ex.execute("i", "Sum(field=v) Max(field=w) TopN(f, n=2) Min(field=v)",
+                           opt=ExecOptions(wire_sink=sink))
+        # Two hits stand among two misses: one trip of the two misses.
+        assert trips[1:] == [[("bsi_max", "w"), ("bsi_min", "v")]]
+        assert [t.hit for t in sink] == [True, False, True, False]
+        assert as_json(again)[0] == first[0] and as_json(again)[2] == first[1]
+        assert as_json(again) == as_json(Executor(ex.holder).execute(
+            "i", "Sum(field=v) Max(field=w) TopN(f, n=2) Min(field=v)"))
+        # The misses were committed under their tokens: all four hit now.
+        sink2 = []
+        ex.execute("i", "Sum(field=v) Max(field=w) TopN(f, n=2) Min(field=v)",
+                   opt=ExecOptions(wire_sink=sink2))
+        assert [t.hit for t in sink2] == [True] * 4 and len(trips) == 2
+
+    def test_one_call_seconds_observation_a_member(self, served):
+        ex, be, trips = served
+
+        def observed():
+            return {k.split('"')[1]: n for k, (_, n) in
+                    global_stats.timing_totals("query_call_seconds").items()}
+
+        def sum_seconds():
+            return global_stats.timing_totals(
+                "query_call_seconds")['query_call_seconds{call="Sum"}'][0]
+
+        be.delay = 0.004
+        before, seconds0, t0 = observed(), sum_seconds(), time.perf_counter()
+        ex.execute("i", PAGE + " GroupBy(Rows(f))")
+        wall = time.perf_counter() - t0
+        after = observed()
+        grown = {k: after[k] - before.get(k, 0) for k in after
+                 if after[k] != before.get(k, 0)}
+        assert grown == {"TopN": 1, "Sum": 10, "GroupBy": 1}
+        # A member's latency is its own leg's, from the run's submission:
+        # ten legs served in turn took 1, 2, ... 10 turns, so the ten
+        # observations add up to several times the request's wall.
+        assert sum_seconds() - seconds0 > 0.004 * 55
+        assert sum_seconds() - seconds0 > 2 * wall
