@@ -86,7 +86,14 @@ class RankCache:
         return len(self.entries)
 
     def _recalculate(self) -> None:
-        top = heapq.nlargest(self.max_entries, self.entries.items(), key=lambda kv: kv[1])
+        # Larger counts first, a tie to the smaller id: which rows stay
+        # is then a function of the counts alone, not of the order in
+        # which imports added them (a fragment loaded in slices keeps
+        # what one import of the union keeps).
+        top = heapq.nlargest(
+            self.max_entries, self.entries.items(),
+            key=lambda kv: (kv[1], -kv[0]),
+        )
         self.entries = dict(top)
         # lint: allow-shared-state(fragment-confined like entries above: recalculation always runs under the owning Fragment.lock)
         self.threshold_value = min((c for _, c in top), default=0)

@@ -1792,17 +1792,21 @@ class Fragment:
                 data, clear=clear, parsed=other
             )
             if changed:
-                self._rebuild_cache_rows(np.array(self.row_ids()))
-                # Stamp only the rows the blob spans (container key >>
-                # shift is the row, SHARD_WIDTH being a multiple of the
-                # 2^16 container span) and only when bits actually
-                # moved: an argless or no-op stamp would re-date blocks
-                # whose content didn't change, and a re-dated stale
-                # block wins directed repair over a peer's genuinely
-                # newer one (an idempotent re-import must not out-date
-                # a write the re-imported data predates).
+                # Only the rows the blob spans (container key >> shift
+                # is the row, SHARD_WIDTH being a multiple of the 2^16
+                # container span): their counts go to the rank cache
+                # again (the other rows' did not move: a tall fragment
+                # loaded in slices paid all its rows a request, ISSUE
+                # 36), and they alone are stamped, and only when bits
+                # actually moved: an argless or no-op stamp would
+                # re-date blocks whose content didn't change, and a
+                # re-dated stale block wins directed repair over a
+                # peer's genuinely newer one (an idempotent re-import
+                # must not out-date a write the re-imported data
+                # predates).
                 shift = SHARD_WIDTH_EXP - 16
                 rows = sorted({int(k) >> shift for k in other.keys()})
+                self._rebuild_cache_rows(np.array(rows))
                 self._mutated(rows, epoch=0 if epoch_unknown else None)
                 if epoch_unknown:
                     # 0 = absent entry (merge_block's discipline): these

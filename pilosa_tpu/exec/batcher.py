@@ -31,6 +31,10 @@ Coalescing strategy per kind:
   count_batch_async (pair-stats fast path or slot-bucketed fused scans).
 - row: calls share one slot-bucketed scanned launch per (spec, blocks)
   group via row_batch_async; identical specs dedupe to one slot.
+- topn_tanimoto (ISSUE 36): the legs of one field, each a (source row,
+  threshold), share the sweeps of the field's packed stack via
+  topn_tanimoto_async: sixteen slots a launch, identical legs one slot,
+  every launch of the drain enqueued before any is read back.
 - bsi_sum/bsi_min/bsi_max and topn: identical legs (same field + filter
   tree) dedupe to ONE backend call — the concurrent-hot-query case that
   dominates serving traffic — and the backend's epoch caches make the
@@ -111,7 +115,8 @@ from pilosa_tpu.utils.threads import spawn
 #: Leg kinds the plane coalesces. count/row/topn legs are built only by
 #: their own methods; bsi_leg() takes the kind as an argument and
 #: validates it against the bsi_ subset below.
-LEG_KINDS = ("count", "row", "bsi_sum", "bsi_min", "bsi_max", "topn")
+LEG_KINDS = ("count", "row", "bsi_sum", "bsi_min", "bsi_max", "topn",
+             "topn_tanimoto")
 
 
 def topn_trim(pairs, n: int):
@@ -237,6 +242,22 @@ class ShardLegBatcher:
         return topn_trim(
             self._one(self.topn_leg(index, field_name, shards, src_call)), n
         )
+
+    def topn_tanimoto_leg(self, index: str, field_name: str,
+                          shards: list[int], row_id: int,
+                          threshold: int) -> _Leg:
+        """TopN(field, Row(field=row_id), tanimotoThreshold=threshold)
+        over a field the backend holds packed. The leg's value is
+        (row ids, counts) of every row that passes, by row id (the
+        submitter orders and trims), or None where the field is not
+        held packed after all (the executor then takes the host path)."""
+        return _Leg("topn_tanimoto", index, tuple(shards),
+                    (field_name, int(row_id), int(threshold)))
+
+    def topn_tanimoto(self, index, field_name, shards, row_id, threshold):
+        return self._one(self.topn_tanimoto_leg(
+            index, field_name, shards, row_id, threshold
+        ))
 
     def _one(self, leg: _Leg):
         self.submit((leg,))
@@ -375,6 +396,8 @@ class ShardLegBatcher:
                 pending.append((legs, self._dispatch_count(index, shards, legs)))
             elif kind == "row":
                 pending.append((legs, self._dispatch_row(index, shards, legs)))
+            elif kind == "topn_tanimoto":
+                pending.extend(self._dispatch_tanimoto(index, shards, legs))
             else:
                 sync_groups.append((kind, index, shards, legs))
         # Synchronous kinds (bsi_*/topn) run AFTER every async dispatch is
@@ -390,10 +413,14 @@ class ShardLegBatcher:
             except Exception:
                 # Shared-launch resolution failed: visible on /metrics,
                 # then isolate so one bad query can't fail the window.
+                # Legs a launch already answered (a Tanimoto group
+                # delivers launch by launch) keep their answers.
                 self.stats.with_tags(f"kind:{legs[0].kind}").count(
                     "batch_dispatch_errors_total"
                 )
-                self._resolve_individually(legs)
+                self._resolve_individually(
+                    [leg for leg in legs if not leg.event.is_set()]
+                )
 
     def _observe_group(self, kind: str, legs: list[_Leg], drain: int) -> None:
         st = self.stats.with_tags(f"kind:{kind}")
@@ -470,6 +497,48 @@ class ShardLegBatcher:
 
         return resolve
 
+    # -- Tanimoto TopN legs ---------------------------------------------------
+
+    def _dispatch_tanimoto(self, index, shards, legs):
+        """[(legs, resolver)] of the group, a field at a time: every
+        launch is enqueued here and read back by the resolver, after the
+        drain's other launches are in flight too."""
+        by_field: dict[str, list[_Leg]] = {}
+        for leg in legs:
+            by_field.setdefault(leg.payload[0], []).append(leg)
+        out = []
+        for field_name, members in by_field.items():
+            try:
+                resolver = self.backend.topn_tanimoto_async(
+                    index, field_name, list(shards),
+                    [leg.payload[1:] for leg in members],
+                )
+            except Exception:
+                self.stats.with_tags("kind:topn_tanimoto").count(
+                    "batch_dispatch_errors_total"
+                )
+                self._resolve_individually(members)
+                continue
+            if resolver is None:
+                # Not held packed (any more): each submitter's host path.
+                for leg in members:
+                    leg.resolve(None)
+                continue
+            out.append((members, self._tanimoto_scatter(members, resolver)))
+        return out
+
+    @staticmethod
+    def _tanimoto_scatter(members, resolver):
+        """The group's resolver: a launch's legs are resolved as soon as
+        that launch has been read back, the launches behind it still on
+        the device."""
+        def deliver(which, answers):
+            with current_profile().phase("scatter"):
+                for i, answer in zip(which, answers):
+                    members[i].resolve(answer)
+
+        return lambda: resolver(deliver)
+
     # -- synchronous kinds (bsi aggregates, topn) ---------------------------
 
     def _serve_sync(self, kind, index, shards, legs) -> None:
@@ -524,6 +593,11 @@ class ShardLegBatcher:
                 elif leg.kind == "row":
                     result = self.backend.bitmap_call(
                         leg.index, leg.payload, list(leg.shards)
+                    )
+                elif leg.kind == "topn_tanimoto":
+                    result = self.backend.topn_tanimoto(
+                        leg.index, leg.payload[0], list(leg.shards),
+                        leg.payload[1], leg.payload[2],
                     )
                 else:  # bsi_*/topn legs retry through _serve_sync directly
                     self._serve_sync(

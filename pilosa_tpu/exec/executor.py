@@ -18,6 +18,7 @@ import operator
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Union
 
+import numpy as np
 
 from pilosa_tpu.core.cache import Pair, add_pairs, top_n_pairs
 from pilosa_tpu.core.field import FIELD_TYPE_BOOL, FIELD_TYPE_INT, FIELD_TYPE_TIME
@@ -374,7 +375,7 @@ class Executor:
                     # before it; any other call is a barrier: what is
                     # pending is submitted and answered first, so a write
                     # stands between the reads on either side of it.
-                    member = self._device_read(index, call)
+                    member = self._device_read(index, call, shards)
                     if member is None and reads:
                         flush()
                     # Cache consult AFTER key translation (keys share the
@@ -937,12 +938,79 @@ class Executor:
             k in c.args for k in ("ids", "threshold", "tanimotoThreshold", "attrName")
         )
 
+    @staticmethod
+    def _own_row_source(c) -> bool:
+        """The TopN's one child is a plain Row of the TopN's own field:
+        the shape of a similarity search."""
+        if len(c.children) != 1:
+            return False
+        src = c.children[0]
+        return (
+            src.name == "Row" and not src.children and len(src.args) == 1
+            and c.args.get("_field") in src.args
+        )
+
+    def _topn_tanimoto_leg(self, index, c, shards) -> Optional[tuple]:
+        """(field, source row, threshold) where `c` is a TopN the device
+        answers from a packed stack (ISSUE 36): its source is a plain Row
+        of the TopN's own field, `tanimotoThreshold` (or nothing) is its
+        only rank-cache option, and the backend holds the field packed.
+        Everything else stays where it was: `ids`, `threshold` and
+        `attrName` take the host's two passes."""
+        if (
+            self.mapper is not None
+            or not hasattr(self.backend, "topn_tanimoto_async")
+            or any(k in c.args for k in ("ids", "threshold", "attrName"))
+        ):
+            return None
+        field_name = c.args.get("_field")
+        if not self._own_row_source(c):
+            return None
+        row_id, ok = c.children[0].uint64_arg(field_name)
+        threshold, searched = c.uint64_arg("tanimotoThreshold")
+        if not ok or threshold > 100:
+            return None
+        if not self.backend.packed_field(
+            index, field_name, shards, alone=not searched
+        ):
+            return None
+        return field_name, row_id, threshold
+
+    @staticmethod
+    def _tanimoto_pairs(raw, n, field_name) -> PairsField:
+        """A `topn_tanimoto` leg's (row ids, counts), every passing row,
+        ordered here (count descending, ties by id) and cut to n last, as
+        core/fragment.py `top` does: on the request's own thread, not the
+        drain's."""
+        rows, counts = raw
+        order = np.lexsort((rows, -counts))
+        if n:
+            order = order[:n]
+        return PairsField(
+            [Pair(id=int(r), count=int(k)) for r, k in
+             zip(rows[order], counts[order])],
+            field_name,
+        )
+
     def _execute_topn(self, index, c, shards, opt) -> PairsField:
         field_name = c.args.get("_field")
         if not field_name:
             raise QueryError("TopN() field required")
         n, _ = c.uint64_arg("n")
 
+        leg = self._topn_tanimoto_leg(index, c, shards)
+        if leg is not None:
+            if self.batcher is not None:
+                raw = self.batcher.topn_tanimoto(index, leg[0], shards, *leg[1:])
+            else:
+                raw = self.backend.topn_tanimoto(index, leg[0], shards, *leg[1:])
+            if raw is not None:
+                return self._tanimoto_pairs(raw, n, field_name)
+        return self._topn_unpacked(index, c, n, shards, opt)
+
+    def _topn_unpacked(self, index, c, n, shards, opt) -> PairsField:
+        """TopN of a field the backend holds dense, or not at all."""
+        field_name = c.args["_field"]
         if (
             self._topn_plain(c)
             and self.mapper is None
@@ -980,25 +1048,30 @@ class Executor:
     # a request's run of device reads (ISSUE 33)
     # ------------------------------------------------------------------
 
-    def _device_read(self, index, c) -> Optional[tuple]:
-        """(leg kind, field name) where `c` is a call that becomes exactly
-        one synchronous batcher leg, under the conditions `_bsi_fast` and
-        `_execute_topn` go to the batcher: a Sum, Min or Max, or a plain
-        TopN. None for everything else, and for a call that would raise
-        (no such field, two children): it raises where it stands, on the
-        path every other call takes. Such reads write nothing, so those
-        that stand side by side in a request are independent."""
+    def _device_read(self, index, c, shards=None) -> Optional[tuple]:
+        """(leg kind, field name[, the leg's own arguments]) where `c` is a
+        call that becomes exactly one batcher leg, under the conditions `_bsi_fast` and
+        `_execute_topn` go to the batcher: a Sum, Min or Max, a plain
+        TopN, or a TopN under a Row of its own field that the backend
+        holds packed (`shards` is the request's shard argument). None for
+        everything else, and for a call that would raise (no such field,
+        two children): it raises where it stands, on the path every other
+        call takes. Such reads write nothing, so those that stand side by
+        side in a request are independent."""
         if self.batcher is None or self.mapper is not None:
             return None
         try:
             if c.name == "TopN":
                 field_name = c.args.get("_field")
                 c.uint64_arg("n")
-                if (
-                    field_name
-                    and self._topn_plain(c)
-                    and hasattr(self.backend, "topn_field")
-                ):
+                if not field_name:
+                    return None
+                leg = self._topn_tanimoto_leg(
+                    index, c, self._shards(index, shards)
+                )
+                if leg is not None:
+                    return ("topn_tanimoto", *leg)
+                if self._topn_plain(c) and hasattr(self.backend, "topn_field"):
                     return "topn", field_name
                 return None
             kind = _BSI_KIND.get(c.name)
@@ -1008,8 +1081,10 @@ class Executor:
         except (QueryError, ValueError):
             return None
 
-    def _read_leg(self, index, c, shards, kind, field_name):
+    def _read_leg(self, index, c, shards, kind, field_name, *leg):
         sub = c.children[0] if c.children else None
+        if kind == "topn_tanimoto":
+            return self.batcher.topn_tanimoto_leg(index, field_name, shards, *leg)
         if kind == "topn":
             return self.batcher.topn_leg(index, field_name, shards, sub)
         return self.batcher.bsi_leg(kind, index, field_name, shards, sub)
@@ -1020,6 +1095,10 @@ class Executor:
         c, raw = r.call, r.leg.value()
         if c.name == "TopN":
             n, _ = c.uint64_arg("n")
+            if r.leg.kind == "topn_tanimoto":
+                if raw is not None:
+                    return self._tanimoto_pairs(raw, n, c.args["_field"])
+                return self._topn_unpacked(index, c, n, r.shards, opt)
             if raw is not None:
                 return PairsField(topn_trim(raw, n), c.args["_field"])
             return self._topn_two_pass(index, c, n, r.shards, opt)

@@ -73,14 +73,19 @@ from pilosa_tpu.exec.tiers import (  # noqa: F401 — the _names: tests import t
     refresh_entry,
 )
 from pilosa_tpu.ops.blocks import (
+    PACKED_BITS,
+    PACKED_WORDS,
     ROW_PAD,
     WORDS_PER_SHARD,
     _padded_rows,
     flat_words,
     fragment_tier_words,
     pack_fragment,
+    pack_fragment_packed,
     pack_row,
     pack_rows,
+    pack_rows_packed,
+    packed_rows,
     stack_shape,
     tile_words,
     unpack_row,
@@ -88,14 +93,18 @@ from pilosa_tpu.ops.blocks import (
 )
 from pilosa_tpu.ops.kernels import (
     MAX_PAIR_SHARDS,
+    TANIMOTO_LIST,
     group_tile_stats,
     group_tile_stats_pershard,
     mask_lane_slab,
     masked_lane_counts,
     pair_stats,
     pair_stats_pershard,
+    packed_row_counts,
     slab_counts,
     splice_shard_slabs,
+    tanimoto_counts,
+    tanimoto_topn,
 )
 from pilosa_tpu.ops.runtime import pallas_interpret, require_serving_platform
 from pilosa_tpu.parallel.mesh import pad_to_multiple
@@ -211,6 +220,11 @@ class _StackedBlocks:
     #: device count for no dispatch saving at realistic dirty rates.
     MESH_UPDATE_CHUNK = 1
 
+    #: Rows a point-write epoch may splice into a PACKED stack
+    #: (get_packed) before a full re-pack wins; the splice ships this
+    #: many rows a dispatch, padded, so one compiled scatter serves.
+    PACKED_UPDATE_ROWS = 64
+
     #: Default decayed-frequency half-life in seconds (config
     #: heat-half-life): a block untouched for one half-life keeps half
     #: its heat — 5 minutes separates the serving hot set from batch
@@ -218,7 +232,8 @@ class _StackedBlocks:
     HEAT_HALF_LIFE = 300.0
 
     def __init__(self, device=None, mesh=None, max_bytes: Optional[int] = None,
-                 fallback=None, heat_half_life: Optional[float] = None):
+                 fallback=None, heat_half_life: Optional[float] = None,
+                 packed_extra=None):
         self.device = device
         self.mesh = mesh  # ShardMesh or None
         self.max_bytes = max_bytes
@@ -235,8 +250,11 @@ class _StackedBlocks:
         self._fallback = fallback if fallback is not None else (
             lambda reason, shape, err: None
         )
-        # key -> (fingerprint, device array, rows_p, per-shard versions).
-        self._entries: dict[tuple, tuple[tuple, object, int, Optional[tuple]]] = {}
+        # key -> (fingerprint, device array, rows_p, per-shard versions,
+        # what the build keeps beside the array: a packed stack's row
+        # counts). The array is None where a packed key's verdict is "not
+        # packed" (get_packed): no bytes, no ledger line.
+        self._entries: dict[tuple, tuple] = {}
         self.evictions = 0
         # Per-entry HBM ledger (ISSUE r8 tentpole 4): resident bytes
         # split by representation tier (dense / array-container /
@@ -272,6 +290,34 @@ class _StackedBlocks:
         # only (row pages are demand-paged by design). GIL-atomic dict
         # writes; pruned against _entries under _lock in refresh_stale.
         self._refresh_args: dict[tuple, tuple] = {}
+        # bytes_limit of the device, read once (admission_bytes).
+        self._device_bytes: Optional[int] = None
+        # Called with (a packed stack as it is built or spliced, the
+        # stack it replaces or None); what it returns is kept in the
+        # stack's entry and get_packed hands it back. TPUBackend counts
+        # the rows there.
+        self.packed_extra = packed_extra or (lambda arr, stale_arr: None)
+
+    def admission_bytes(self) -> Optional[int]:
+        """The most bytes one stack may take: the configured budget, or
+        where none is set what the device says it has (a stack taller
+        than the chip's memory can never be resident: ISSUE 36, a field
+        of 1.7 M rows asked for 207 GB dense). None where neither is
+        known (a CPU device reports no limit): everything is admitted."""
+        if self.max_bytes is not None:
+            return self.max_bytes
+        if self._device_bytes is None:
+            limit = 0
+            try:
+                dev = self.device or (
+                    self.mesh.devices[0] if self.mesh is not None
+                    else jax.devices()[0]
+                )
+                limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+            except (RuntimeError, AttributeError, NotImplementedError):
+                limit = 0  # no memory_stats on this backend: no limit known
+            self._device_bytes = limit
+        return self._device_bytes or None
 
     def _pad_shards(self, n: int) -> int:
         if self.mesh is None or self.mesh.n <= 1:
@@ -344,7 +390,8 @@ class _StackedBlocks:
                 # the mix re-trues on the next full rebuild).
                 return updated, rows_p, vers, None
             nbytes = s_pad * rows_p * WORDS_PER_SHARD * 4
-            if self.max_bytes is not None and nbytes > self.max_bytes:
+            budget = self.admission_bytes()
+            if budget is not None and nbytes > budget:
                 # Stack can never be resident under the budget: the caller
                 # falls back to row paging or the CPU oracle instead of
                 # blowing HBM. Not cached (None entries are cheap to
@@ -462,7 +509,7 @@ class _StackedBlocks:
             )
             return arr, rows_p, vers, tiers
 
-        return self._cached_build(key, fingerprint, build)
+        return self._cached_build(key, fingerprint, build)[:2]
 
     def _try_incremental(self, stale, shards, min_rows, frags, vers, rows_p, s_pad):
         """Dirty-shard-granular refresh (VERDICT r3 #1): when a write
@@ -480,7 +527,7 @@ class _StackedBlocks:
         (_splice_sharded)."""
         if stale is None:
             return None
-        old_fp, old_arr, old_rows_p, old_vers = stale
+        old_fp, old_arr, old_rows_p, old_vers = stale[:4]
         if (
             old_arr is None
             or old_vers is None
@@ -673,6 +720,176 @@ class _StackedBlocks:
             parts,
         )
 
+    def get_packed(self, index: str, field_obj, shards: tuple[int, ...],
+                   view_name: str = VIEW_STANDARD):
+        """(stack uint32[S, rows_p, PACKED_WORDS], rows_p, what
+        `packed_extra` keeps beside it) of a narrow field, or (None, 0,
+        None) where the field is not: chosen from what the fragments
+        show (ISSUE 36). A field is held packed when every bit of it
+        lies in a shard's first PACKED_BITS columns and the packed stack
+        is admitted (admission_bytes); whether its dense stack is
+        resident too, or could be, does not enter, so the same field is
+        packed beside any neighbour and under any budget that holds it.
+        Packed it takes at most half of what it takes dense, and from
+        1,024 rows on a 256th. Rows are on the sublane axis, the words
+        in use on the lanes: a program sweeps every row in one pass
+        (ops/kernels.py tanimoto_topn).
+
+        Cached, counted in the ledger and evicted like any stack, under
+        the key (index, field, view, "packed"), and as fresh: the view's
+        generation is the fingerprint, a moved one re-derives the entry
+        (a point-write epoch splices the rows its bit ops name, anything
+        else re-packs), so a Set is in the next read. The verdict "not
+        packed" is an entry too, with no array: the walk that found a
+        wide bit is not repeated until a write moves the generation. One
+        device only: under a mesh the field is not packed; nor over no
+        shard at all (`?shards=`), which the host answers."""
+        v = field_obj.view(view_name)
+        if v is None or self.mesh is not None or not shards:
+            return None, 0, None
+        fingerprint = (tuple(shards), v.generation)
+        key = (index, field_obj.name, view_name, "packed")
+
+        def build(stale):
+            t_build = time.perf_counter()
+            frags = [v.fragment(s) for s in shards]
+            n_rows = max(
+                [fr.max_row_id + 1 for fr in frags if fr is not None] + [1]
+            )
+            rows_p = packed_rows(n_rows)
+            budget = self.admission_bytes()
+            if (
+                budget is not None
+                and len(shards) * rows_p * PACKED_WORDS * 4 > budget
+            ):
+                return None, 0, None, None
+            stale_arr = stale[1] if stale is not None else None
+            updated = self._packed_splice(stale, shards, frags, rows_p)
+            if updated is not None:
+                arr, vers = updated
+                extra = (
+                    stale[4] if arr is stale_arr
+                    else self.packed_extra(arr, stale_arr)
+                )
+                return arr, rows_p, vers, None, extra
+            host = np.zeros((len(shards), rows_p, PACKED_WORDS), np.uint32)
+            vers = []
+            for i, fr in enumerate(frags):
+                if fr is None:
+                    vers.append(None)
+                    continue
+                with fr.lock:
+                    # Read BEFORE the pack: the content may be newer
+                    # than the version says, never older, and the row
+                    # splice sets whole rows again (idempotent).
+                    vers.append((fr.uid, fr.version))
+                slab = pack_fragment_packed(fr, rows_p)
+                if slab is None:
+                    return None, 0, None, None
+                host[i] = slab
+            if stale_arr is not None:
+                global_stats.count("stack_full_rebuilds_total")
+            arr = jax.device_put(host, self.device)
+            # Compile the row splice now, beside a build that already
+            # costs seconds, and not under a window's first write.
+            self._packed_update_fn()(
+                arr,
+                np.zeros(self.PACKED_UPDATE_ROWS, np.int32),
+                np.zeros(self.PACKED_UPDATE_ROWS, np.int32),
+                np.asarray(host[0, :1]).repeat(self.PACKED_UPDATE_ROWS, 0),
+            )
+            global_stats.with_tags(f"field:{field_obj.name}").timing(
+                "stack_build_seconds", time.perf_counter() - t_build
+            )
+            global_stats.with_tags(
+                f"index:{index}", f"field:{field_obj.name}"
+            ).gauge("stack_row_words", PACKED_WORDS)
+            return (
+                arr, rows_p, tuple(vers), None,
+                self.packed_extra(arr, stale_arr),
+            )
+
+        return self._cached_build(key, fingerprint, build, keep_none=True)
+
+    def _packed_update_fn(self):
+        """The compiled row splice of a packed stack: PACKED_UPDATE_ROWS
+        (shard, row) places set to as many rows of words. Shard 0's row
+        0 repeated pads a short epoch (equal payloads: benign)."""
+        fn = self._update_fns.get("packed")
+        if fn is None:
+            fn = jax.jit(
+                lambda arr, si, ri, rows: arr.at[si, ri].set(rows)
+            )
+            self._update_fns["packed"] = fn
+        return fn
+
+    def _packed_splice(self, stale, shards, frags, rows_p):
+        """(new stack, versions) where the epoch since `stale` is point
+        writes to few rows, all narrow: those rows are packed again and
+        set in place (a NEW device array: identity is the write epoch, as
+        for a dense stack). None where a full re-pack is needed: first
+        build, changed shape or shard set, a fragment recreated, a bulk
+        write, more rows than a splice ships, a bit beyond the packed
+        width (the re-pack then finds the field no longer narrow)."""
+        if stale is None:
+            return None
+        old_fp, old_arr, old_rows_p, old_vers = stale[:4]
+        if (
+            old_arr is None or old_vers is None or old_rows_p != rows_p
+            or old_fp[0] != tuple(shards)
+        ):
+            return None
+        places: list[tuple[int, int]] = []
+        vers = []
+        for i, fr in enumerate(frags):
+            was = old_vers[i]
+            if fr is None:
+                if was is not None:
+                    return None
+                vers.append(None)
+                continue
+            with fr.lock:
+                now = (fr.uid, fr.version)
+            vers.append(now)
+            if now == was:
+                continue
+            if was is None or was[0] != now[0]:
+                return None
+            ops = fr.bit_ops_between(was[1], now[1])
+            if ops is None:
+                return None
+            for r in sorted({op[1] for op in ops}):
+                if r >= rows_p:
+                    return None
+                places.append((i, r))
+        if not places:
+            return old_arr, tuple(vers)
+        if len(places) > self.PACKED_UPDATE_ROWS:
+            return None
+        rows = np.zeros((self.PACKED_UPDATE_ROWS, PACKED_WORDS), np.uint32)
+        for i in sorted({p[0] for p in places}):
+            mine = [j for j, p in enumerate(places) if p[0] == i]
+            # Rows as they are NOW, which may be newer than `vers` says:
+            # setting a row again is idempotent, so the next epoch's
+            # splice of the same rows costs a dispatch and no error.
+            packed = pack_rows_packed(frags[i], [places[j][1] for j in mine])
+            if packed is None:
+                return None
+            rows[mine] = packed
+        # A short epoch is padded with its first place again (equal
+        # payloads at equal places: benign).
+        rows[len(places):] = rows[0]
+        places += [places[0]] * (self.PACKED_UPDATE_ROWS - len(places))
+        arr = self._packed_update_fn()(
+            old_arr,
+            np.array([p[0] for p in places], np.int32),
+            np.array([p[1] for p in places], np.int32),
+            rows,
+        )
+        global_stats.count("stack_update_bytes_total", rows.nbytes)
+        global_stats.count("stack_incremental_updates_total")
+        return arr, tuple(vers)
+
     def get_row(self, index: str, field_obj, shards: tuple[int, ...],
                 view_name: str, row_id: int):
         """[S_pad, 1, W/128, 128] single-row stack — the on-demand page
@@ -744,12 +961,17 @@ class _StackedBlocks:
                 _REFRESHER.active = False
         return n
 
-    def _cached_build(self, key: tuple, fingerprint: tuple, build):
+    def _cached_build(self, key: tuple, fingerprint: tuple, build,
+                      keep_none: bool = False):
         """Shared hit/latch/build/evict protocol for stack and row-page
         entries. build(stale) receives the stale entry for this key (or
         None) so it can refresh incrementally, and returns
-        (device_array_or_None, rows_p, shard_versions, tier_words); a
-        None array means 'cannot be resident' and is returned uncached.
+        (device_array_or_None, rows_p, shard_versions, tier_words) and,
+        where it keeps something beside the array, a fifth `extra`.
+        Returns (array, rows_p, extra). A None array means 'cannot be
+        resident' and is returned uncached; with keep_none it is kept as
+        the key's entry instead (no bytes, no ledger line), so that a
+        verdict that cost a walk is reached once a fingerprint.
         Concurrent misses for one key build once (losers wait on the
         winner's latch, then re-check)."""
         while True:
@@ -766,13 +988,15 @@ class _StackedBlocks:
                     if led is not None:
                         self._bump_heat(led)
                         nbytes = led["bytes"]
-                    hit = (cached[1], cached[2])
+                    hit = (cached[1], cached[2], cached[4])
                 else:
                     latch = self._building.get(key)
                     if latch is None:
                         self._building[key] = threading.Event()
                         break
             if hit is not None:
+                if hit[0] is None:
+                    return hit
                 # Reuse-distance sample OUTSIDE the ledger lock: the
                 # sampler rejects in one hash compare; admitted samples
                 # take the estimator's own lock only.
@@ -782,18 +1006,24 @@ class _StackedBlocks:
             # its fingerprint usually matches ours (same live fragments).
             latch.wait()
         try:
-            arr, rows_p, vers, tiers = build(cached)
+            arr, rows_p, vers, tiers, *extra = build(cached)
+            extra = extra[0] if extra else None
             if arr is None:
-                return None, rows_p
+                if keep_none:
+                    with self._lock:
+                        self._entries.pop(key, None)
+                        self._ledger.pop(key, None)
+                        self._entries[key] = (fingerprint, None, rows_p, vers, None)
+                return None, rows_p, None
             with self._lock:
                 self._entries.pop(key, None)
-                self._entries[key] = (fingerprint, arr, rows_p, vers)
+                self._entries[key] = (fingerprint, arr, rows_p, vers, extra)
                 self._ledger_upload(key, arr, tiers)
                 self._evict(keep=key)
             # Misses are references too: without them the reuse stream
             # would be hits-only and every distance would look resident.
             self._record_reuse(key, int(np.prod(arr.shape)) * 4)
-            return arr, rows_p
+            return arr, rows_p, extra
         finally:
             with self._lock:
                 self._building.pop(key).set()
@@ -867,23 +1097,27 @@ class _StackedBlocks:
         with self._lock:
             target = max(0, self.max_bytes - nbytes)
             while self.resident_bytes() > target and self._entries:
-                victim = next(iter(self._entries))
-                self._entries.pop(victim)
-                self._ledger.pop(victim, None)
-                self.evictions += 1
+                self._drop(next(iter(self._entries)))
 
     def _evict(self, keep: tuple) -> None:
         if self.max_bytes is None:
             return
         while self.resident_bytes() > self.max_bytes and len(self._entries) > 1:
-            victim = next(k for k in self._entries if k != keep)
-            self._entries.pop(victim)
-            self._ledger.pop(victim, None)
+            self._drop(next(k for k in self._entries if k != keep))
+
+    def _drop(self, victim: tuple) -> None:
+        """Caller holds _lock. An entry with no array (a packed key's
+        "not packed") goes in its turn and frees nothing: not counted."""
+        if self._entries.pop(victim)[1] is not None:
             self.evictions += 1
+        self._ledger.pop(victim, None)
 
     def resident_bytes(self) -> int:
         with self._lock:
-            return sum(int(np.prod(e[1].shape)) * 4 for e in self._entries.values())
+            return sum(
+                int(np.prod(e[1].shape)) * 4
+                for e in self._entries.values() if e[1] is not None
+            )
 
     def tier_bytes(self) -> dict[str, int]:
         """Resident bytes by representation tier; the dict sums exactly
@@ -910,7 +1144,7 @@ class _StackedBlocks:
         wall = time.time()  # lint: allow-monotonic-time(lastAccess is an operator-facing epoch display; idleSeconds math is monotonic)
         out = []
         with self._lock:
-            for key, (_, arr, rows_p, _) in self._entries.items():
+            for key, (_, arr, rows_p, *_) in self._entries.items():
                 led = self._ledger.get(key)
                 if led is None:
                     continue
@@ -929,6 +1163,8 @@ class _StackedBlocks:
                 }
                 if len(key) > 3 and key[3] == "row":
                     ent["row"] = key[4]
+                elif len(key) > 3:
+                    ent["layout"] = key[3]
                 out.append(ent)
         return out
 
@@ -1465,6 +1701,7 @@ class TPUBackend(VersionWalks):
         self.blocks = _StackedBlocks(
             device, self.mesh, max_bytes, fallback=self._count_device_fallback,
             heat_half_life=heat_half_life,
+            packed_extra=self._packed_row_counts,
         )
         self._fns: dict = {}
         self._fns_lock = threading.RLock()
@@ -2078,6 +2315,15 @@ class TPUBackend(VersionWalks):
 
             out = (ax, ax, ax, ax, ax, ax) if mesh is not None else None
             fn = self._wrap(kind, body, True, out)
+
+        elif kind in ("topn_tanimoto", "topn_tanimoto_counts",
+                      "packed_row_counts"):
+            # Over a packed stack (blocks.get_packed): one device only.
+            fn = jax.jit(_named(kind, {
+                "topn_tanimoto": tanimoto_topn,
+                "topn_tanimoto_counts": tanimoto_counts,
+                "packed_row_counts": packed_row_counts,
+            }[kind]))
 
         else:
             raise ValueError(kind)
@@ -3834,7 +4080,13 @@ class TPUBackend(VersionWalks):
             # a None-vers entry refuses every future incremental update.
             vers = live_vers
         pershard = None
-        if block is None:
+        packed_counts = None
+        if block is None and src is None:
+            # A field held packed has its row counts on the device.
+            packed_counts = self.blocks.get_packed(index, f, shards_t)[2]
+        if packed_counts is not None:
+            counts = np.asarray(packed_counts, dtype=np.uint64).sum(axis=0)[:rp]
+        elif block is None:
             # Over the HBM budget: page the row axis through the device
             # (VERDICT r2 #8) instead of falling back to the CPU path.
             counts = self._topn_paged_counts(index, f, shards_t, src)
@@ -3951,6 +4203,160 @@ class TPUBackend(VersionWalks):
                 arr = arr.sum(axis=0)
             counts[start : start + page] += arr
         return counts[:n_rows]
+
+    # -- Tanimoto TopN over a packed field (ISSUE 36) ----------------------
+
+    #: Legs one launch of the Tanimoto program holds (its slot buckets
+    #: are 1, 2, 4, 8, 16); a drain of more is several launches.
+    MAX_TANIMOTO_SLOTS = 16
+
+    def packed_field(self, index: str, field_name: str, shards,
+                     alone: bool = False) -> bool:
+        """Whether the backend holds (or can hold) this field packed:
+        the executor's gate for a TopN under a Row of the field itself.
+        `alone`: and its dense stack is not admitted. A search by
+        `tanimotoThreshold` is exact only from the packed stack and
+        takes it wherever there is one; a plain TopN under a Row is
+        exact from either and keeps the dense stack's sweep
+        (topn_field) where there is a dense stack."""
+        idx = self.holder.index(index)
+        f = idx.field(field_name) if idx else None
+        if (
+            f is None or f.options.type == FIELD_TYPE_INT
+            or f.view(VIEW_STANDARD) is None
+        ):
+            return False
+        shards_t = tuple(shards)
+        if self.blocks.get_packed(index, f, shards_t)[0] is None:
+            return False
+        return not alone or self.blocks.get(index, f, shards_t)[0] is None
+
+    def _packed_row_counts(self, packed, stale):
+        """A packed stack's row counts, int32[S, R] on the device: kept
+        in the stack's entry of the block store (its `packed_extra`), so
+        they are evicted with it."""
+        counts = self._program("packed_row_counts", None, False)(packed)
+        if stale is None or stale.shape != packed.shape:
+            # A stack of a new shape: compile the exact finish of an
+            # overflowing leg now, beside a build that costs seconds,
+            # not under the first search that needs it (one inactive
+            # slot: run, nothing found, result dropped).
+            one = np.zeros(1, np.int32)
+            self._program("topn_tanimoto_counts", None, False)(
+                packed, counts, one, one, one
+            )
+        return counts
+
+    def topn_tanimoto_async(self, index: str, field_name: str,
+                            shards: list[int], legs: list[tuple[int, int]]):
+        """Dispatch `TopN(field, Row(field=m), tanimotoThreshold=T)` for
+        every (m, T) of `legs` over the field's packed stack and return a
+        resolver, or None where the field is not held packed.
+
+        Each distinct leg takes a slot; slots go out MAX_TANIMOTO_SLOTS a
+        launch, padded to a bucket (padded slots are inactive), and every
+        launch is enqueued before the resolver reads any back. The
+        resolver gives, leg by leg, (row ids, counts): every row r with
+        c = |m ∩ r| > 0 and c * 100 // |m ∪ r| >= T, shard by shard as
+        core/fragment.py `top` tests it, over ALL rows (the host path
+        asks only its rank cache's candidates). By row id; the caller
+        orders by count and trims. A leg with more hits than the
+        program's list is finished from its whole count vector
+        (`topn_tanimoto_overflow_total`), never cut. T = 0 is the plain
+        TopN under a Row."""
+        f = self._field(index, field_name)
+        shards_t = tuple(shards)
+        with current_profile().phase("stack_fetch"):
+            packed, _, row_counts = self.blocks.get_packed(index, f, shards_t)
+        if packed is None:
+            return None
+        rows_p = packed.shape[1]
+        prof = current_profile()
+        with prof.phase("slots"):
+            slot_of: dict[tuple[int, int], int] = {}
+            for leg in legs:
+                slot_of.setdefault(leg, len(slot_of))
+            unique = list(slot_of)
+        pending = []
+        fn = self._program("topn_tanimoto", None, False)
+        for at in range(0, len(unique), self.MAX_TANIMOTO_SLOTS):
+            part = unique[at : at + self.MAX_TANIMOTO_SLOTS]
+            qb = _slot_bucket(len(part))
+            ids = np.zeros(qb, np.int32)
+            thr = np.zeros(qb, np.int32)
+            act = np.zeros(qb, np.int32)
+            for j, (m, t) in enumerate(part):
+                # A row past the stack holds nothing: an inactive slot.
+                ids[j], thr[j], act[j] = min(m, rows_p - 1), t, m < rows_p
+            with prof.phase("dispatch", span="pilosa.topn_tanimoto",
+                            legs=len(part), slots=qb):
+                out = fn(packed, row_counts, ids, thr, act)
+            pending.append((len(part), out, (ids, thr, act)))
+
+        def resolve(deliver=None) -> list[tuple[np.ndarray, np.ndarray]]:
+            """Launch by launch, in the order enqueued: wait, read back,
+            and hand the legs of that launch to `deliver(leg indices,
+            answers)` at once where one is given, while the device is
+            still at the launches behind it. The batcher resolves a
+            launch's legs there, so that their requests are answered,
+            and the clients' next searches queued, before the drain has
+            ended: the next drain then starts on a device that never
+            stood still (one resolve at the drain's end left it idle for
+            46 % of a window of 64 clients on the chip, PR 36)."""
+            prof_r = current_profile()
+            answers: list = [None] * len(unique)
+            legs_of: dict[int, list[int]] = {}
+            for i, leg in enumerate(legs):
+                legs_of.setdefault(slot_of[leg], []).append(i)
+            k = TANIMOTO_LIST
+            at = 0
+            for n, out, args in pending:
+                with prof_r.phase("device_wait"):
+                    self.programs.block_ready(out)
+                with prof_r.phase("readback"):
+                    host = np.asarray(out)
+                    for j in range(n):
+                        total = int(host[j, 0])
+                        if total > k:
+                            answers[at + j] = self._tanimoto_overflow(
+                                packed, row_counts, args, j
+                            )
+                        else:
+                            answers[at + j] = (
+                                host[j, 1 : 1 + total].copy(),
+                                host[j, 1 + k : 1 + k + total].copy(),
+                            )
+                    self.stats.count(
+                        "topn_tanimoto_hits_total",
+                        sum(answers[at + j][0].size for j in range(n)),
+                    )
+                if deliver is not None:
+                    mine = [i for j in range(n) for i in legs_of[at + j]]
+                    deliver(mine, [answers[slot_of[legs[i]]] for i in mine])
+                at += n
+            return [answers[slot_of[leg]] for leg in legs]
+
+        return resolve
+
+    def _tanimoto_overflow(self, packed, row_counts, args, j):
+        """A leg whose hits outran the program's list, finished exactly:
+        its whole masked count vector read back, the hits taken on the
+        host."""
+        self.stats.count("topn_tanimoto_overflow_total")
+        ids, thr, act = (a[j : j + 1] for a in args)
+        out = self._program("topn_tanimoto_counts", None, False)(
+            packed, row_counts, ids, thr, act
+        )
+        counts = np.asarray(self.programs.block_ready(out))[0]
+        rows = np.flatnonzero(counts)
+        return rows.astype(np.int32), counts[rows]
+
+    def topn_tanimoto(self, index, field_name, shards, row_id, threshold):
+        """One leg, waited for: the batcher's path without a batcher."""
+        resolver = self.topn_tanimoto_async(
+            index, field_name, shards, [(row_id, threshold)]
+        )
+        return resolver()[0] if resolver is not None else None
 
     # -- BSI aggregates (device fast path; fragment.go:1111-1268) ----------
 

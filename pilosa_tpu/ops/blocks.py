@@ -15,6 +15,13 @@ tiles, 128 KiB contiguous, which a program reads in place. With the flat
 first copied it out of the whole stack (ISSUE 27). The two shapes are the
 same bytes in the same order, so tile_words / flat_words are views.
 
+A second layout, "packed", is for a set field that is narrow, every bit
+in a shard's first 4,096 columns (ISSUE 36: 1.7 M such rows, tall as
+Pilosa's fields are). Dense it is rows x 128 KiB; packed it is
+uint32[shards, rows_padded, PACKED_WORDS]: the 128 words in use on the
+lane axis, rows on the sublane axis, so a program sweeps every row of the
+field in one pass (pack_fragment_packed, pack_rows_packed).
+
 Packing walks roaring containers directly: a container key maps to
 (row, word-range) and its 1024 uint64 words view as 2048 little-endian
 uint32 words, so dense containers are a straight memcpy and array
@@ -212,3 +219,102 @@ def pack_rows(frag, row_start: int, row_end: int) -> np.ndarray:
             c,
         )
     return arr
+
+
+# -- the packed layout: a tall field's first PACKED_BITS columns ----------
+
+#: Columns a packed row holds: one lane line of words.
+PACKED_WORDS = 128
+PACKED_BITS = PACKED_WORDS * 32
+
+#: Rows of a packed stack are padded to this: a program packs a row mask
+#: 32 rows to a word and wants whole lane lines of those words.
+PACKED_ROW_PAD = 1024
+
+#: Containers whose positions are gathered and packed in one numpy pass.
+_PACK_CHUNK_KEYS = 1 << 16
+
+_NO_POSITIONS = np.empty(0, dtype=np.uint16)
+
+
+def packed_rows(n_rows: int) -> int:
+    return max(-(-n_rows // PACKED_ROW_PAD), 1) * PACKED_ROW_PAD
+
+
+def _packed_positions(c) -> Optional[np.ndarray]:
+    """A container's positions where all lie under PACKED_BITS."""
+    if c.typ == "array":
+        data = c.data
+    elif c.n > PACKED_BITS:
+        return None
+    else:
+        data = c.positions()
+    if data.size and int(data[-1]) >= PACKED_BITS:
+        return None
+    return data
+
+
+def _or_positions(out: np.ndarray, row_of: np.ndarray, datas: list) -> None:
+    """OR the positions of `datas` (each sorted, all under PACKED_BITS)
+    into the rows `row_of` of out[rows, PACKED_WORDS]: one pass over the
+    concatenated positions, no loop a container."""
+    lens = np.fromiter(map(len, datas), dtype=np.int64, count=len(datas))
+    if not int(lens.sum()):
+        return
+    pos = np.concatenate(datas).astype(np.int64)
+    flat = np.repeat(row_of.astype(np.int64) * PACKED_BITS, lens) + pos
+    word = flat >> 5
+    bit = np.uint32(1) << (flat & 31).astype(np.uint32)
+    # Rows ascend and a row's positions ascend, so equal words are
+    # neighbours: one OR a run.
+    starts = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
+    out.reshape(-1)[word[starts]] |= np.bitwise_or.reduceat(bit, starts)
+
+
+def pack_fragment_packed(frag, rows_p: int) -> Optional[np.ndarray]:
+    """A fragment as uint32[rows_p, PACKED_WORDS], or None where a bit
+    lies at column PACKED_BITS or beyond (the field is not narrow: the
+    caller keeps the dense layout or leaves for the host)."""
+    storage = frag.storage
+    out = np.zeros((rows_p, PACKED_WORDS), dtype=np.uint32)
+    keys = storage.keys()
+    for k0 in range(0, len(keys), _PACK_CHUNK_KEYS):
+        chunk = np.asarray(keys[k0 : k0 + _PACK_CHUNK_KEYS], dtype=np.int64)
+        if (chunk % _CONTAINERS_PER_ROW).any():
+            return None  # a container past a row's first 2^16 columns
+        rows = chunk // _CONTAINERS_PER_ROW
+        datas = []
+        for key in keys[k0 : k0 + _PACK_CHUNK_KEYS]:
+            c = storage.container(key)
+            data = _packed_positions(c) if c is not None else _NO_POSITIONS
+            if data is None:
+                return None
+            datas.append(data)
+        keep = rows < rows_p
+        if not keep.all():
+            datas = [d for d, k in zip(datas, keep.tolist()) if k]
+            rows = rows[keep]
+        _or_positions(out, rows, datas)
+    return out
+
+
+
+def pack_rows_packed(frag, row_ids) -> Optional[np.ndarray]:
+    """The rows `row_ids` of a fragment as uint32[len, PACKED_WORDS], or
+    None where one of them holds a bit at PACKED_BITS or beyond: what a
+    point write's splice into a packed stack ships."""
+    storage = frag.storage
+    out = np.zeros((len(row_ids), PACKED_WORDS), dtype=np.uint32)
+    datas = []
+    for r in row_ids:
+        base = int(r) * _CONTAINERS_PER_ROW
+        for k in range(base + 1, base + _CONTAINERS_PER_ROW):
+            if storage.container(k) is not None:
+                return None
+        c = storage.container(base)
+        data = _packed_positions(c) if c is not None else _NO_POSITIONS
+        if data is None:
+            return None
+        datas.append(data)
+    _or_positions(out, np.arange(len(row_ids)), datas)
+    return out

@@ -501,3 +501,152 @@ def pair_stats_xla(f_stack, g_stack):
         jax.lax.population_count(g_stack).astype(jnp.int32), axis=(0, 2, 3)
     )
     return pair, cf, cg
+
+
+# -- Tanimoto TopN over a packed stack (ISSUE 36) ---------------------------
+
+#: Rows a leg's bounded answer holds. More hits than this and the leg is
+#: finished from its whole count vector (tanimoto_counts), never cut.
+TANIMOTO_LIST = 4096
+
+
+def tanimoto_counts(packed, row_counts, ids, thresholds, active):
+    """int32[B, R]: the whole masked count vector of each leg (what
+    tanimoto_topn lists, and what finishes a leg whose hits outran its
+    list): per leg b and row r, |A_b ∩ r| summed over the shards
+    in which the pair passes the reference's integer test
+    (core/fragment.py top: c > 0 and c * 100 // (|A| + |r| - c) >= T,
+    which for whole numbers is c * 100 >= T * union), else 0.
+
+    packed uint32[S, R, W]; row_counts int32[S, R]; ids, thresholds,
+    active int32[B]. The AND, the popcount and the sum over a row's words
+    are one fusion: [B, R, W] is never written."""
+    src = packed[:, ids, :]  # [S, B, W]
+    inter = jnp.sum(
+        jax.lax.population_count(packed[:, None, :, :] & src[:, :, None, :]),
+        axis=-1, dtype=jnp.int32,
+    )  # [S, B, R]
+    src_n = row_counts[:, ids]  # [S, B]
+    union = row_counts[:, None, :] + src_n[:, :, None] - inter
+    ok = (inter > 0) & (
+        inter * 100 >= thresholds[None, :, None] * union
+    ) & (active[None, :, None] > 0)
+    return jnp.sum(jnp.where(ok, inter, 0), axis=0, dtype=jnp.int32)
+
+
+def _pack_flags(flags):
+    """bool[B, N] -> uint32[B, ceil(N / 32)]: flag i is bit i % 32 of word
+    i // 32 (N padded with unset flags to whole words)."""
+    b, n = flags.shape
+    flags = jnp.pad(flags, ((0, 0), (0, -n % 32)))
+    lane = jnp.arange(32, dtype=jnp.uint32)
+    return jnp.sum(
+        flags.reshape(b, -1, 32).astype(jnp.uint32) << lane,
+        axis=-1, dtype=jnp.uint32,
+    )
+
+
+#: Words a block of `_first_set_bits` holds.
+_SELECT_BLOCK = 64
+
+
+def _first_set_bits(words, k: int):
+    """uint32[B, N] -> (int32[B, k], int32[B]): the positions (word * 32 +
+    bit) of a row's first k set bits in order, and how many it has in
+    all; entries past that number are filler.
+
+    Bit j is found by comparing j with running counts, never by a loop
+    of gathers, which is what a binary search over a prefix sum is on
+    this device (16 rounds: PR 35 read 6 ms of a launch's 14 there), and
+    in two steps of 64, never against all N words at once (k * N compares
+    a row: twelve such fusions of [16, 4096, 4096] were 12 ms of a
+    launch's 19.5 on the chip, PR 36): first the block of 64 words whose
+    running count passes j, then the word within that block's own 64
+    running counts, then the bit within the word's 32."""
+    b, n = words.shape
+    nb = -(-n // _SELECT_BLOCK)
+    words = jnp.pad(words, ((0, 0), (0, nb * _SELECT_BLOCK - n)))
+    lane = jnp.arange(32, dtype=jnp.uint32)
+    ones = jax.lax.population_count(words).astype(jnp.int32)
+    in_block = jnp.cumsum(ones.reshape(b, nb, _SELECT_BLOCK), axis=2)  # inclusive
+    block_upto = jnp.cumsum(in_block[:, :, -1], axis=1)  # [B, nb], inclusive
+    j = jnp.arange(k, dtype=jnp.int32)[None, :]
+    block = jnp.minimum(
+        jnp.sum(block_upto[:, None, :] <= j[:, :, None], axis=2, dtype=jnp.int32),
+        nb - 1,
+    )  # [B, k]
+    before_block = jnp.where(
+        block > 0,
+        jnp.take_along_axis(block_upto, jnp.maximum(block - 1, 0), axis=1), 0,
+    )
+    j_in = j - before_block
+    counts = jnp.take_along_axis(in_block, block[:, :, None], axis=1)  # [B, k, 64]
+    wholly = counts <= j_in[:, :, None]  # words of the block wholly before bit j
+    word_in = jnp.minimum(
+        jnp.sum(wholly, axis=2, dtype=jnp.int32), _SELECT_BLOCK - 1
+    )
+    # The running count is non-decreasing: the largest one not past j is
+    # the count before bit j's word.
+    rank = j_in - jnp.max(jnp.where(wholly, counts, 0), axis=2)
+    word_at = block * _SELECT_BLOCK + word_in
+    word = jnp.take_along_axis(words, word_at, axis=1)
+    bits = ((word[:, :, None] >> lane) & 1).astype(jnp.int32)  # [B, k, 32]
+    bit_at = jnp.minimum(
+        jnp.sum(jnp.cumsum(bits, axis=2) <= rank[:, :, None], axis=2,
+                dtype=jnp.int32),
+        31,
+    )
+    return jnp.minimum(word_at, n - 1) * 32 + bit_at, block_upto[:, -1]
+
+
+def _compact(hit, k: int):
+    """bool[B, R] -> (int32[B, k], int32[B]): a leg's first k set rows by
+    id (filler past its number of hits) and how many are set in all.
+
+    The hits are few among millions, so the flags are packed 32 to a
+    word three times over (1.7 M rows: 53,152 words, 1,661, 52) and the
+    set bits are taken from the top down: the non-empty words of a level
+    are the set bits of the level above. A leg with n <= k hits has at
+    most n non-empty words at every level and loses none; the work after
+    the sweep is a function of k, not of the field's height."""
+    level0 = _pack_flags(hit)
+    level1 = _pack_flags(level0 != 0)
+    level2 = _pack_flags(level1 != 0)
+    total = jnp.sum(jax.lax.population_count(level0), axis=1, dtype=jnp.int32)
+
+    def below(level, at, count, k_out):
+        """The words of `level` at `at` (none past `count`), and the
+        first k_out set bits among them as positions in `level`."""
+        live = jnp.arange(at.shape[1], dtype=jnp.int32)[None, :] < count[:, None]
+        words = jnp.where(
+            live,
+            jnp.take_along_axis(level, jnp.minimum(at, level.shape[1] - 1), axis=1),
+            jnp.uint32(0),
+        )
+        local, n = _first_set_bits(words, k_out)
+        return jnp.take_along_axis(at, local // 32, axis=1) * 32 + local % 32, n
+
+    # Level 1 has at most this many words, whatever k.
+    k1 = min(k, level1.shape[1])
+    at1, n1 = _first_set_bits(level2, k1)   # non-empty words of level 1
+    at0, n0 = below(level1, at1, n1, k)     # non-empty words of level 0
+    rows, _ = below(level0, at0, n0, k)     # set rows
+    return rows, total
+
+
+def tanimoto_topn(packed, row_counts, ids, thresholds, active,
+                  k: int = TANIMOTO_LIST):
+    """int32[B, 1 + 2k]: per leg, the number of rows that pass, then the
+    first k of them by row id, then their counts (0 beyond the hits).
+    A leg whose first number exceeds k holds only a part of its answer."""
+    cnt = tanimoto_counts(packed, row_counts, ids, thresholds, active)
+    rows, total = _compact(cnt > 0, k)
+    live = jnp.arange(k, dtype=jnp.int32)[None, :] < total[:, None]
+    rows = jnp.where(live, jnp.minimum(rows, cnt.shape[1] - 1), 0)
+    counts = jnp.where(live, jnp.take_along_axis(cnt, rows, axis=1), 0)
+    return jnp.concatenate([total[:, None], rows, counts], axis=1)
+
+
+def packed_row_counts(packed):
+    """int32[S, R]: bits a row holds in each shard of a packed stack."""
+    return jnp.sum(jax.lax.population_count(packed), axis=-1, dtype=jnp.int32)

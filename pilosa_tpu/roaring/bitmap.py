@@ -55,6 +55,16 @@ def _runs_win(run_count: int, n: int) -> bool:
     return 4 * run_count < min(2 * n, 8 * BITMAP_N)
 
 
+def array_run_count(data: np.ndarray) -> int:
+    """Runs of consecutive values in sorted-unique uint16 positions,
+    counted without building them: whether an array container's RLE form
+    could win is two passes over its values (a tall field is a million
+    small array containers, none of which it does: ISSUE 36)."""
+    if data.size < 2:
+        return int(data.size)
+    return int(np.count_nonzero(data[1:] - data[:-1] != 1)) + 1
+
+
 def _sorted_member_mask(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Boolean mask over a: a[i] ∈ b (both sorted unique — the array-
     container invariant). A 64 KiB bool lookup over the uint16 domain:
@@ -915,6 +925,10 @@ class Bitmap:
         for key in self.keys():
             c = self._cs[key]
             if c.typ == TYPE_RUN:
+                continue
+            if c.typ == TYPE_ARRAY and not _runs_win(
+                array_run_count(c.data), c.n
+            ):
                 continue
             runs = c.runs()
             if _runs_win(runs.shape[0], c.n):

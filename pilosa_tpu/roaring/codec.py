@@ -30,8 +30,10 @@ from pilosa_tpu.native import fnv32a
 from pilosa_tpu.roaring.bitmap import (
     ARRAY_MAX_SIZE,
     BITMAP_N,
+    TYPE_ARRAY,
     Bitmap,
     Container,
+    array_run_count,
 )
 
 MAGIC_NUMBER = 12348
@@ -96,6 +98,12 @@ class ReplayInfo:
 def _encoded_container(c: Container) -> tuple[int, bytes]:
     """Pick the smallest of array/bitmap/run encodings (reference Optimize)."""
     n = c.n
+    if c.typ == TYPE_ARRAY and 2 + 4 * array_run_count(c.data) >= 2 * n:
+        # An array container keeps its form unless its runs are few:
+        # counted before they are built. A fragment of a million small
+        # array containers paid the build for each (ISSUE 36: most of a
+        # tall field's load and of its snapshots).
+        return TYPE_CODE_ARRAY, c.data.astype("<u2", copy=False).tobytes()
     runs = c.runs()
     run_size = 2 + 4 * runs.shape[0]
     array_size = 2 * n

@@ -1039,6 +1039,13 @@ class _Handler(BaseHTTPRequestHandler):
             shards = None
             if "shards" in self.query:
                 shards = [int(s) for s in self.query["shards"].split(",")]
+            elif "shards=" in urlparse(self.path).query.split("&"):
+                # `?shards=` with nothing after it is the empty list: the
+                # query runs over no shard (`self.query` drops a blank
+                # value, which would have meant every shard: the opposite
+                # of what was asked. A one-shard index pinned to all of
+                # its shards but the last asks exactly this).
+                shards = []
             column_attrs = self.query.get("columnAttrs") == "true"
             exclude_row_attrs = self.query.get("excludeRowAttrs") == "true"
             exclude_columns = self.query.get("excludeColumns") == "true"

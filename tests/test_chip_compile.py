@@ -277,3 +277,38 @@ def test_other_stack_readers_compile(v5e, on_chip, meshed):
     ):
         program = be._program(kind, spec, True, extra=extra).__wrapped__
         _compiled(program, *args)
+
+
+@pytest.mark.parametrize("slots", [1, 16])
+def test_tanimoto_programs_compile_at_the_molecule_library_s_height(v5e, on_chip, slots):
+    """The programs over a packed stack (ISSUE 36) at chem-1chip's shape,
+    uint32[1, 1,700,864, 128] (0.87 GB): the sweep with its bounded list
+    at the smallest and the largest slot bucket, the exact finish of an
+    overflowing leg, the row counts and the row splice of a point write.
+    At 16 slots the [16, R] counts and the compaction's compares are the
+    temporaries: well under a chip, next to the stack."""
+    from pilosa_tpu.ops.blocks import PACKED_WORDS, packed_rows
+
+    be = TPUBackend(on_chip, device=v5e[0])
+    one = SingleDeviceSharding(v5e[0])
+    rows = packed_rows(1_700_000)
+    assert rows == 1_700_864
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one
+    )
+    packed = jax.ShapeDtypeStruct((1, rows, PACKED_WORDS), jnp.uint32, sharding=one)
+    legs = (i32(slots),) * 3
+    topn = be._program("topn_tanimoto", None, False).__wrapped__
+    mem = _compiled(topn, packed, i32(1, rows), *legs)
+    # What a launch hands back is its bounded lists, not a row vector.
+    listed = slots * (1 + 2 * kernels.TANIMOTO_LIST) * 4
+    assert listed <= mem.output_size_in_bytes <= listed + (64 << 10)
+    if slots == 1:
+        _compiled(be._program("topn_tanimoto_counts", None, False).__wrapped__,
+                  packed, i32(1, rows), *legs)
+        _compiled(be._program("packed_row_counts", None, False).__wrapped__, packed)
+        n = be.blocks.PACKED_UPDATE_ROWS
+        _compiled(
+            be.blocks._packed_update_fn(), packed, i32(n), i32(n),
+            jax.ShapeDtypeStruct((n, PACKED_WORDS), jnp.uint32, sharding=one),
+        )

@@ -128,6 +128,26 @@ class TestQueryRoutes:
         out = req(server, "POST", "/index/i/query?shards=1", b"Count(Row(f=1))", ctype="text/plain")
         assert out == {"results": [1]}
 
+    @pytest.mark.parametrize("tail, want", [
+        ("?shards=", 0), ("?shards=&columnAttrs=false", 0),
+        ("?columnAttrs=false&shards=", 0), ("?shards=0", 1), ("", 2),
+        ("?noshards=", 2),
+    ])
+    def test_an_empty_shards_param_is_no_shard(self, server, tail, want):
+        """`?shards=` is the empty list, not "every shard": the query
+        runs over nothing (ISSUE 36: what a one-shard index pinned to all
+        of its shards but the last asks)."""
+        from pilosa_tpu.shardwidth import SHARD_WIDTH
+
+        req(server, "POST", "/index/i", {})
+        req(server, "POST", "/index/i/field/f", {})
+        req(server, "POST", "/index/i/query", f"Set({SHARD_WIDTH+1}, f=1)".encode(), ctype="text/plain")
+        req(server, "POST", "/index/i/query", b"Set(1, f=1)", ctype="text/plain")
+        out = req(server, "POST", "/index/i/query" + tail, b"Count(Row(f=1))", ctype="text/plain")
+        assert out == {"results": [want]}
+        top = req(server, "POST", "/index/i/query" + tail, b"TopN(f)", ctype="text/plain")
+        assert top == {"results": [[{"id": 1, "count": want}] if want else []]}
+
 
 class TestImportRoutes:
     def test_json_import(self, server):

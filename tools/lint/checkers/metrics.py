@@ -42,7 +42,7 @@ METRIC_SUFFIXES = (
     "_inflight", "_up", "_fds", "_threads", "_nodes", "_fields",
     "_shards", "_evictions", "_rederives", "_state",
     "_occupancy", "_queries", "_ops", "_entries",
-    "_programs", "_live", "_heat", "_depth", "_opened",
+    "_programs", "_live", "_heat", "_depth", "_opened", "_words",
 )
 
 _CALL_RE = re.compile(
