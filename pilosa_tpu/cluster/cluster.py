@@ -1351,7 +1351,11 @@ def decode_result(c, raw: Any) -> Any:
                 )
                 for fr in g.get("group", [])
             ]
-            out_groups.append(GroupCount(frs, int(g.get("count", 0))))
+            total = g.get("sum")
+            out_groups.append(GroupCount(
+                frs, int(g.get("count", 0)),
+                None if total is None else int(total),
+            ))
         return out_groups
     if name in ("Set", "Clear", "Store", "ClearRow"):
         return bool(raw)

@@ -1235,6 +1235,7 @@ class Executor:
         filter_call = c.args.get("filter")
         if filter_call is not None and not isinstance(filter_call, Call):
             raise QueryError("filter must be a call")
+        agg = self._group_aggregate(index, c)
 
         # Pre-compute cluster-wide Rows results for children with limit or
         # column args (reference executor.go:1085-1117).
@@ -1272,6 +1273,7 @@ class Executor:
                     # applied below (local) or by the coordinator
                     # (remote partials are capped-but-untrimmed).
                     cap=cap if has_lim else None,
+                    aggregate=None if agg is None else agg.name,
                 )
             if results is not None:
                 if opt.remote:
@@ -1286,8 +1288,9 @@ class Executor:
                     results = results[:limit]
                 return results
 
+        self.stats.with_tags("path:host").count("groupby_served_total")
         map_fn = lambda shard: self._execute_group_by_shard(
-            index, c, filter_call, shard, child_rows, cap
+            index, c, filter_call, shard, child_rows, cap, agg
         )
 
         def reduce_fn(a, b):
@@ -1308,8 +1311,28 @@ class Executor:
             results = results[:limit]
         return results
 
+    def _group_aggregate(self, index, c):
+        """The int field of GroupBy's `aggregate=Sum(field=…)`, or None
+        without one. Any other aggregate, argument or field is a query
+        error."""
+        agg = c.args.get("aggregate")
+        if agg is None:
+            return None
+        if not isinstance(agg, Call) or agg.name != "Sum":
+            raise QueryError("GroupBy aggregate must be Sum(field=<int field>)")
+        if agg.children or set(agg.args) != {"field"}:
+            raise QueryError(
+                "GroupBy aggregate takes one argument, field=<int field>"
+            )
+        f = self._agg_field(index, agg)
+        if f.options.type != FIELD_TYPE_INT:
+            raise QueryError(
+                f"GroupBy aggregate field {f.name!r} is not an int field"
+            )
+        return f
+
     def _execute_group_by_shard(
-        self, index, c, filter_call, shard, child_rows, cap=MAX_INT
+        self, index, c, filter_call, shard, child_rows, cap=MAX_INT, agg=None
     ) -> list[GroupCount]:
         filter_row = None
         if filter_call is not None:
@@ -1345,7 +1368,8 @@ class Executor:
             if i == len(per_child):
                 cnt = acc.count() if acc is not None else 0
                 if cnt > 0:
-                    out.append(GroupCount(list(group), cnt))
+                    total = None if agg is None else agg.sum(acc, shard)[0]
+                    out.append(GroupCount(list(group), cnt, total))
                 return len(out) < cap
             for rid, row in per_child[i]:
                 nxt = row if acc is None else acc.intersect(row)

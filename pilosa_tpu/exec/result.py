@@ -88,16 +88,21 @@ class FieldRow:
 
 @dataclass
 class GroupCount:
-    """One GroupBy result group (reference executor.go:1187)."""
+    """One GroupBy result group (reference executor.go:1187). `sum` is
+    the aggregate's, where the GroupBy asked `aggregate=Sum(field=…)`."""
 
     group: list[FieldRow]
     count: int
+    sum: Optional[int] = None
 
     def compare_key(self) -> tuple:
         return tuple(fr.row_id for fr in self.group)
 
     def to_json(self) -> dict:
-        return {"group": [fr.to_json() for fr in self.group], "count": self.count}
+        out = {"group": [fr.to_json() for fr in self.group], "count": self.count}
+        if self.sum is not None:
+            out["sum"] = self.sum
+        return out
 
 
 class GroupCounts(Sequence):
@@ -113,13 +118,14 @@ class GroupCounts(Sequence):
     group_rows_encoded_total{path="objects"}. A slice is a view of the
     same type over the same arrays."""
 
-    __slots__ = ("fields", "rows", "counts", "keys")
+    __slots__ = ("fields", "rows", "counts", "keys", "sums")
 
-    def __init__(self, fields, rows, counts, keys=None):
+    def __init__(self, fields, rows, counts, keys=None, sums=None):
         self.fields = tuple(fields)
         self.rows = rows
         self.counts = counts
         self.keys = keys if keys is not None else [None] * len(self.fields)
+        self.sums = sums
 
     def __len__(self) -> int:
         return len(self.counts)
@@ -129,6 +135,7 @@ class GroupCounts(Sequence):
             return GroupCounts(
                 self.fields, self.rows[i], self.counts[i],
                 [k if k is None else k[i] for k in self.keys],
+                None if self.sums is None else self.sums[i],
             )
         n = len(self)
         if not -n <= i < n:
@@ -148,13 +155,13 @@ class GroupCounts(Sequence):
             for j, name in enumerate(self.fields)
         ]
         # lint: allow-hot-serialize(the on-demand object form of a columnar answer: protobuf, merges and the dict oracle; the serving path's JSON never comes here)
-        cells = zip(self.rows.tolist(), self.counts.tolist())
+        cells = zip(self.rows.tolist(), self.counts.tolist(), self.sums.tolist() if self.sums is not None else [None] * len(self))
         return [
             GroupCount(
                 [FieldRow(name, ids[j], ks[g]) for j, (name, ks) in enumerate(cols)],
-                count,
+                count, total,
             )
-            for g, (ids, count) in enumerate(cells)
+            for g, (ids, count, total) in enumerate(cells)
         ]
 
     def __eq__(self, other) -> bool:
@@ -182,6 +189,8 @@ class GroupCounts(Sequence):
         """What the result cache charges: the arrays, the names, and the
         key strings of translated fields."""
         n = 64 + self.rows.nbytes + self.counts.nbytes
+        if self.sums is not None:
+            n += self.sums.nbytes
         n += sum(56 + len(name) for name in self.fields)
         for ks in self.keys:
             if ks is not None:
@@ -190,8 +199,8 @@ class GroupCounts(Sequence):
 
 
 def merge_group_counts(a: list[GroupCount], b: list[GroupCount], limit: int) -> list[GroupCount]:
-    """Sorted merge summing counts of equal groups, capped at limit
-    (reference executor.go mergeGroupCounts :1195)."""
+    """Sorted merge summing counts (and aggregate sums) of equal groups,
+    capped at limit (reference executor.go mergeGroupCounts :1195)."""
     limit = min(limit, len(a) + len(b))
     out: list[GroupCount] = []
     i = j = 0
@@ -204,7 +213,8 @@ def merge_group_counts(a: list[GroupCount], b: list[GroupCount], limit: int) -> 
             out.append(b[j])
             j += 1
         else:
-            out.append(GroupCount(a[i].group, a[i].count + b[j].count))
+            total = None if a[i].sum is None else a[i].sum + b[j].sum
+            out.append(GroupCount(a[i].group, a[i].count + b[j].count, total))
             i += 1
             j += 1
     while i < len(a) and len(out) < limit:

@@ -92,7 +92,10 @@ from pilosa_tpu.ops.blocks import (
     unpack_slab_columns,
 )
 from pilosa_tpu.ops.kernels import (
+    GROUP_SUM_CHUNK,
     MAX_PAIR_SHARDS,
+    group_sum_counts,
+    group_sum_masks,
     group_tile_stats,
     group_tile_stats_pershard,
     mask_lane_slab,
@@ -156,6 +159,12 @@ MAX_GROUP_TILE_SLOTS = 64
 # Bounds the _agg_cache charge and the enumeration working set; combos
 # past it fall back to the CPU oracle rather than OOMing the host.
 MAX_GROUP_RESULT_CELLS = 1 << 24
+
+# GroupBy(…, aggregate=Sum(field=…)): combinations one group_sum launch
+# lists (a program serves any number up to it), and the most live
+# combinations the device answers before the host iterator does.
+GROUP_SUM_SLOTS = 1024
+MAX_GROUP_SUM_COMBOS = 1 << 16
 
 
 def _from_list(table, total: int):
@@ -1355,6 +1364,12 @@ def _eval_spec(spec, blocks_it, scalars_it):
     (tests/test_chip_compile.py test_count_programs_read_rows_in_place).
     """
     tag = spec[0]
+    if tag == "@":
+        # ("@", order, tree): the tree's leaves read the distinct blocks
+        # in `order` (TPUBackend._assemble).
+        distinct = list(blocks_it)
+        return _eval_spec(spec[2], iter([distinct[i] for i in spec[1]]),
+                          scalars_it)
     if tag == "R":
         block = next(blocks_it)  # [S, R, W/128, 128]
         row = next(scalars_it)  # traced scalar
@@ -2038,6 +2053,22 @@ class TPUBackend(VersionWalks):
         blocks: list = []
         scalars: list = []
         spec = self._build(index, c, shards, blocks, scalars)
+        # A stack that several leaves read (a Union of rows of one field)
+        # is one argument of the program, not one a leaf: the compiler
+        # counts every argument's bytes, and eight 4.7 GB leaves of one
+        # stack are no 38 GB program. The spec keeps which leaf reads
+        # which ("@", _eval_spec).
+        unique: list = []
+        at: dict = {}
+        order = []
+        for b in blocks:
+            if id(b) not in at:
+                at[id(b)] = len(unique)
+                unique.append(b)
+            order.append(at[id(b)])
+        if len(unique) < len(blocks):
+            spec = ("@", tuple(order), spec)
+            blocks = unique
         return spec, tuple(blocks), tuple(scalars)
 
     # -- compiled programs -------------------------------------------------
@@ -3166,16 +3197,24 @@ class TPUBackend(VersionWalks):
                     _log("pair program", e)
 
     def group_by(self, index, c: Call, filter_call, child_rows, shards,
-                 cap=None) -> Optional[list]:
+                 cap=None, aggregate=None) -> Optional[list]:
         """_group_by, with a None (the executor then runs the host
-        iterator) counted as a host-path answer."""
-        out = self._group_by(index, c, filter_call, child_rows, shards, cap)
+        iterator) counted as a host-path answer. `aggregate` names the
+        int field of `aggregate=Sum(field=…)`, validated by the executor."""
+        out = self._group_by(index, c, filter_call, child_rows, shards, cap,
+                             aggregate)
         if out is None:
             self._host_path("groupby", "not lowerable")
         return out
 
+    def _served(self, source: str) -> None:
+        """One GroupBy answered: `device` (a program ran for it), `cache`
+        (the group tensor cache held it) or `tables` (the maintained host
+        tables did). The executor counts `host`."""
+        self.stats.with_tags(f"path:{source}").count("groupby_served_total")
+
     def _group_by(self, index, c: Call, filter_call, child_rows, shards,
-                  cap) -> Optional[list]:
+                  cap, aggregate=None) -> Optional[list]:
         """Whole-query GroupBy: device programs compute the group-count
         tensor over every shard — one fused sweep for n<=2, the tiled
         slot engine over the popcount-pruned live combination space for
@@ -3200,6 +3239,9 @@ class TPUBackend(VersionWalks):
             fields.append((fname, f_obj))
             prev, has_prev = child.uint64_arg("previous")
             starts.append(prev + 1 if has_prev else 0)
+        if aggregate is not None:
+            return self._group_sum(index, fields, starts, child_rows,
+                                   shards_t, filter_call, aggregate, cap)
         # Unfiltered 1-/2-field groups ARE the maintained host tables:
         # the TopN rank vector and the pair-count matrix — both
         # refreshed incrementally under write churn, so these GroupBys
@@ -3209,6 +3251,7 @@ class TPUBackend(VersionWalks):
             served = self._group_from_tables(index, fields, shards_t, n)
             if served is not None:
                 stats_np, rs = served
+                self._served("tables")
                 return self._group_enumerate(
                     fields, starts, child_rows, rs, stats_np, n, cap
                 )
@@ -3221,6 +3264,7 @@ class TPUBackend(VersionWalks):
             served = self._groupn_tensor(index, fields, shards_t)
             if served is not None:
                 stats_np, rs = served
+                self._served("tables")
                 return self._group_enumerate(
                     fields, starts, child_rows, rs, stats_np, n, cap
                 )
@@ -3295,6 +3339,7 @@ class TPUBackend(VersionWalks):
                     ))
             if ckey is not None:
                 self._agg_cache.store(ckey, TierEntry(cfp, payload))
+        self._served("device" if hit is None else "cache")
         if payload[0] == "dense":
             return self._group_enumerate(
                 fields, starts, child_rows, rs, payload[1], n, cap
@@ -3303,6 +3348,230 @@ class TPUBackend(VersionWalks):
         return self._group_enumerate_live(
             fields, starts, child_rows, rs, live_rows, stats_live, n, cap
         )
+
+    # -- GroupBy with a Sum aggregate ----------------------------------------
+
+    def _group_sum_program(self, n: int, filtered: bool, depth: int,
+                           signed: bool):
+        """`pilosa_group_sum`: the count masks of the int field's planes
+        under the filter (ops/kernels.py group_sum_masks), then every
+        listed row combination of the n grouped fields counted against
+        each of them (group_sum_counts). One program per (n, filter,
+        depth, sign); jit keys the stack shapes."""
+        key = ("group_sum", n, filtered, depth, signed)
+        with self._fns_lock:
+            fn = self._fns.get(key)
+        if fn is not None:
+            return fn
+
+        interpret = pallas_interpret()
+
+        def body(*args):
+            stacks, bsi_block = args[:n], args[n]
+            combos, active, n_chunks = args[n + 1:n + 4]
+            filt = args[n + 4] if filtered else None
+            masks = group_sum_masks(filt, bsi_block, depth, signed)
+            return self._psum(group_sum_counts(
+                stacks, masks, combos, active, n_chunks, interpret=interpret
+            ))
+
+        body = _named("group_sum", body)
+        if self.mesh is None:
+            fn = jax.jit(body)
+        else:
+            ax = P(self.mesh.axis)
+            fn = jax.jit(shard_map(
+                body, mesh=self.mesh.mesh,
+                in_specs=(ax,) * (n + 1) + (P(), P(), P())
+                + ((ax,) if filtered else ()),
+                out_specs=P(), check_vma=False,
+            ))
+        fn = self._counted_launch("group_sum", fn, key=key)
+        with self._fns_lock:
+            fn = self._fns.setdefault(key, fn)
+        return fn
+
+    def _group_sum(self, index, fields, starts, child_rows, shards_t,
+                   filter_call, agg_name, cap) -> Optional[list]:
+        """GroupBy(…, aggregate=Sum(field=agg_name)): each group's count,
+        and the sum of the int field over the group's columns that hold a
+        value. The answer is cached like a filtered group tensor, keyed by
+        the int field too and fingerprinted by its BSI view, so another
+        aggregate field or a write to this one is never served an entry.
+        None where the device cannot answer (the executor's host iterator
+        then does)."""
+        f_agg = self._field(index, agg_name)
+        opts = f_agg.bsi_group()
+        if opts.bit_depth > MAX_BSI_DEPTH:
+            return None
+        vn = bsi_view_name(agg_name)
+        fkey = ffp = None
+        if filter_call is not None:
+            ffp = self._filter_epochs(index, filter_call)
+            if ffp is not None:
+                fkey = canonical_key(filter_call)
+        ckey = cfp = payload = None
+        if filter_call is None or fkey is not None:
+            ckey = ("group_sum", index, tuple(f for f, _ in fields), fkey,
+                    agg_name)
+            # Captured BEFORE the stack fetch, as _group_by's.
+            cfp = fingerprint(
+                shards_t,
+                [fo.view(VIEW_STANDARD) for _, fo in fields] + [f_agg.view(vn)],
+            ) + (ffp,)
+            hit = self._agg_cache.hit(ckey, cfp)
+            if hit is not None:
+                payload = hit.value
+        if payload is not None:
+            self._served("cache")
+        else:
+            prof = current_profile()
+            try:
+                stacks = [self._get_block(index, fo, shards_t)[0]
+                          for _, fo in fields]
+                bsi_block, _ = self._get_block(
+                    index, f_agg, shards_t, view_name=vn,
+                    min_rows=BSI_OFFSET_BIT + opts.bit_depth,
+                )
+                filt = None
+                if filter_call is not None:
+                    with prof.phase("plan"):
+                        spec, blocks, scalars = self._assemble(
+                            index, filter_call, shards_t
+                        )
+                    with prof.phase("dispatch", span="pilosa.group_sum"):
+                        filt = self._program("vec", spec, False)(
+                            blocks, scalars
+                        )
+            except _Unsupported:
+                return None
+            if stacks[0].shape[0] > MAX_PAIR_SHARDS:
+                return None  # int32 accumulator bound (ops/kernels.py)
+            payload = self._group_sum_sweep(stacks, bsi_block, filt, opts)
+            if payload is None:
+                return None
+            if ckey is not None:
+                self._agg_cache.store(ckey, TierEntry(cfp, payload))
+            self._served("device")
+        _, live, counts, sums = payload
+        return self._group_sum_enumerate(
+            [f for f, _ in fields], starts, child_rows, live, counts, sums, cap
+        )
+
+    def _group_sum_sweep(self, stacks, bsi_block, filt, opts):
+        """("sum", live rows a field, counts int64[K], sums int64[K]) over
+        the K live combinations in odometer order, or None past
+        MAX_GROUP_SUM_COMBOS. A row with no column under the filter is
+        in no group, so only rows that hold one are combined (what keeps
+        a 250 x 250 x 7 grouping of two cities of one nation at 2 x 2 x 6);
+        the same per-row counts of the BSI block say whether any column
+        under the filter holds a negative value. Each round of launches
+        is timed as the other device reads are: `dispatch`, then
+        `device_wait` until the device is done, then `readback`."""
+        depth = opts.bit_depth
+        filtered = filt is not None
+        prof = current_profile()
+        prune = self._group_program(1, filtered)
+        with prof.phase("dispatch", span="pilosa.group_sum"):
+            pending = [prune(*((s, filt) if filtered else (s,)))
+                       for s in list(stacks) + [bsi_block]]
+        with prof.phase("device_wait"):
+            self.programs.block_ready(pending)
+        with prof.phase("readback"):
+            per_row = [np.asarray(p) for p in pending]
+        live = [np.flatnonzero(c > 0) for c in per_row[:-1]]
+        signed = depth > 0 and int(per_row[-1][BSI_SIGN_BIT]) > 0
+        k_live = 1
+        for lr in live:
+            k_live *= lr.size
+        if k_live > MAX_GROUP_SUM_COMBOS:
+            return None
+        live_t = tuple(tuple(int(r) for r in lr) for lr in live)
+        if k_live == 0:
+            empty = np.zeros(0, np.int64)
+            return ("sum", live_t, empty, empty)
+        grids = np.meshgrid(*live, indexing="ij")
+        combos = np.stack([g.ravel() for g in grids], axis=1).astype(np.int32)
+        prog = self._group_sum_program(len(stacks), filtered, depth, signed)
+        repl = (NamedSharding(self.mesh.mesh, P()) if self.mesh is not None
+                else None)
+        # What a launch has to read once: its combinations' rows of each
+        # field, the BSI block (exists, sign and the planes) and the filter.
+        fixed = depth + 2 + (1 if filtered else 0)
+        pending = []
+        with prof.phase("dispatch", span="pilosa.group_sum"):
+            for c0 in range(0, k_live, GROUP_SUM_SLOTS):
+                chunk = combos[c0:c0 + GROUP_SUM_SLOTS]
+                occ = chunk.shape[0]
+                slots = np.zeros((GROUP_SUM_SLOTS, chunk.shape[1]), np.int32)
+                slots[:occ] = chunk
+                active = np.zeros(GROUP_SUM_SLOTS, np.uint32)
+                active[:occ] = 1
+                n_chunks = np.int32(-(-occ // GROUP_SUM_CHUNK))
+                if repl is not None:
+                    slots, active, n_chunks = (
+                        jax.device_put(a, repl)
+                        for a in (slots, active, n_chunks)
+                    )
+                args = tuple(stacks) + (bsi_block, slots, active, n_chunks)
+                if filtered:
+                    args += (filt,)
+                self.stats.count(
+                    "group_sum_rows_total",
+                    fixed + sum(np.unique(chunk[:, j]).size
+                                for j in range(chunk.shape[1])),
+                )
+                pending.append((occ, prog(*args)))
+        with prof.phase("device_wait"):
+            self.programs.block_ready([o for _, o in pending])
+        with prof.phase("readback"):
+            out = np.concatenate(
+                [np.asarray(o)[:occ] for occ, o in pending]
+            ).astype(np.int64)
+        counts = out[:, 0]
+        planes = out[:, 2:2 + depth]
+        if signed:
+            planes = planes - 2 * out[:, 2 + depth:2 + 2 * depth]
+        if depth <= 31:
+            weights = np.left_shift(np.int64(1), np.arange(depth, dtype=np.int64))
+            raw = planes @ weights
+        else:  # exact in Python ints, then int64 like every Sum
+            raw = np.array(
+                planes.astype(object) @ np.array([1 << p for p in range(depth)],
+                                                 dtype=object),
+                dtype=np.int64,
+            )
+        sums = raw + opts.base * out[:, 1]
+        return ("sum", live_t, counts, sums)
+
+    @staticmethod
+    def _group_sum_enumerate(names, starts, child_rows, live, counts, sums,
+                             cap):
+        """The non-empty groups of a grouped Sum among each field's
+        candidates (its rows from `previous` + 1, or its Rows(limit=…)
+        answer), in odometer order, the first `cap` of them."""
+        from pilosa_tpu.exec.result import GroupCounts
+
+        keep = []
+        for lr, lo, rows in zip(live, starts, child_rows):
+            lr = np.asarray(lr, dtype=np.int64)
+            ok = lr >= lo
+            if rows is not None:
+                ok &= np.isin(lr, np.fromiter(rows, dtype=np.int64,
+                                              count=len(rows)))
+            keep.append(np.flatnonzero(ok))
+        shape = tuple(len(lr) for lr in live)
+        sub = np.ix_(*keep)
+        cnt = counts.reshape(shape)[sub]
+        hit = np.nonzero(cnt > 0)
+        if cap is not None:
+            hit = tuple(h[:cap] for h in hit)
+        rows = np.stack(
+            [np.asarray(lr, dtype=np.int64)[k][h]
+             for lr, k, h in zip(live, keep, hit)],
+            axis=1,
+        )
+        return GroupCounts(names, rows, cnt[hit], sums=sums.reshape(shape)[sub][hit])
 
     def _filter_epochs(self, index, filter_call):
         """Epoch fingerprint of every field a GroupBy filter tree

@@ -486,6 +486,70 @@ def group_tile_stats_pershard(f_stack, g_stack, extras, rows_idx, active):
     return _group_tile(f_stack, g_stack, extras, rows_idx, active, None, True)
 
 
+# -- GroupBy with a Sum aggregate -------------------------------------------
+
+#: Combinations a group_sum_counts step ANDs and counts at once.
+GROUP_SUM_CHUNK = 16
+
+
+def group_sum_masks(filt, bsi_block, depth: int, signed: bool):
+    """The count masks of a grouped Sum, uint32[S, P, L, 128]: the filter
+    (a group's count), the filter's columns that hold a value, each
+    magnitude plane under those, and, where the field can hold negative
+    values, each plane under those whose sign bit is set. `filt` None is
+    every column."""
+    exists, sign = bsi_block[:, 0], bsi_block[:, 1]
+    count = jnp.full_like(exists, 0xFFFFFFFF) if filt is None else filt
+    held = exists if filt is None else exists & filt
+    planes = bsi_block[:, 2:2 + depth]
+    masks = [count[:, None], held[:, None], planes & held[:, None]]
+    if signed:
+        masks.append(planes & (held & sign)[:, None])
+    return jnp.concatenate(masks, axis=1)
+
+
+def group_sum_counts(stacks, masks, combos, active, n_chunks,
+                     interpret: bool = False):
+    """Popcounts of row combinations against the count masks:
+
+    ((uint32[S, R_i, L, 128], ...), uint32[S, P, L, 128], int32[T, n] row
+    ids, uint32[T], int32 scalar) -> int32[T, P] with
+    out[t, p] = popcount(AND_i stacks[i][:, combos[t, i]] & masks[:, p])
+    for active[t] == 1, and 0 for every other slot.
+
+    Only the first `n_chunks` steps of GROUP_SUM_CHUNK combinations run,
+    so one compiled program serves any number of live combinations up to
+    T. A step ANDs its combinations' rows, read from the resident stacks,
+    and counts them against every mask in one pair_stats sweep: the
+    [combinations, masks] face is the kernel's [Rf, Rg]. (The fused-XLA
+    sweep of the tile programs ran this face at a fifth of the kernel's
+    rate on a v5e, PERF.md.) Accumulator bound: MAX_PAIR_SHARDS, as
+    pair_stats."""
+    t_slots = combos.shape[0]
+    chunk = GROUP_SUM_CHUNK
+
+    def step(i, out):
+        idx = jax.lax.dynamic_slice_in_dim(combos, i * chunk, chunk, 0)
+        act = jax.lax.dynamic_slice_in_dim(active, i * chunk, chunk, 0)
+        w = None
+        for j, stack in enumerate(stacks):
+            # A dynamic slice a row, read where it lies: a gather of the
+            # chunk's rows lowers to slices of the whole stack.
+            rows = jnp.stack([
+                jax.lax.dynamic_index_in_dim(stack, idx[k, j], axis=1,
+                                             keepdims=False)
+                for k in range(chunk)
+            ], axis=1)  # [S, C, L, 128]
+            w = rows if w is None else w & rows
+        w = mask_lane_slab(w, act[None, :, None, None])
+        counts = pair_stats(w, masks, interpret=interpret)[0]  # [C, P]
+        return jax.lax.dynamic_update_slice_in_dim(out, counts, i * chunk, 0)
+
+    return jax.lax.fori_loop(
+        0, n_chunks, step, jnp.zeros((t_slots, masks.shape[1]), jnp.int32)
+    )
+
+
 def pair_stats_xla(f_stack, g_stack):
     """Fused-XLA reference formulation of pair_stats (same results; used
     as the differential oracle for the Pallas kernel and as the fallback

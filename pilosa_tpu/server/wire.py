@@ -411,6 +411,8 @@ def encode_query_result(r) -> bytes:
                     fbody += _encode_string(3, fr.row_key)
                 gbody += _encode_bytes(1, fbody)
             gbody += _encode_uint64(2, int(gc.count))
+            if gc.sum is not None:
+                gbody += _encode_int64(3, int(gc.sum))
             out += _encode_bytes(8, gbody)
     else:  # None / unknown
         out += _encode_tag(6, 0) + _encode_varint(QUERY_RESULT_NIL)
@@ -570,6 +572,8 @@ def _decode_query_result(data: bytes):
                         gc["group"].append(fr)
                     elif f2 == 2:
                         gc["count"] = v2
+                    elif f2 == 3:
+                        gc["sum"] = v2 - (1 << 64) if v2 >= 1 << 63 else v2
                 groups.append(gc)
         return groups
     return None

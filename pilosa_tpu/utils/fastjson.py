@@ -186,7 +186,9 @@ def _group_count(gc) -> bytes:
                 b'{"field": ' + _string(fr.field)
                 + b', "rowID": %d}' % fr.row_id
             )
-    return b'{"group": [' + b", ".join(rows) + b'], "count": %d}' % gc.count
+    tail = b"" if gc.sum is None else b', "sum": %d' % gc.sum
+    return (b'{"group": [' + b", ".join(rows) + b'], "count": %d' % gc.count
+            + tail + b"}")
 
 
 def _strings(ss: list[bytes]) -> tuple:
@@ -202,7 +204,8 @@ def _strings(ss: list[bytes]) -> tuple:
 def _group_counts(r) -> bytes:
     """A columnar GroupBy answer (exec/result.py GroupCounts) -> the JSON
     list _group_count gives group by group: per field one constant
-    prefix and the id column's digits, spliced for all groups at once. A
+    prefix and the id column's digits, spliced for all groups at once,
+    then the counts and, under a Sum aggregate, the signed sums. A
     field that carries row keys renders its value row by row (a key is a
     Python string to escape; a row without one keeps its rowID)."""
     n = len(r)
@@ -224,7 +227,13 @@ def _group_counts(r) -> bytes:
                 ]),
             ]
         lead = b'}, {"field": '
-    parts += [b'}], "count": ', _digits(r.counts), b"}, "]
+    parts += [b'}], "count": ', _digits(r.counts)]
+    if r.sums is not None:
+        sums = np.asarray(r.sums, dtype=np.int64)
+        minus = np.full((n, 1), ord("-"), dtype=np.uint8)
+        parts += [b', "sum": ', (minus, (sums < 0).astype(np.int64), False),
+                  _digits(np.abs(sums))]
+    parts.append(b"}, ")
     return b"[" + _splice(n, parts)[:-2] + b"]"
 
 

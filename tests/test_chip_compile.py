@@ -315,3 +315,63 @@ def test_tanimoto_programs_compile_at_the_molecule_library_s_height(v5e, on_chip
             be.blocks._packed_update_fn(), packed, i32(n), i32(n),
             jax.ShapeDtypeStruct((n, PACKED_WORDS), jnp.uint32, sharding=one),
         )
+
+
+@pytest.mark.parametrize("depth, signed", [(24, False), (17, False), (24, True)],
+                         ids=["revenue", "supplycost", "signed"])
+@pytest.mark.parametrize("meshed", [False, True], ids=["one", "mesh4"])
+def test_group_sum_program_compiles_at_the_star_schema_s_shape(v5e, on_chip,
+                                                                meshed, depth,
+                                                                signed):
+    """`pilosa_group_sum` (GroupBy with a Sum aggregate) at the shape of
+    SSB's Q4.3 on one chip's share of SF 100: 36 shards; d_year, s_city
+    and p_brand1 (8, 256 and 1,000 rows) grouped under a filter; the
+    revenue's 24 planes or the supply cost's 17, and a signed field's
+    masks (twice the planes). Mosaic lowers the pair sweep over the
+    [combinations, masks] face; the masks and one step's rows are its
+    temporaries: a fraction of a chip beside the resident stacks."""
+    shards = 36
+    if meshed:
+        mesh = ShardMesh(v5e)
+        be = TPUBackend(on_chip, mesh=mesh)
+        place = NamedSharding(mesh.mesh, P(mesh.axis))
+        scalar = NamedSharding(mesh.mesh, P())
+    else:
+        be = TPUBackend(on_chip, device=v5e[0])
+        place = scalar = SingleDeviceSharding(v5e[0])
+    stacks = [_stack(place, shards=shards, rows=r) for r in (8, 256, 1000)]
+    bsi = _stack(place, shards=shards, rows=32)
+    slab = jax.ShapeDtypeStruct((shards, WORD_LINES, WORD_LANES), jnp.uint32,
+                                sharding=place)
+    slots = tpu_mod.GROUP_SUM_SLOTS
+    program = be._group_sum_program(3, True, depth, signed).__wrapped__
+    lowered = program.lower(
+        *stacks, bsi,
+        jax.ShapeDtypeStruct((slots, 3), jnp.int32, sharding=scalar),
+        jax.ShapeDtypeStruct((slots,), jnp.uint32, sharding=scalar),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar),
+        slab,
+    )
+    assert "jit_pilosa_group_sum" in lowered.as_text()[:400]
+    exe = lowered.compile()
+    assert "tpu_custom_call" in exe.as_text()
+    mem = exe.memory_analysis()
+    assert mem.temp_size_in_bytes <= MAX_TEMP_BYTES, mem.temp_size_in_bytes
+    if not meshed:
+        # The 19-50 masks of 36 shards (89-236 MB) and a step's 16
+        # combinations: well under a gigabyte.
+        assert mem.temp_size_in_bytes < (1 << 30), mem.temp_size_in_bytes
+
+
+def test_a_union_of_rows_of_one_stack_reads_it_as_one_argument(v5e, on_chip):
+    """SSB's Q2.2 filter, a Union of eight rows of p_brand1 (1,000 rows of
+    36 shards, 4.7 GB): the assembled program takes the stack once ("@" in
+    the spec, TPUBackend._assemble), where a leaf each asked the compiler
+    for eight copies, 38 GB, and it refused the program."""
+    be = TPUBackend(on_chip, device=v5e[0])
+    one = SingleDeviceSharding(v5e[0])
+    stack = _stack(one, shards=36, rows=1000)
+    u32 = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one)
+    spec = ("@", (0,) * 8, ("U", (("R", "p_brand1"),) * 8))
+    mem = _compiled(be._program("vec", spec, False).__wrapped__, (stack,), (u32,) * 16)
+    assert mem.argument_size_in_bytes < 5 << 30

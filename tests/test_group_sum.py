@@ -1,0 +1,251 @@
+"""GroupBy(…, aggregate=Sum(field=…)): each group's count and the sum of
+an int field over the group's columns that hold a value.
+
+Three ways to answer, one reference. The reference is written here in
+numpy over the columns' own draws (a row id a grouped field, a value or
+none an int field) and knows nothing of planes or bitmaps: a group's
+count is a `np.bincount` of the filtered columns by group, its sum a
+`np.bincount` of their values. The answers compared with it are the
+device executor's (`pilosa_group_sum` on JAX's CPU devices, on one and
+over a mesh of them), the executor's host iterator over the same
+backend, and the executor over `exec/cpu.py`'s backend.
+"""
+
+import numpy as np
+import pytest
+
+from pilosa_tpu.core import Holder
+from pilosa_tpu.core.field import options_for_int, options_for_mutex
+from pilosa_tpu.exec import Executor
+from pilosa_tpu.exec.cpu import CPUBackend, QueryError
+from pilosa_tpu.exec.result import GroupCounts, result_to_json
+from pilosa_tpu.exec.tpu import TPUBackend
+from pilosa_tpu.parallel import ShardMesh
+from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils import fastjson
+from pilosa_tpu.utils.stats import global_stats
+
+N_COLUMNS = 2400
+#: Grouped fields, rows each: `big` is as tall as SSB's p_brand1.
+ROWS = {"a": 4, "b": 5, "c": 3, "big": 1000, "f": 4}
+
+
+def served(path: str) -> float:
+    return global_stats.counter_totals("groupby_served_total").get(
+        f'groupby_served_total{{path="{path}"}}', 0.0
+    )
+
+
+def launches(kind: str) -> float:
+    return global_stats.counter_totals("device_launches_total").get(
+        f'device_launches_total{{kind="{kind}"}}', 0.0
+    )
+
+
+@pytest.fixture(scope="module")
+def data(tmp_path_factory):
+    """(holder, per-column draws) over two shards: every grouped field
+    holds one row a column; `v` (signed, -1000..100000) and `u` (0..5000)
+    hold a value in most columns and none in the rest; `w` is the int
+    field the filters compare."""
+    rng = np.random.default_rng(20261015)
+    cols = np.sort(rng.choice(2 * SHARD_WIDTH, N_COLUMNS, replace=False))
+    draws = {name: rng.integers(0, n, cols.size) for name, n in ROWS.items()}
+    # The big field is tall, and sparse under any filter.
+    draws["big"] = rng.integers(0, 40, cols.size) * 25 + rng.integers(0, 2, cols.size)
+    draws["big"][0] = ROWS["big"] - 1
+    draws["v"] = np.where(rng.random(cols.size) < 0.3,
+                          rng.integers(-1000, 0, cols.size),
+                          rng.integers(0, 100001, cols.size))
+    draws["v_held"] = rng.random(cols.size) < 0.85
+    draws["u"] = rng.integers(0, 5001, cols.size)
+    draws["u_held"] = rng.random(cols.size) < 0.9
+    draws["w"] = rng.integers(0, 100, cols.size)
+    holder = Holder(str(tmp_path_factory.mktemp("group_sum") / "data")).open()
+    idx = holder.create_index("i")
+    for name in ROWS:
+        idx.create_field(name, options_for_mutex())
+        idx.field(name).import_bits(
+            draws[name].astype(np.uint64), cols.astype(np.uint64)
+        )
+    for name, lo, hi in (("v", -1000, 100000), ("u", 0, 5000), ("w", 0, 99)):
+        idx.create_field(name, options_for_int(lo, hi))
+        held = draws.get(name + "_held", np.ones(cols.size, bool))
+        idx.field(name).import_value(cols[held].astype(np.uint64),
+                                     draws[name][held])
+    draws["cols"] = cols
+    yield holder, draws
+    holder.close()
+
+
+def reference(draws, fields, keep, agg, limit=None, offset=0):
+    """The groups of `fields` over the columns `keep` selects, in
+    odometer order (the last field fastest), with count and sum."""
+    heights = [ROWS[f] for f in fields]
+    key = np.zeros(draws["cols"].size, dtype=np.int64)
+    for f, h in zip(fields, heights):
+        key = key * h + draws[f]
+    size = int(np.prod(heights))
+    count = np.bincount(key[keep], minlength=size)
+    held = keep & draws[agg + "_held"]
+    total = np.bincount(key[held], weights=draws[agg][held].astype(np.float64),
+                        minlength=size)
+    out = []
+    for k in np.flatnonzero(count):
+        rows = np.unravel_index(k, heights)
+        out.append({
+            "group": [{"field": f, "rowID": int(r)} for f, r in zip(fields, rows)],
+            "count": int(count[k]),
+            "sum": int(round(total[k])),
+        })
+    out = out[offset:]
+    return out if limit is None else out[:limit]
+
+
+# (PQL, fields, the filter as a function of the draws, aggregate, limit,
+# offset)
+CASES = {
+    "one_field": ("GroupBy(Rows(a), aggregate=Sum(field=v))",
+                  ["a"], lambda d: np.ones(d["cols"].size, bool), "v", None, 0),
+    "two_fields_union_filter": (
+        "GroupBy(Rows(a), Rows(b), filter=Union(Row(f=0), Row(f=1)), "
+        "aggregate=Sum(field=v))",
+        ["a", "b"], lambda d: d["f"] <= 1, "v", None, 0),
+    "three_fields_bsi_less_than": (
+        "GroupBy(Rows(a), Rows(b), Rows(c), "
+        "filter=Intersect(Row(f=2), Row(w < 50)), aggregate=Sum(field=v))",
+        ["a", "b", "c"], lambda d: (d["f"] == 2) & (d["w"] < 50), "v", None, 0),
+    "thousand_rows_bsi_between": (
+        "GroupBy(Rows(big), Rows(a), filter=Intersect(Union(Row(f=0), "
+        "Row(f=3)), Row(10 <= w <= 60)), aggregate=Sum(field=v))",
+        ["big", "a"],
+        lambda d: np.isin(d["f"], [0, 3]) & (d["w"] >= 10) & (d["w"] <= 60),
+        "v", None, 0),
+    "unsigned_field": ("GroupBy(Rows(b), Rows(c), aggregate=Sum(field=u))",
+                       ["b", "c"], lambda d: np.ones(d["cols"].size, bool),
+                       "u", None, 0),
+    "limit_offset": ("GroupBy(Rows(a), Rows(b), limit=5, offset=3, "
+                     "aggregate=Sum(field=v))",
+                     ["a", "b"], lambda d: np.ones(d["cols"].size, bool),
+                     "v", 5, 3),
+    "empty": ("GroupBy(Rows(a), filter=Row(f=9), aggregate=Sum(field=v))",
+              ["a"], lambda d: np.zeros(d["cols"].size, bool), "v", None, 0),
+}
+
+
+def executor_for(holder, path):
+    if path == "cpu":
+        return Executor(holder, backend=CPUBackend(holder))
+    # "mesh": the program under shard_map over the test's virtual
+    # devices, its counts psum-ed.
+    backend = TPUBackend(holder, mesh=ShardMesh() if path == "mesh" else None)
+    if path == "host":
+        backend.group_by = lambda *a, **k: None
+    return Executor(holder, backend=backend)
+
+
+@pytest.mark.parametrize("path", ["device", "mesh", "host", "cpu"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_grouped_sum_equals_the_numpy_reference(data, case, path):
+    holder, draws = data
+    pql, fields, keep, agg, limit, offset = CASES[case]
+    ex = executor_for(holder, path)
+    before = {p: served(p) for p in ("device", "host")}
+    before_launches = launches("group_sum")
+    got = ex.execute("i", pql)[0]
+    want = reference(draws, fields, keep(draws), agg, limit, offset)
+    assert result_to_json(got) == want
+    # The JSON the server writes is the same groups, byte for byte.
+    assert fastjson.encode_result(got) == fastjson.dumps(want)
+    if path in ("device", "mesh"):
+        assert isinstance(got, GroupCounts)
+        assert served("device") == before["device"] + 1
+        assert launches("group_sum") >= before_launches + (1 if want else 0)
+    else:
+        assert served("host") == before["host"] + 1
+    if case != "empty":
+        assert want, "a case whose reference is empty tests nothing"
+
+
+def test_a_cached_answer_is_neither_another_field_s_nor_stale(data):
+    holder, draws = data
+    ex = executor_for(holder, "device")
+    fields = ["a", "b"]
+    keep = draws["f"] <= 1
+    pql = ("GroupBy(Rows(a), Rows(b), filter=Union(Row(f=0), Row(f=1)), "
+           "aggregate=Sum(field={}))")
+    assert result_to_json(ex.execute("i", pql.format("v"))[0]) == \
+        reference(draws, fields, keep, "v")
+    hits = served("cache")
+    ex.execute("i", pql.format("v"))
+    assert served("cache") == hits + 1
+    # Another aggregate field over the same groups and filter: a sweep.
+    swept = served("device")
+    assert result_to_json(ex.execute("i", pql.format("u"))[0]) == \
+        reference(draws, fields, keep, "u")
+    assert served("device") == swept + 1
+    # A write to the int field: the entry no longer answers.
+    at = int(np.flatnonzero(keep & draws["v_held"])[0])
+    ex.execute("i", f"Set({int(draws['cols'][at])}, v=77777)")
+    draws = dict(draws, v=draws["v"].copy())
+    draws["v"][at] = 77777
+    swept = served("device")
+    assert result_to_json(ex.execute("i", pql.format("v"))[0]) == \
+        reference(draws, fields, keep, "v")
+    assert served("device") == swept + 1
+
+
+@pytest.mark.parametrize("pql", [
+    "GroupBy(Rows(a), aggregate=Sum(field=b))",
+    "GroupBy(Rows(a), aggregate=Min(field=v))",
+    "GroupBy(Rows(a), aggregate=Sum(field=v, n=2))",
+    "GroupBy(Rows(a), aggregate=Sum(Row(f=1), field=v))",
+    "GroupBy(Rows(a), aggregate=v)",
+])
+def test_an_aggregate_that_is_not_a_sum_of_an_int_field_is_an_error(data, pql):
+    holder, _ = data
+    for path in ("device", "cpu"):
+        with pytest.raises(QueryError):
+            executor_for(holder, path).execute("i", pql)
+
+
+def test_partial_answers_merge_their_sums_and_travel_over_protobuf():
+    """A coordinator's merge of two nodes' partials adds sums as it adds
+    counts, and a group's sum, negative ones too, crosses the protobuf
+    wire as field 3."""
+    from pilosa_tpu.exec.result import FieldRow, GroupCount, merge_group_counts
+    from pilosa_tpu.server.wire import decode_query_response, encode_query_response
+
+    def group(row, count, total):
+        return GroupCount([FieldRow("a", row)], count, total)
+
+    merged = merge_group_counts([group(1, 2, -5), group(2, 1, 7)],
+                                [group(1, 3, 4), group(3, 1, 0)], 10)
+    assert [(g.group[0].row_id, g.count, g.sum) for g in merged] == [
+        (1, 5, -1), (2, 1, 7), (3, 1, 0)]
+    columns = GroupCounts(["a", "b"], np.array([[1, 2], [3, 4]]),
+                          np.array([5, 6]), sums=np.array([-(1 << 40), 9]))
+    back = decode_query_response(encode_query_response([columns]))["results"][0]
+    assert back == result_to_json(columns) == [
+        {"group": [{"field": "a", "rowID": 1}, {"field": "b", "rowID": 2}],
+         "count": 5, "sum": -(1 << 40)},
+        {"group": [{"field": "a", "rowID": 3}, {"field": "b", "rowID": 4}],
+         "count": 6, "sum": 9},
+    ]
+
+
+def test_the_device_s_work_is_timed_as_dispatch_and_its_wait(data):
+    """A request's grouped sum waits for the device in `device_wait`
+    (filed under `device_dispatch`), then reads back (`host_reduce`): the
+    filter's assembly is `plan`, and no phase of the host holds queued
+    device time."""
+    from pilosa_tpu.utils.qprofile import profile_scope
+
+    holder, _ = data
+    ex = executor_for(holder, "device")
+    pql = CASES["three_fields_bsi_less_than"][0]
+    with profile_scope(index="i", query=pql, call="GroupBy") as prof:
+        ex.execute("i", pql)
+    assert {"plan", "device_dispatch", "host_reduce"} <= set(prof.phases)
+    assert not set(prof.phases) & {"dispatch", "device_wait", "readback"}
+    assert prof.counters["device_wait_us"] > 0

@@ -174,9 +174,10 @@ ALLOWED_TAG_KEYS = {
     "shape",   # canonical-PQL shape fingerprint (pql/ast.py shape_key:
                # structure only — call vocabulary x schema field names;
                # literals never survive into the key)
-    "path",    # which of two code paths did the work (two literals at
-               # the call sites: columnar/objects); never a file or URL
-               # path
+    "path",    # which code path did the work (literals at the call
+               # sites: columnar/objects of a GroupBy's encoding;
+               # device/cache/tables/host of its answer); never a file
+               # or URL path
     "list",    # which of a Tanimoto launch's lists answered a leg
                # (three literals: short/long/finish); never a list's
                # content
