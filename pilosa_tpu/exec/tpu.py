@@ -3507,7 +3507,8 @@ class TPUBackend(VersionWalks):
                 slots[:occ] = chunk
                 active = np.zeros(GROUP_SUM_SLOTS, np.uint32)
                 active[:occ] = 1
-                n_chunks = np.int32(-(-occ // GROUP_SUM_CHUNK))
+                steps = -(-occ // GROUP_SUM_CHUNK)
+                n_chunks = np.int32(steps)
                 if repl is not None:
                     slots, active, n_chunks = (
                         jax.device_put(a, repl)
@@ -3521,6 +3522,10 @@ class TPUBackend(VersionWalks):
                     fixed + sum(np.unique(chunk[:, j]).size
                                 for j in range(chunk.shape[1])),
                 )
+                # A step reads every mask whatever its occupancy: the
+                # sweep's cost a combination is steps over combinations.
+                self.stats.count("group_sum_steps_total", steps)
+                self.stats.count("group_sum_combos_total", occ)
                 pending.append((occ, prog(*args)))
         with prof.phase("device_wait"):
             self.programs.block_ready([o for _, o in pending])

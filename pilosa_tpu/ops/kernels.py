@@ -69,14 +69,16 @@ def _word_counts(x):
     return jnp.sum(jnp.sum(x, axis=-2), axis=-1)
 
 
-def _sequential_grid(n_axes: int):
+def _sequential_grid(n_axes: int, vmem_limit_bytes=None):
     """Mosaic parameters for the accumulating kernels: every grid axis
     is ARBITRARY — visited in order on one core — because each kernel
     carries an output block in VMEM across consecutive grid steps. An
     axis left to the default could be split or reordered and the
-    accumulator silently lost."""
+    accumulator silently lost. `vmem_limit_bytes` None is Mosaic's
+    default scoped limit."""
     return pltpu.CompilerParams(
-        dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,) * n_axes
+        dimension_semantics=(pltpu.GridDimensionSemantics.ARBITRARY,) * n_axes,
+        vmem_limit_bytes=vmem_limit_bytes,
     )
 
 
@@ -502,10 +504,116 @@ def group_sum_masks(filt, bsi_block, depth: int, signed: bool):
     count = jnp.full_like(exists, 0xFFFFFFFF) if filt is None else filt
     held = exists if filt is None else exists & filt
     planes = bsi_block[:, 2:2 + depth]
-    masks = [count[:, None], held[:, None], planes & held[:, None]]
+    s, _, lines, lanes = bsi_block.shape
+    # Each group written in place into the masks' one buffer: the
+    # compiler kept the signed planes' two groups as temporaries beside
+    # a concatenation of them.
+    masks = jnp.zeros((s, 2 + depth * (2 if signed else 1), lines, lanes),
+                      jnp.uint32)
+    masks = masks.at[:, 0].set(count).at[:, 1].set(held)
+    masks = masks.at[:, 2:2 + depth].set(planes & held[:, None])
     if signed:
-        masks.append(planes & (held & sign)[:, None])
-    return jnp.concatenate(masks, axis=1)
+        masks = masks.at[:, 2 + depth:].set(planes & (held & sign)[:, None])
+    return masks
+
+
+#: Word lines of a sub-tile of the combination sweep: a combination's
+#: AND is two vregs, its popcounts against a mask tile are summed into
+#: one (8, 128) accumulator. On a v5e at SSB Q4.3's shape (19-50 masks)
+#: 8 lines ran up to 29 % longer a step, 32 up to 11 %.
+_COMBO_SUB_LINES = 16
+#: VMEM for the combination sweep's double-buffered row and mask blocks
+#: (blocks of 128 word lines at Q4.3's shape). Half of it ran 5-19 %
+#: longer a step on a v5e, twice it within 1 %.
+_COMBO_BLOCK_BYTES = 16 * 1024 * 1024
+
+
+def _combo_tiles(n: int, masks: int, lines: int, lanes: int):
+    """(word lines a block, lines a sub-tile, VMEM limit) of the
+    combination sweep over n fields and `masks` masks."""
+    chunk = GROUP_SUM_CHUNK
+    sub = min(lines, _COMBO_SUB_LINES)
+    lt = lines
+    while 2 * (chunk * n + masks) * lt * lanes * 4 > _COMBO_BLOCK_BYTES \
+            and lt > sub:
+        lt //= 2
+    blocks = 2 * (chunk * n + masks) * lt * lanes * 4
+    acc = masks * chunk * _MIN_LINES * lanes * 4
+    return lt, sub, blocks + acc + (8 << 20)
+
+
+def _combo_sweep_kernel(n, sub, ids_ref, act_ref, *refs):
+    """A block of the combination sweep: grid (step, shard, word-line
+    block). Each step's combinations' rows of the block are copied from
+    the stacks, which stay in HBM, into a double buffer a block ahead;
+    a combination's n row tiles are ANDed in VMEM and counted against
+    each mask tile in turn, a sub-tile of word lines at a time. The
+    counts stay vreg-shaped in `acc_ref` until the step's last block, so
+    their sublanes and lanes are summed once a step."""
+    stacks, (masks_ref, out_ref) = refs[:n], refs[n:n + 2]
+    bufs, (acc_ref, sem) = refs[n + 2:2 * n + 2], refs[2 * n + 2:]
+    p, chunk, _, lanes = acc_ref.shape
+    lt = masks_ref.shape[2]
+    c, s, w = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_s, n_w = pl.num_programs(1), pl.num_programs(2)
+    at_block = (c * n_s + s) * n_w + w
+
+    def copies(block, slot, start: bool):
+        """Start, or wait for, the copies of a block's rows."""
+        step = block // (n_s * n_w)
+        sh, wb = block // n_w % n_s, block % n_w
+        for j in range(n):
+            @pl.loop(0, chunk)
+            def _(k):
+                cp = pltpu.make_async_copy(
+                    stacks[j].at[sh, ids_ref[(step * chunk + k) * n + j],
+                                 pl.ds(wb * lt, lt)],
+                    bufs[j].at[slot, k], sem.at[slot],
+                )
+                cp.start() if start else cp.wait()
+
+    slot = at_block % 2
+
+    @pl.when(at_block == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+        copies(at_block, slot, True)
+
+    @pl.when(at_block + 1 < pl.num_programs(0) * n_s * n_w)
+    def _():
+        copies(at_block + 1, 1 - slot, True)
+
+    @pl.when(jnp.logical_and(s == 0, w == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    copies(at_block, slot, False)
+
+    @pl.loop(0, lt // sub)
+    def _(q):
+        lines = pl.ds(pl.multiple_of(q * sub, sub), sub)
+        combos = []
+        for k in range(chunk):
+            m = bufs[0][slot, k, lines, :]  # [sub, 128]
+            for j in range(1, n):
+                m = m & bufs[j][slot, k, lines, :]
+            # A padded slot replays slot 0's rows: its flag zeroes them.
+            combos.append(mask_lane_slab(m, act_ref[c * chunk + k]))
+        combos = jnp.stack(combos)  # [C, sub, 128]
+        # A mask tile at a time against every combination, never the
+        # whole face in VMEM (its [C, P, sub, 128] form ran 1.2-2.2 times
+        # as long).
+        for i in range(p):
+            pc = jax.lax.population_count(combos & masks_ref[0, i, lines, :])
+            acc_ref[i] += pc.astype(jnp.int32).reshape(
+                chunk, sub // _MIN_LINES, _MIN_LINES, lanes
+            ).sum(axis=1)
+
+    @pl.when(jnp.logical_and(s == n_s - 1, w == n_w - 1))
+    def _():
+        out_ref[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :] = (
+            _word_counts(jnp.swapaxes(acc_ref[...], 0, 1))
+        )
 
 
 def group_sum_counts(stacks, masks, combos, active, n_chunks,
@@ -517,37 +625,40 @@ def group_sum_counts(stacks, masks, combos, active, n_chunks,
     out[t, p] = popcount(AND_i stacks[i][:, combos[t, i]] & masks[:, p])
     for active[t] == 1, and 0 for every other slot.
 
-    Only the first `n_chunks` steps of GROUP_SUM_CHUNK combinations run,
-    so one compiled program serves any number of live combinations up to
-    T. A step ANDs its combinations' rows, read from the resident stacks,
-    and counts them against every mask in one pair_stats sweep: the
-    [combinations, masks] face is the kernel's [Rf, Rg]. (The fused-XLA
-    sweep of the tile programs ran this face at a fifth of the kernel's
-    rate on a v5e, PERF.md.) Accumulator bound: MAX_PAIR_SHARDS, as
-    pair_stats."""
-    t_slots = combos.shape[0]
+    One Pallas sweep whose grid is (step, shard, word-line block): only
+    the first `n_chunks` (at least 1) steps of GROUP_SUM_CHUNK
+    combinations run, so one compiled program serves any number of live
+    combinations up to T. The row ids and flags are prefetched as
+    scalars; a step's combinations' row tiles are copied from the
+    resident stacks by id, ANDed in VMEM and counted against every mask
+    tile, the [combinations, masks] face accumulated as pair_stats does
+    its [Rf, Rg]. No combination's row is written to HBM (ANDed into a
+    uint32[S, C, L, 128] temporary and read back, they took a fifth of
+    ssb-flights-1chip's device time: PERF.md). Each stack is one operand
+    the kernel copies from, not a block a row: interpret mode copies
+    every operand whole, and C x n of them held 34 GB on the test mesh.
+    Accumulator bound: MAX_PAIR_SHARDS, as pair_stats."""
+    t_slots, n = combos.shape
+    s, p, lines, lanes = masks.shape
     chunk = GROUP_SUM_CHUNK
-
-    def step(i, out):
-        idx = jax.lax.dynamic_slice_in_dim(combos, i * chunk, chunk, 0)
-        act = jax.lax.dynamic_slice_in_dim(active, i * chunk, chunk, 0)
-        w = None
-        for j, stack in enumerate(stacks):
-            # A dynamic slice a row, read where it lies: a gather of the
-            # chunk's rows lowers to slices of the whole stack.
-            rows = jnp.stack([
-                jax.lax.dynamic_index_in_dim(stack, idx[k, j], axis=1,
-                                             keepdims=False)
-                for k in range(chunk)
-            ], axis=1)  # [S, C, L, 128]
-            w = rows if w is None else w & rows
-        w = mask_lane_slab(w, act[None, :, None, None])
-        counts = pair_stats(w, masks, interpret=interpret)[0]  # [C, P]
-        return jax.lax.dynamic_update_slice_in_dim(out, counts, i * chunk, 0)
-
-    return jax.lax.fori_loop(
-        0, n_chunks, step, jnp.zeros((t_slots, masks.shape[1]), jnp.int32)
-    )
+    lt, sub, vmem = _combo_tiles(n, p, lines, lanes)
+    return pl.pallas_call(
+        functools.partial(_combo_sweep_kernel, n, sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_chunks, s, lines // lt),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n + [
+                pl.BlockSpec((1, p, lt, lanes), lambda c, i, w, *_: (i, 0, w, 0))
+            ],
+            out_specs=pl.BlockSpec((t_slots, p), lambda *_: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, chunk, lt, lanes), jnp.uint32)] * n
+            + [pltpu.VMEM((p, chunk, _MIN_LINES, lanes), jnp.int32),
+               pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t_slots, p), jnp.int32),
+        compiler_params=_sequential_grid(3, vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(combos.reshape(t_slots * n), active, *stacks, masks)
 
 
 def pair_stats_xla(f_stack, g_stack):

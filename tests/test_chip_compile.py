@@ -327,9 +327,12 @@ def test_group_sum_program_compiles_at_the_star_schema_s_shape(v5e, on_chip,
     SSB's Q4.3 on one chip's share of SF 100: 36 shards; d_year, s_city
     and p_brand1 (8, 256 and 1,000 rows) grouped under a filter; the
     revenue's 24 planes or the supply cost's 17, and a signed field's
-    masks (twice the planes). Mosaic lowers the pair sweep over the
-    [combinations, masks] face; the masks and one step's rows are its
-    temporaries: a fraction of a chip beside the resident stacks."""
+    masks (twice the planes). Mosaic lowers one kernel, whose grid walks
+    the steps: it copies each step's combinations' rows by id and ANDs
+    them as it counts them against the masks. The masks are the
+    program's one large temporary, and no step writes its combinations'
+    rows (`u32[S, 16, 256, 128]`, 75.5 MB a field at 36 shards) to
+    HBM."""
     shards = 36
     if meshed:
         mesh = ShardMesh(v5e)
@@ -354,13 +357,23 @@ def test_group_sum_program_compiles_at_the_star_schema_s_shape(v5e, on_chip,
     )
     assert "jit_pilosa_group_sum" in lowered.as_text()[:400]
     exe = lowered.compile()
-    assert "tpu_custom_call" in exe.as_text()
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    local = shards // (len(v5e) if meshed else 1)
+    step_rows = "u32[%d,%d,%d,%d]" % (
+        local, kernels.GROUP_SUM_CHUNK, WORD_LINES, WORD_LANES
+    )
+    assert step_rows not in text
     mem = exe.memory_analysis()
     assert mem.temp_size_in_bytes <= MAX_TEMP_BYTES, mem.temp_size_in_bytes
     if not meshed:
-        # The 19-50 masks of 36 shards (89-236 MB) and a step's 16
-        # combinations: well under a gigabyte.
-        assert mem.temp_size_in_bytes < (1 << 30), mem.temp_size_in_bytes
+        # The 19-50 masks of 36 shards, 89-236 MB, and nothing of size
+        # beside them.
+        n_masks = 2 + depth * (2 if signed else 1)
+        masks = shards * n_masks * WORD_LINES * WORD_LANES * 4
+        assert mem.temp_size_in_bytes <= masks + (16 << 20), (
+            mem.temp_size_in_bytes, masks
+        )
 
 
 def test_a_union_of_rows_of_one_stack_reads_it_as_one_argument(v5e, on_chip):

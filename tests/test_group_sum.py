@@ -20,6 +20,7 @@ from pilosa_tpu.exec import Executor
 from pilosa_tpu.exec.cpu import CPUBackend, QueryError
 from pilosa_tpu.exec.result import GroupCounts, result_to_json
 from pilosa_tpu.exec.tpu import TPUBackend
+from pilosa_tpu.ops.blocks import WORD_LINES
 from pilosa_tpu.parallel import ShardMesh
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils import fastjson
@@ -232,6 +233,86 @@ def test_partial_answers_merge_their_sums_and_travel_over_protobuf():
         {"group": [{"field": "a", "rowID": 3}, {"field": "b", "rowID": 4}],
          "count": 6, "sum": 9},
     ]
+
+
+# The count sweep alone, in interpret mode: (grouped fields' heights,
+# depth of the int field, signed, slots, steps run, how the host fills
+# the slots, word lines a shard row). "repeat" gives a step's
+# combinations one row id of the first field; "holes" leaves slots
+# inactive between active ones. At a full shard row (WORD_LINES) the
+# kernel walks its blocks and their sub-tiles as on the chip.
+SWEEPS = {
+    "one_field": ((5,), 3, False, 32, 2, "live", 8),
+    "two_fields": ((3, 40), 2, False, 32, 2, "live", 16),
+    "three_fields_signed": ((8, 25, 100), 3, True, 32, 2, "live", WORD_LINES),
+    "repeated_row_in_a_step": ((6, 9), 2, False, 16, 1, "repeat", 8),
+    "padded_slots": ((4, 7, 3), 2, True, 32, 2, "holes", 8),
+    "steps_not_run": ((5, 11), 2, False, 64, 2, "live", WORD_LINES),
+}
+
+
+@pytest.mark.parametrize("case", list(SWEEPS))
+def test_the_fused_count_sweep_equals_a_numpy_oracle(case):
+    """`group_sum_counts` reads each combination's rows by id, ANDs them
+    in the kernel and counts them against every mask of
+    `group_sum_masks`: out[t, p] = popcount(AND_i stacks[i][:, combos[t,
+    i]] & masks[:, p]), exactly 0 for an inactive slot and for every
+    slot of a step past `n_chunks`. The oracle builds the masks from
+    the planes in numpy too (filter; exists; each plane under them; a
+    signed field's planes under its sign as well: P = 2 + 2 x depth)."""
+    import jax
+    from pilosa_tpu.ops.kernels import (
+        GROUP_SUM_CHUNK, group_sum_counts, group_sum_masks,
+    )
+
+    heights, depth, signed, t_slots, n_chunks, fill, lines = SWEEPS[case]
+    rng = np.random.default_rng(sum(heights) * 31 + t_slots)
+    shards = 2
+    words = lambda *shape: rng.integers(  # noqa: E731
+        0, 1 << 32, (shards, *shape, lines, 128), dtype=np.uint32
+    )
+    stacks = [words(h) for h in heights]
+    bsi = words(2 + depth)
+    filt = words()
+    combos = np.stack([rng.integers(0, h, t_slots) for h in heights],
+                      axis=1).astype(np.int32)
+    active = np.ones(t_slots, np.uint32)
+    if fill == "repeat":
+        combos[:, 0] = combos[0, 0]
+    if fill == "holes":
+        active[rng.permutation(t_slots)[: t_slots // 3]] = 0
+        active[n_chunks * GROUP_SUM_CHUNK - 1] = 0
+    active[n_chunks * GROUP_SUM_CHUNK:] = 0  # the host pads past its steps
+
+    @jax.jit
+    def sweep(stacks, bsi, filt, combos, active, n_chunks):
+        masks = group_sum_masks(filt, bsi, depth, signed)
+        return group_sum_counts(stacks, masks, combos, active, n_chunks,
+                                interpret=True)
+
+    # One more step than the host asks for, so the run's last step would
+    # have something to count if `n_chunks` were not honoured.
+    live = active.copy()
+    if t_slots > n_chunks * GROUP_SUM_CHUNK:
+        active[n_chunks * GROUP_SUM_CHUNK:] = 1
+    got = np.asarray(sweep(stacks, bsi, filt, combos, active, np.int32(n_chunks)))
+
+    held = bsi[:, 0] & filt
+    planes = bsi[:, 2:2 + depth]
+    masks = [filt[:, None], held[:, None], planes & held[:, None]]
+    if signed:
+        masks.append(planes & (held & bsi[:, 1])[:, None])
+    masks = np.concatenate(masks, axis=1)  # [S, P, L, 128]
+    rows = np.bitwise_and.reduce(
+        [st[:, combos[:, i]] for i, st in enumerate(stacks)], axis=0
+    )  # [S, T, L, 128]
+    want = np.bitwise_count(rows[:, :, None] & masks[:, None]).sum(
+        axis=(0, 3, 4), dtype=np.int64
+    ) * live[:, None]
+    assert got.shape == (t_slots, 2 + depth * (2 if signed else 1))
+    assert np.array_equal(got, want)
+    assert (got[live == 0] == 0).all()
+    assert (got[live == 1] > 0).all(), "an active slot that counts nothing tests nothing"
 
 
 def test_the_device_s_work_is_timed_as_dispatch_and_its_wait(data):
