@@ -251,10 +251,7 @@ class Executor:
                 with prof.call_timer(
                     r.call.name, waited=r.leg.resolved_at - submitted
                 ):
-                    with self.tracer.start_span(
-                        f"executor.execute{r.call.name}"
-                    ):
-                        result = self._read_result(index, r, opt)
+                    result = self._read_result(index, r, opt)
                     finish(r.call, r.node, r.token, result)
 
         with self.tracer.start_span("executor.Execute") as span:
@@ -289,71 +286,70 @@ class Executor:
                                 if translate or b.has_str_args else b
                                 for b in batch
                             ]
-                    with self.tracer.start_span("executor.executeCountBatch"):
-                        inner = [b.children[0] for b in batch]
-                        sh = self._shards(index, shards)
-                        ex = getattr(prof, "explain", None)
-                        node = None
-                        if ex is not None:
-                            node = ex.begin_call("Count")
-                            node["fused"] = run
-                            node["shards"] = len(sh)
-                            node["devices"] = self._explain_devices()
-                            node["cache"] = cache_verdicts = [None] * run
-                        # Cache consult BEFORE legs go to the batcher:
-                        # hits never launch; the remaining misses still
-                        # coalesce into one device dispatch.
-                        out: list = [None] * run
-                        tokens: list = [None] * run
-                        if cache is not None:
-                            if opt.cache_bypass:
-                                cache.count_bypass(index, run)
-                                prof.incr("cache_bypass", run)
-                            else:
-                                for k, b in enumerate(batch):
-                                    t = cache.begin(
-                                        index, b, sh, remote=opt.remote
-                                    )
-                                    if t is None:
-                                        prof.incr("cache_uncached")
-                                        continue
-                                    tokens[k] = t
-                                    prof.incr("cache_lookups")
-                                    if t.hit:
-                                        prof.incr("cache_hits")
-                                        out[k] = int(t.value)
-                                        if node is not None:
-                                            cache_verdicts[k] = {
-                                                "verdict": "hit",
-                                                "staleBy": getattr(
-                                                    t, "stale_by", 0
-                                                ),
-                                            }
-                        miss = [k for k in range(run) if out[k] is None]
-                        if node is not None:
-                            for k in miss:
-                                if cache_verdicts[k] is None:
-                                    cache_verdicts[k] = {"verdict": "miss"}
-                            node["route"] = (
-                                "rescache" if not miss else (
-                                    "batcher" if self.batcher is not None
-                                    else "count_batch"
+                    inner = [b.children[0] for b in batch]
+                    sh = self._shards(index, shards)
+                    ex = getattr(prof, "explain", None)
+                    node = None
+                    if ex is not None:
+                        node = ex.begin_call("Count")
+                        node["fused"] = run
+                        node["shards"] = len(sh)
+                        node["devices"] = self._explain_devices()
+                        node["cache"] = cache_verdicts = [None] * run
+                    # Cache consult BEFORE legs go to the batcher:
+                    # hits never launch; the remaining misses still
+                    # coalesce into one device dispatch.
+                    out: list = [None] * run
+                    tokens: list = [None] * run
+                    if cache is not None:
+                        if opt.cache_bypass:
+                            cache.count_bypass(index, run)
+                            prof.incr("cache_bypass", run)
+                        else:
+                            for k, b in enumerate(batch):
+                                t = cache.begin(
+                                    index, b, sh, remote=opt.remote
                                 )
+                                if t is None:
+                                    prof.incr("cache_uncached")
+                                    continue
+                                tokens[k] = t
+                                prof.incr("cache_lookups")
+                                if t.hit:
+                                    prof.incr("cache_hits")
+                                    out[k] = int(t.value)
+                                    if node is not None:
+                                        cache_verdicts[k] = {
+                                            "verdict": "hit",
+                                            "staleBy": getattr(
+                                                t, "stale_by", 0
+                                            ),
+                                        }
+                    miss = [k for k in range(run) if out[k] is None]
+                    if node is not None:
+                        for k in miss:
+                            if cache_verdicts[k] is None:
+                                cache_verdicts[k] = {"verdict": "miss"}
+                        node["route"] = (
+                            "rescache" if not miss else (
+                                "batcher" if self.batcher is not None
+                                else "count_batch"
                             )
-                        if miss:
-                            miss_inner = [inner[k] for k in miss]
-                            if self.batcher is not None:
-                                counts = self.batcher.count(
-                                    index, miss_inner, sh
-                                )
-                            else:
-                                counts = self.backend.count_batch(
-                                    index, miss_inner, sh
-                                )
-                            for k, v in zip(miss, counts):
-                                out[k] = int(v)
-                                if tokens[k] is not None:
-                                    cache.commit(tokens[k], int(v))
+                        )
+                    if miss:
+                        miss_inner = [inner[k] for k in miss]
+                        if self.batcher is not None:
+                            counts = self.batcher.count(
+                                index, miss_inner, sh
+                            )
+                        else:
+                            counts = self.backend.count_batch(
+                                index, miss_inner, sh
+                            )
+                        for k, v in zip(miss, counts):
+                            out[k] = int(v)
+                            if tokens[k] is not None:
+                                cache.commit(tokens[k], int(v))
                     results.extend(out)
                     if opt.wire_sink is not None:
                         opt.wire_sink.extend(tokens)
@@ -442,8 +438,7 @@ class Executor:
                 # request's body: `query_seconds{call}` is the request's,
                 # under its first call's name.
                 with prof.call_timer(call.name):
-                    with self.tracer.start_span(f"executor.execute{call.name}"):
-                        result = self.execute_call(index, call, shards, opt)
+                    result = self.execute_call(index, call, shards, opt)
                     finish(call, node, token, result)
                 i += 1
             flush()
@@ -1264,17 +1259,16 @@ class Executor:
         # program (exec/tpu.py group_by); falls back (None) to the
         # per-shard host iterator for anything not lowerable.
         if (self.mapper is None or opt.remote) and hasattr(self.backend, "group_by"):
-            with self.tracer.start_span("executor.executeGroupByDevice"):
-                results = self.backend.group_by(
-                    index, c, filter_call, child_rows,
-                    self._shards(index, shards),
-                    # Enumeration may stop after cap nonzero groups: the
-                    # executor's window is a prefix of odometer order,
-                    # applied below (local) or by the coordinator
-                    # (remote partials are capped-but-untrimmed).
-                    cap=cap if has_lim else None,
-                    aggregate=None if agg is None else agg.name,
-                )
+            results = self.backend.group_by(
+                index, c, filter_call, child_rows,
+                self._shards(index, shards),
+                # Enumeration may stop after cap nonzero groups: the
+                # executor's window is a prefix of odometer order,
+                # applied below (local) or by the coordinator
+                # (remote partials are capped-but-untrimmed).
+                cap=cap if has_lim else None,
+                aggregate=None if agg is None else agg.name,
+            )
             if results is not None:
                 if opt.remote:
                     # Partial for the coordinator's merge: cap, never

@@ -43,6 +43,7 @@ import functools
 import math
 import threading
 import time
+import weakref
 from typing import Callable, Optional
 
 import numpy as np
@@ -123,7 +124,7 @@ from pilosa_tpu.pql.ast import (
 from pilosa_tpu.roaring import Bitmap
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils.locks import InstrumentedRLock
-from pilosa_tpu.utils.qprofile import current_profile
+from pilosa_tpu.utils.qprofile import current_profile, device_feed
 from pilosa_tpu.utils.reuse import ReuseDistanceEstimator
 from pilosa_tpu.utils.stats import global_stats
 
@@ -1539,6 +1540,14 @@ class _ProgramEntry:
         self.last_wall = 0.0     # epoch stamp, for operator display
 
 
+def _dropped(refs) -> bool:
+    """Every output of a launch collected: nothing can wait for it."""
+    for r in refs:
+        if r() is not None:
+            return False
+    return bool(refs)
+
+
 class _ProgramLedger:
     """Device-program ledger (ISSUE 16 tentpole 2): every compiled
     executable the backend ever launched, keyed by its (kind, build
@@ -1554,23 +1563,36 @@ class _ProgramLedger:
     `device_programs_live` gauge. Served coldest-first at
     GET /debug/programs, mirroring /debug/hbm.
 
-    Device time: each launch parks (signature, dispatch t0) on the
-    dispatching thread; the block_ready() wrapper around
-    jax.block_until_ready closes every parked launch of that thread
-    into its entry's cumulative post-sync device seconds."""
+    Device time: each launch is parked as outstanding (its thread,
+    signature, dispatch t0 and weak references to its outputs) until
+    the block_ready() wrapper around jax.block_until_ready that waits
+    for it returns, on whichever thread: a wait closes the launches
+    whose outputs it was handed, and every launch its own thread parked
+    before them, whose outputs fed them or ran ahead of them; a wait on
+    arrays no launch returned (a gather of an output) closes all of its
+    thread's launches. Closing adds the launch's dispatch-to-wait
+    seconds to its entry, and takes it off the process's feed clock
+    (utils/qprofile.py `device_feed`). A launch whose outputs were all
+    dropped unwaited is closed at the next wait or scrape."""
 
-    _PENDING_CAP = 64
+    #: Outstanding launches kept; past it a launch is not parked (and
+    #: not counted on the feed clock), so a leak cannot grow the list.
+    _PENDING_CAP = 256
 
     def __init__(self, stats):
         self._lock = threading.Lock()
         self._entries: dict[tuple, _ProgramEntry] = {}
         self._stats = stats
-        self._local = threading.local()
+        #: Outstanding launches, oldest first: (thread ident, signature,
+        #: dispatch t0, the outputs' ids, weak references to them).
+        self._pending: list[tuple] = []
+        #: (kind, fill) -> slots launched and not yet exported.
+        self._slots: dict[tuple[str, str], int] = {}
 
     # -- registration ------------------------------------------------------
 
     def record_launch(self, kind: str, key, args, wall: float,
-                      compiled: bool, t_dispatch: float) -> tuple:
+                      compiled: bool, t_dispatch: float, out) -> tuple:
         shape_sig = _shape_sig(args)
         sig = (kind, key, shape_sig)
         live = None
@@ -1600,11 +1622,21 @@ class _ProgramLedger:
             if recompile:
                 st.count("device_recompiles_total")
             self._stats.gauge("device_programs_live", live)
-        pend = getattr(self._local, "pending", None)
-        if pend is None:
-            pend = self._local.pending = []
-        if len(pend) < self._PENDING_CAP:
-            pend.append((sig, t_dispatch))
+        leaves = jax.tree_util.tree_leaves(out)
+        refs = []
+        for leaf in leaves:
+            try:
+                refs.append(weakref.ref(leaf))
+            except TypeError:  # a Python scalar: nothing to wait for
+                pass
+        entry = (threading.get_ident(), sig, t_dispatch,
+                 frozenset(map(id, leaves)), refs)
+        with self._lock:
+            parked = len(self._pending) < self._PENDING_CAP
+            if parked:
+                self._pending.append(entry)
+        if parked:
+            device_feed.launched()
         return sig
 
     def record_compile(self, kind: str, key, shapes, seconds: float) -> None:
@@ -1637,8 +1669,8 @@ class _ProgramLedger:
     # -- device-time accrual ----------------------------------------------
 
     def block_ready(self, x):
-        """jax.block_until_ready + close this thread's parked launches
-        into their entries' cumulative device seconds."""
+        """jax.block_until_ready + close the launches the wait covers
+        (see the class docstring) into their entries' device seconds."""
         t0 = time.perf_counter()
         jax.block_until_ready(x)
         now = time.perf_counter()
@@ -1646,15 +1678,61 @@ class _ProgramLedger:
         # /debug/workload ranks shapes by (leaders only; a helper's plane
         # profile drops it, by the one-payer rule).
         current_profile().incr("device_wait_us", math.ceil((now - t0) * 1e6))
-        pend = getattr(self._local, "pending", None)
-        if pend:
-            with self._lock:
-                for sig, t0 in pend:
-                    e = self._entries.get(sig)
-                    if e is not None:
-                        e.device_seconds += now - t0
-            del pend[:]
+        self._close(now, threading.get_ident(),
+                    set(map(id, jax.tree_util.tree_leaves(x))))
         return x
+
+    def count_slots(self, kind: str, live: int, bucket: int) -> None:
+        """One slot-bucketed launch: its distinct slots and its padding,
+        exported at a scrape as `device_slots_total{kind,fill=live|pad}`."""
+        with self._lock:
+            for fill, n in (("live", live), ("pad", bucket - live)):
+                if n:
+                    key = (kind, fill)
+                    self._slots[key] = self._slots.get(key, 0) + n
+
+    def publish(self) -> None:
+        """At a scrape: close the launches whose outputs were all dropped
+        unwaited, and export the slots counted since the last one."""
+        self._close(time.perf_counter(), None, ())
+        with self._lock:
+            slots, self._slots = self._slots, {}
+        for (kind, fill), n in slots.items():
+            self._stats.with_tags(f"kind:{kind}", f"fill:{fill}").count(
+                "device_slots_total", n
+            )
+
+    def outstanding(self) -> int:
+        """Launches parked and not yet closed by a wait."""
+        with self._lock:
+            return len(self._pending)
+
+    def _close(self, now: float, me, ids) -> None:
+        with self._lock:
+            pend = self._pending
+            if not pend:
+                return
+            last = -1  # the last launch the wait was handed
+            for i, entry in enumerate(pend):
+                if not entry[3].isdisjoint(ids):
+                    last = i
+            keep, closed = [], 0
+            for i, entry in enumerate(pend):
+                tid, sig, t0, out_ids, refs = entry
+                if not (
+                    (tid == me and (last < 0 or i <= last))
+                    or (last >= 0 and not out_ids.isdisjoint(ids))
+                    or _dropped(refs)
+                ):
+                    keep.append(entry)
+                    continue
+                closed += 1
+                e = self._entries.get(sig)
+                if e is not None:
+                    e.device_seconds += now - t0
+            self._pending = keep
+        if closed:
+            device_feed.closed(closed)
 
     # -- export ------------------------------------------------------------
 
@@ -2120,7 +2198,8 @@ class TPUBackend(VersionWalks):
             compiled = (
                 before is not None and cache_size() > before
             )
-            sig = ledger.record_launch(kind, key, args, wall, compiled, t0)
+            sig = ledger.record_launch(kind, key, args, wall, compiled, t0,
+                                       out)
             prof = current_profile()
             if prof.charges:
                 # ISSUE 18 satellite fix: stamp the cheap scalar totals
@@ -2408,7 +2487,9 @@ class TPUBackend(VersionWalks):
         except _Unsupported as e:
             self._host_path("bitmap", e)
             return self.cpu.bitmap_call_shard(index, c, shard)
-        slab = self._program("vec", spec, False)(blocks, scalars)
+        slab = self.programs.block_ready(
+            self._program("vec", spec, False)(blocks, scalars)
+        )
         # Lazy columns-backed Row: unpack_row output is sorted and the
         # shard base is a scalar add — no roaring construction unless a
         # set-algebra caller materializes.
@@ -3022,10 +3103,10 @@ class TPUBackend(VersionWalks):
         tile sweeps, so a row pruned here is all-zero in every cell it
         would have produced — unlike the maintained TopN tables, whose
         capture version can trail the fetched stacks under churn."""
+        prog = self._group_program(1, False)
+        counts = self.programs.block_ready([prog(st) for st in stacks[2:]])
         return [
-            np.nonzero(np.asarray(self._group_program(1, False)(st)) > 0)[0]
-            .astype(np.int32)
-            for st in stacks[2:]
+            np.nonzero(np.asarray(c) > 0)[0].astype(np.int32) for c in counts
         ]
 
     def _group_tiles(self, stacks, filt, combos, t_slots: int,
@@ -3073,6 +3154,7 @@ class TPUBackend(VersionWalks):
             occ_st.count("groupby_tiles_total")
             occ_st.histogram("groupby_tile_occupancy", occ)
             pending.append((occ, prog(*args)))
+        self.programs.block_ready([o for _, o in pending])
         return np.concatenate([np.asarray(o)[:occ] for occ, o in pending])
 
     def preheat(self, logger=None) -> int:
@@ -3168,7 +3250,9 @@ class TPUBackend(VersionWalks):
             shapes_done.add(b.shape)
             try:
                 reduce_dev = self._topn_gates(b.shape[0], b.shape[1], False)[1]
-                self._program("topn_plain", None, reduce_dev)(b)
+                self.programs.block_ready(
+                    self._program("topn_plain", None, reduce_dev)(b)
+                )
             except Exception as e:  # noqa: BLE001
                 _log("topn program", e)
         compiled = set()
@@ -3190,9 +3274,11 @@ class TPUBackend(VersionWalks):
                     return
                 compiled.add(key)
                 try:
-                    # Dispatch only (no readback): the compile is the
-                    # cost being fronted; the sweep itself pipelines.
-                    self._pair_program(pershard=pershard_ok)(fb, gb)
+                    # Waited for and dropped (no readback): the compile
+                    # is the cost being fronted.
+                    self.programs.block_ready(
+                        self._pair_program(pershard=pershard_ok)(fb, gb)
+                    )
                 except Exception as e:  # noqa: BLE001
                     _log("pair program", e)
 
@@ -3334,9 +3420,9 @@ class TPUBackend(VersionWalks):
                         return None  # live product past the cell budget
                 else:
                     args = tuple(stacks) + ((filt,) if filt is not None else ())
-                    payload = ("dense", np.asarray(
+                    payload = ("dense", np.asarray(self.programs.block_ready(
                         self._group_program(n, filt is not None)(*args)
-                    ))
+                    )))
             if ckey is not None:
                 self._agg_cache.store(ckey, TierEntry(cfp, payload))
         self._served("device" if hit is None else "cache")
@@ -4093,6 +4179,7 @@ class TPUBackend(VersionWalks):
                 with prof.phase("dispatch", span="pilosa.count_batch",
                                 legs=len(idxs), slots=1):
                     out = self._program("count", spec, reduce_dev)(blocks, ())
+                self.programs.count_slots("count_batch", 1, 1)
                 pending.append((idxs, out, None))
                 continue
             # Slot dedupe by scalar bytes (ISSUE r14; the row_batch_async
@@ -4110,6 +4197,7 @@ class TPUBackend(VersionWalks):
             with prof.phase("dispatch", span="pilosa.count_batch",
                             legs=len(idxs), slots=qb):
                 out = self._program("count_batch", spec, reduce_dev)(blocks, scalars)
+            self.programs.count_slots("count_batch", len(unique), qb)
             pending.append((idxs, out, slot_of))
 
         def resolve() -> list[int]:
@@ -4207,14 +4295,16 @@ class TPUBackend(VersionWalks):
                                 blocks, assembled[chunk[0]][1]
                             )
                         )
+                        self.programs.count_slots("row_batch", 1, 1)
                         continue
+                    qb = _slot_bucket(len(chunk))
                     scal = self._padded_slot_scalars(
-                        [assembled[i][1] for i in chunk],
-                        _slot_bucket(len(chunk)),
+                        [assembled[i][1] for i in chunk], qb
                     )
                     outs.append(
                         self._program("vec_batch", spec, False)(blocks, scal)
                     )
+                    self.programs.count_slots("row_batch", len(chunk), qb)
             pending.append((idxs, slot_of, outs, per_chunk))
 
         # Subset requests gather on device before readback (same
@@ -4522,10 +4612,12 @@ class TPUBackend(VersionWalks):
             # not under the first search that needs it (one inactive
             # slot: run, nothing found, result dropped).
             one = np.zeros(1, np.int32)
-            self._program("topn_tanimoto_counts", None, False)(
-                packed, counts, one, one, one
+            self.programs.block_ready(
+                self._program("topn_tanimoto_counts", None, False)(
+                    packed, counts, one, one, one
+                )
             )
-        return counts
+        return self.programs.block_ready(counts)
 
     def topn_tanimoto_async(self, index: str, field_name: str,
                             shards: list[int], legs: list[tuple[int, int]]):
@@ -4576,6 +4668,7 @@ class TPUBackend(VersionWalks):
             with prof.phase("dispatch", span="pilosa.topn_tanimoto",
                             legs=len(part), slots=qb):
                 out = fn(packed, row_counts, ids, thr, act)
+            self.programs.count_slots("topn_tanimoto", len(part), qb)
             pending.append((len(part), out, (ids, thr, act)))
 
         def resolve(deliver=None) -> list[tuple[np.ndarray, np.ndarray]]:

@@ -1337,14 +1337,25 @@ class _Handler(BaseHTTPRequestHandler):
         self.api.recalculate_caches()
         self._reply({"success": True})
 
-    def _refresh_device_gauges(self) -> None:
-        """Surface device-residency gauges at scrape time (HBM policy) —
-        shared by /metrics and the /metrics/cluster local leg so a bare
-        server (no RuntimeMonitor poller) still exports fresh values."""
+    def _refresh_at_scrape(self) -> None:
+        """Bring the series read at scrape time up to now, shared by
+        /metrics and the /metrics/cluster local leg so a bare server (no
+        RuntimeMonitor poller) still exports fresh values: every
+        thread's CPU, the launched slots, the
+        feed clock up to this instant (launches whose outputs were
+        dropped unwaited closed first), and the device-residency gauges
+        (HBM policy)."""
         from pilosa_tpu.utils.monitor import publish_hbm_gauges
+        from pilosa_tpu.utils.qprofile import device_feed
         from pilosa_tpu.utils.stats import global_stats
+        from pilosa_tpu.utils.threads import thread_cpu
 
+        thread_cpu.publish(global_stats)
         backend = getattr(self.api.executor, "backend", None)
+        programs = getattr(backend, "programs", None)
+        if programs is not None:
+            programs.publish()
+        device_feed.flush(global_stats)
         blocks = getattr(backend, "blocks", None)
         if blocks is None:
             return
@@ -1384,7 +1395,7 @@ class _Handler(BaseHTTPRequestHandler):
             self._error("metrics disabled by [metric] service config",
                         status=404, code="metrics-disabled")
             return
-        self._refresh_device_gauges()
+        self._refresh_at_scrape()
         self._exposition_reply(global_stats.prometheus_text())
 
     @route("GET", r"/debug/queries")
@@ -1690,7 +1701,7 @@ class _Handler(BaseHTTPRequestHandler):
         client = self._scrape_client()
 
         def local_text() -> str:
-            self._refresh_device_gauges()
+            self._refresh_at_scrape()
             return global_stats.prometheus_text()
 
         out: list[str] = []

@@ -446,6 +446,99 @@ class NopProfile:
 NOP_PROFILE = NopProfile()
 
 
+class DeviceFeed:
+    """The process's feed clock: whether the host holds a launch it has
+    not yet waited for, and when it did not, which drain step it was in.
+
+    exec/tpu.py `_ProgramLedger` tells it of every counted launch as its
+    asynchronous call returns (`launched`) and of every launch a
+    `block_ready` closes as that wait returns (`closed`), so
+    `outstanding` counts launches of every backend and thread: on four
+    chips a launch across the mesh is one. While it is at least one,
+    time is `fed`; while it is 0, `unfed`, and each stretch of it is
+    charged once, to whoever takes it first: the drain step whose lap
+    it fell in (`PlaneProfile._lap`), `no_drain` for a stretch outside
+    any drain (charged by the next drain's first lap), or at a scrape
+    (`flush`) to `cause`, the step a drain is in then (`no_drain`
+    between drains). Exported
+    as `device_feed_seconds_total{state,cause}`, which over any two
+    scrapes grows by the wall seconds between them.
+
+    Fed is the host's view: a launch the device has finished stays fed
+    until its wait returns, so the device's own idle share less the
+    unfed share is time the device sat idle while the host held
+    launches it had not yet waited for."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.outstanding = 0
+        #: Start of the stretch not yet accounted, fed or unfed.
+        self._mark = time.perf_counter()
+        self._fed = 0.0    # closed fed stretches, not yet exported
+        self._unfed = 0.0  # closed unfed stretches, not yet charged
+        #: cause -> unfed seconds charged and not yet exported.
+        self._charged: dict[str, float] = {}
+        #: What a scrape charges the open unfed stretch to: the step of
+        #: the drain in progress, or `no_drain` (written by the drain's
+        #: thread, read at a scrape).
+        self.cause = "no_drain"
+
+    # The clock is read under the lock, so that marks never go back.
+
+    def launched(self, n: int = 1) -> None:
+        with self._lock:
+            if self.outstanding == 0:
+                now = time.perf_counter()
+                self._unfed += now - self._mark
+                self._mark = now
+            self.outstanding += n
+
+    def closed(self, n: int) -> None:
+        with self._lock:
+            self.outstanding -= n
+            if self.outstanding == 0:
+                now = time.perf_counter()
+                self._fed += now - self._mark
+                self._mark = now
+
+    def charge(self, cause: str, now: float) -> None:
+        """Charge the unfed seconds up to `now` that nothing has charged
+        yet to `cause`; exported at the next `flush`."""
+        with self._lock:
+            secs, self._unfed = self._unfed, 0.0
+            if self.outstanding == 0 and now > self._mark:
+                secs += now - self._mark
+                self._mark = now
+            if secs:
+                self._charged[cause] = self._charged.get(cause, 0.0) + secs
+
+    def flush(self, stats) -> None:
+        """Export everything up to now (a scrape): the fed seconds to
+        `{state=fed,cause=launch}`, the open unfed stretch to `cause`,
+        and what the laps charged."""
+        self.charge(self.cause, time.perf_counter())
+        with self._lock:
+            now = time.perf_counter()
+            fed, self._fed = self._fed, 0.0
+            if self.outstanding:
+                fed += now - self._mark
+                self._mark = now
+            charged, self._charged = self._charged, {}
+        if fed:
+            stats.with_tags("state:fed", "cause:launch").count(
+                "device_feed_seconds_total", fed
+            )
+        for cause, secs in charged.items():
+            stats.with_tags("state:unfed", f"cause:{cause}").count(
+                "device_feed_seconds_total", secs
+            )
+
+
+#: One feed clock a process, whatever its backends.
+device_feed = DeviceFeed()
+
+
+
 class _StepTimer:
     __slots__ = ("plane", "step", "span")
 
@@ -456,6 +549,7 @@ class _StepTimer:
 
     def __enter__(self):
         self.plane._open = True
+        device_feed.cause = self.step
         if self.span is not None:
             self.span.__enter__()
         return self
@@ -484,7 +578,11 @@ class PlaneProfile(NopProfile):
     dispatch keeps its kind's span name, `pilosa.count_batch`, …), and is
     forwarded to the leader's request profile under the phase it has
     always had there, so `query_phase_seconds` keeps one payer per
-    dispatch. A helper has no leader; what it would forward is dropped."""
+    dispatch. A helper has no leader; what it would forward is dropped.
+    The lap also charges the feed clock's unfed seconds over the same
+    stretch to the step (`device_feed_seconds_total{state=unfed,
+    cause=<step>}`); the first lap also charges the unfed stretch
+    before the drain's start to `cause=no_drain`."""
 
     def __init__(self, stats):
         self.stats = stats
@@ -494,6 +592,9 @@ class PlaneProfile(NopProfile):
         self._open = False
         self._wall = time.perf_counter()
         self._cpu = time.thread_time()
+        #: The drain's start, until the first lap has charged the unfed
+        #: stretch before it to `no_drain` (after `take`, not before it).
+        self._start: Optional[float] = self._wall
 
     def __enter__(self) -> "PlaneProfile":
         """Active on this thread, over the request profile it carries (a
@@ -506,6 +607,7 @@ class PlaneProfile(NopProfile):
 
     def __exit__(self, *exc):
         _local.profile = self.leader
+        device_feed.cause = "no_drain"
         if self.leader is not None and self.leader.in_call is not None:
             self.leader.in_call.resume()
         return False
@@ -537,6 +639,10 @@ class PlaneProfile(NopProfile):
         st = self.stats.with_tags(f"step:{step}")
         st.timing("batch_step_seconds", d_wall)
         st.count("batch_step_cpu_seconds_total", d_cpu)
+        if self._start is not None:
+            device_feed.charge("no_drain", self._start)
+            self._start = None
+        device_feed.charge(step, wall)
         name = _STEP_PHASE.get(step, step)
         if self.leader is not None and name is not None:
             self.leader.add_phase(name, d_wall)

@@ -40,6 +40,7 @@ keep seeing every spawn site as a thread root.
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import time
 from typing import Callable, Optional
@@ -158,3 +159,75 @@ def threads_snapshot() -> list[dict]:
         )
     out.sort(key=lambda e: (e["role"], e["name"]))
     return out
+
+
+class ThreadCpu:
+    """Every thread's CPU seconds, read from the kernel at a scrape and
+    never on a request's path: `thread_cpu_seconds_total{role,runtime}`.
+
+    A thread the interpreter knows (`threading.enumerate()`) is
+    `runtime=python` under its registered role (``main``; ``other``
+    where it has none); any other thread of the process is
+    `runtime=native`, `role=runtime`: the XLA / TPU runtime's own. A
+    Python thread's CPU is an upper bound on its time holding the
+    interpreter lock. Each thread's `utime + stime` from
+    `/proc/self/task/<tid>/stat` is kept between reads, so a read counts
+    what each live thread spent since the one before (a thread's whole
+    life the first time it is seen); what a thread spends between the
+    last read and its exit is not counted. Where there is no /proc, the
+    family is absent."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        #: native thread id -> CPU seconds at the last read.
+        self._last: dict[int, float] = {}
+        try:
+            self._tick = float(os.sysconf("SC_CLK_TCK"))
+        except (AttributeError, OSError, ValueError):
+            self._tick = 100.0
+
+    def _cpu(self, tid: int) -> Optional[float]:
+        try:
+            with open(f"/proc/self/task/{tid}/stat", "rb") as f:
+                raw = f.read()
+        except OSError:  # the thread exited meanwhile
+            return None
+        # Field 2 (the name) may hold spaces and parentheses: count from
+        # the last ')'; utime and stime are fields 14 and 15.
+        rest = raw[raw.rfind(b")") + 2:].split()
+        return (int(rest[11]) + int(rest[12])) / self._tick
+
+    def publish(self, stats) -> None:
+        try:
+            tids = [int(t) for t in os.listdir("/proc/self/task")]
+        except OSError:
+            return
+        roles = roles_snapshot()
+        python = {
+            t.native_id: roles.get(t.ident, "other")
+            for t in threading.enumerate() if t.native_id is not None
+        }
+        spent: dict[tuple[str, str], float] = {}
+        with self._lock:
+            last, self._last = self._last, {}
+            for tid in tids:
+                cpu = self._cpu(tid)
+                if cpu is None:
+                    continue
+                self._last[tid] = cpu
+                before = last.get(tid, 0.0)
+                # A smaller reading is a new thread under a reused id.
+                d = cpu - before if cpu >= before else cpu
+                role = python.get(tid)
+                key = ("runtime", "native") if role is None else (
+                    role, "python"
+                )
+                spent[key] = spent.get(key, 0.0) + d
+        # A role that spent nothing yet still gets its series.
+        for (role, runtime), secs in spent.items():
+            stats.with_tags(f"role:{role}", f"runtime:{runtime}").count(
+                "thread_cpu_seconds_total", secs
+            )
+
+
+thread_cpu = ThreadCpu()

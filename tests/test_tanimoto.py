@@ -407,6 +407,36 @@ def test_n_is_applied_after_the_threshold(mole, n, t):
     assert pairs(ex.execute("mole", pql(m, t or None, n))[0]) == whole[:n]
 
 
+def test_a_search_leaves_no_launch_outstanding(mole):
+    """The stack's row counts, the finish's warm-up and the search are
+    each closed by a wait: nothing stays on the feed clock."""
+    ex, _, bits, _ = mole
+    kinds = ("packed_row_counts", "topn_tanimoto_counts", "topn_tanimoto")
+    before = {k: counter("device_launches_total", kind=k) for k in kinds}
+    assert pairs(ex.execute("mole", pql(30, 70))[0]) == reference(bits, 30, 70)
+    for k in kinds:
+        assert counter("device_launches_total", kind=k) > before[k], k
+    assert ex.backend.programs.outstanding() == 0
+
+
+@pytest.mark.parametrize("legs,bucket", [(1, 1), (3, 4), (16, 16), (17, 17)])
+def test_launched_slots_add_up_to_the_buckets(groups, legs, bucket):
+    """`device_slots_total{kind=topn_tanimoto}`: a launch's distinct legs
+    are live, the rest of its bucket pad; 17 legs are 16 + 1."""
+    be, _, _ = groups
+    fill = ("live", "pad")
+    be.programs.publish()  # what a scrape does
+    before = [counter("device_slots_total", kind="topn_tanimoto", fill=f)
+              for f in fill]
+    asked = [(m, 70) for m in range(legs)] + [(0, 70)]  # one repeated
+    be.topn_tanimoto_async("mole", "fingerprint", [0], asked)()
+    be.programs.publish()
+    live, pad = (counter("device_slots_total", kind="topn_tanimoto", fill=f)
+                 - b for f, b in zip(fill, before))
+    assert (live, pad) == (legs, bucket - legs)
+    assert be.programs.outstanding() == 0
+
+
 def test_plain_topn_under_a_row_is_the_threshold_zero(mole):
     ex, host, bits, _ = mole
     got = pairs(ex.execute("mole", pql(9))[0])

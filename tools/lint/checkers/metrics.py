@@ -181,6 +181,12 @@ ALLOWED_TAG_KEYS = {
     "list",    # which of a Tanimoto launch's lists answered a leg
                # (three literals: short/long/finish); never a list's
                # content
+    "cause",   # what the device's feed clock charges a stretch to
+               # (utils/qprofile.py DeviceFeed: `launch`, a drain step
+               # of DRAIN_STEPS or a phase a drain passes through,
+               # `no_drain`): literals, never request content
+    "runtime", # whose thread spent CPU: python/native (two literals)
+    "fill",    # a launched slot: live/pad (two literals)
 }
 
 #: Variable names that smell like raw request content. A tag VALUE
