@@ -167,6 +167,13 @@ MAX_GROUP_RESULT_CELLS = 1 << 24
 GROUP_SUM_SLOTS = 1024
 MAX_GROUP_SUM_COMBOS = 1 << 16
 
+# A grouped field whose filter names its rows has them counted alone
+# (`pilosa_groupby_rows`) while their slot bucket, at most this many,
+# is at most a quarter of the stack's rows; past that the whole-stack
+# count reads about as much and serves any list.
+MAX_GROUP_ROW_SLOTS = 16
+GROUP_ROWS_SHARE = 4
+
 
 def _from_list(table, total: int):
     """A leg's (row ids, counts) from its line of a tanimoto_topn output:
@@ -186,6 +193,24 @@ def _slot_bucket(n: int) -> int:
     while b < n:
         b <<= 1
     return b
+
+
+def _named_rows(call: Call, field: str) -> Optional[set]:
+    """The row ids of `field` that a filter names, where the filter is
+    `Row(field=<id>)` with no other argument or a Union of such Rows
+    alone; None for any other call."""
+
+    def row_id(c):
+        if c.name != "Row" or c.children or len(c.args) != 1:
+            return None
+        r = c.args.get(field)
+        return None if isinstance(r, bool) or not isinstance(r, int) else r
+
+    if call.name == "Union" and call.children and not call.args:
+        ids = {row_id(c) for c in call.children}
+        return None if None in ids else ids
+    r = row_id(call)
+    return None if r is None else {r}
 
 
 class _Unsupported(Exception):
@@ -1847,6 +1872,12 @@ class TPUBackend(VersionWalks):
         # (a live object's id cannot be reused). Re-planning every
         # request cost ~12% of serving CPU.
         self._plan_cache = TierTable(512)
+        # Whether no column of a resident stack lies in two of its rows,
+        # (index, field) -> (weak reference to the stack it was counted
+        # on, verdict). A write gives the field a new stack array (a
+        # splice or a rebuild, never an update in place), so the verdict
+        # holds for exactly the array it names (_rows_disjoint).
+        self._disjoint: dict = {}
         # Background-compile the fixed-shape sparse-upload programs so
         # a cold stack build never pays their XLA compile on its
         # critical path (ops/sparse.py; idempotent per device). Under a
@@ -3477,6 +3508,125 @@ class TPUBackend(VersionWalks):
             fn = self._fns.setdefault(key, fn)
         return fn
 
+    def _stack_program(self, kind: str, stats, sharded: tuple):
+        """One jitted reduction over a stack, `jit_pilosa_<kind>`: `stats`
+        under the mesh's shard_map with its outputs psum-ed, an argument
+        split on the shard axis where `sharded` says so and replicated
+        elsewhere."""
+        key = (kind, sharded)
+        with self._fns_lock:
+            fn = self._fns.get(key)
+        if fn is not None:
+            return fn
+
+        def body(*args):
+            return jax.tree.map(self._psum, stats(*args))
+
+        body = _named(kind, body)
+        if self.mesh is None:
+            fn = jax.jit(body)
+        else:
+            ax = self.mesh.axis
+            fn = jax.jit(shard_map(
+                body, mesh=self.mesh.mesh,
+                in_specs=tuple(P(ax) if s else P() for s in sharded),
+                out_specs=P(), check_vma=False,
+            ))
+        fn = self._counted_launch(kind, fn, key=key)
+        with self._fns_lock:
+            fn = self._fns.setdefault(key, fn)
+        return fn
+
+    def _group_rows_program(self, filtered: bool):
+        """`pilosa_groupby_rows`: the count of each listed row of a stack
+        under the filter, int32[k], reading only those rows. An id past
+        the stack's rows flags a padded slot and counts 0."""
+
+        def stats(stack, ids, *filt):
+            # One row leaf a slot, as a count program reads its rows
+            # (_eval_spec): a slice of the resident stack fused into the
+            # reduction that counts it, no gathered copy.
+            valid = ids < stack.shape[1]
+            safe = jnp.where(valid, ids, 0)
+            counts = []
+            for j in range(ids.shape[0]):
+                row = jnp.take(stack, safe[j], axis=1)
+                if filt:
+                    row = row & filt[0]
+                counts.append(jnp.sum(
+                    jax.lax.population_count(row).astype(jnp.int32)
+                ))
+            return jnp.stack(counts) * valid
+
+        return self._stack_program(
+            "groupby_rows", stats, (True, False) + ((True,) if filtered else ())
+        )
+
+    def _overlap_program(self):
+        """`pilosa_groupby_overlap`: a stack's per-row counts, int32[R],
+        and the count of the union of its rows."""
+
+        def stats(stack):
+            per_row = jnp.sum(
+                jax.lax.population_count(stack).astype(jnp.int32),
+                axis=(0, 2, 3),
+            )
+            union = jax.lax.reduce(stack, np.uint32(0), jax.lax.bitwise_or,
+                                   (1,))
+            return per_row, jnp.sum(
+                jax.lax.population_count(union).astype(jnp.int32)
+            )
+
+        return self._stack_program("groupby_overlap", stats, (True,))
+
+    def _rows_disjoint(self, index: str, name: str, stack) -> bool:
+        """Whether no column of `stack` lies in two of its rows: the sum
+        of the rows' counts equals the count of their union (never
+        smaller, so equal means no overlap), summed exactly on the host.
+        Counted once a stack array, `pilosa_groupby_overlap`."""
+        key = (index, name)
+        ent = self._disjoint.get(key)
+        if ent is not None and ent[0]() is stack:
+            return ent[1]
+        prof = current_profile()
+        with prof.phase("dispatch", span="pilosa.group_sum"):
+            out = self._overlap_program()(stack)
+        with prof.phase("device_wait"):
+            self.programs.block_ready(out)
+        with prof.phase("readback"):
+            per_row, union = (np.asarray(o) for o in out)
+        verdict = int(per_row.sum(dtype=np.int64)) == int(union)
+        self._disjoint[key] = (weakref.ref(stack), verdict)
+        return verdict
+
+    def _filter_candidates(self, index, names, stacks, filter_call) -> list:
+        """Per grouped field, the sorted row ids its live rows can only be
+        among, or None (count the whole stack). A field has them where
+        the filter, or a conjunct of an Intersect filter, names its rows
+        (`_named_rows`) and no column of its stack lies in two rows: a
+        column under the filter then lies in a named row and in no
+        other. Whether a stack's rows are disjoint is read from the
+        stack (`_rows_disjoint`), whatever the field's type: a mutex
+        field loaded by `import_roaring` can hold a column in two rows,
+        and both count it."""
+        out = [None] * len(names)
+        if filter_call is None:
+            return out
+        conjuncts = (filter_call.children if filter_call.name == "Intersect"
+                     else [filter_call])
+        for i, (name, stack) in enumerate(zip(names, stacks)):
+            ids = None
+            for call in conjuncts:
+                named = _named_rows(call, name)
+                if named is not None:
+                    ids = named if ids is None else ids & named
+            if ids is None or not self._rows_disjoint(index, name, stack):
+                continue
+            out[i] = np.array(
+                sorted(r for r in ids if r < stack.shape[1]), dtype=np.int64
+            )
+        return out
+
     def _group_sum(self, index, fields, starts, child_rows, shards_t,
                    filter_call, agg_name, cap) -> Optional[list]:
         """GroupBy(…, aggregate=Sum(field=agg_name)): each group's count,
@@ -3533,7 +3683,11 @@ class TPUBackend(VersionWalks):
                 return None
             if stacks[0].shape[0] > MAX_PAIR_SHARDS:
                 return None  # int32 accumulator bound (ops/kernels.py)
-            payload = self._group_sum_sweep(stacks, bsi_block, filt, opts)
+            cands = self._filter_candidates(
+                index, [f for f, _ in fields], stacks, filter_call
+            )
+            payload = self._group_sum_sweep(stacks, bsi_block, filt, opts,
+                                            cands)
             if payload is None:
                 return None
             if ckey is not None:
@@ -3544,29 +3698,59 @@ class TPUBackend(VersionWalks):
             [f for f, _ in fields], starts, child_rows, live, counts, sums, cap
         )
 
-    def _group_sum_sweep(self, stacks, bsi_block, filt, opts):
+    def _group_sum_sweep(self, stacks, bsi_block, filt, opts, cands):
         """("sum", live rows a field, counts int64[K], sums int64[K]) over
         the K live combinations in odometer order, or None past
         MAX_GROUP_SUM_COMBOS. A row with no column under the filter is
         in no group, so only rows that hold one are combined (what keeps
-        a 250 x 250 x 7 grouping of two cities of one nation at 2 x 2 x 6);
-        the same per-row counts of the BSI block say whether any column
-        under the filter holds a negative value. Each round of launches
+        a 250 x 250 x 7 grouping of two cities of one nation at 2 x 2 x 6).
+        A field with candidates (`_filter_candidates`) has only those
+        counted, by `pilosa_groupby_rows` where they are few; the count
+        of the BSI block's sign row under the filter says whether any
+        column there holds a negative value. Each round of launches
         is timed as the other device reads are: `dispatch`, then
         `device_wait` until the device is done, then `readback`."""
         depth = opts.bit_depth
         filtered = filt is not None
+        extra = (filt,) if filtered else ()
         prof = current_profile()
+        repl = (NamedSharding(self.mesh.mesh, P()) if self.mesh is not None
+                else None)
         prune = self._group_program(1, filtered)
+        count_rows = self._group_rows_program(filtered)
+
+        for ids in cands:
+            self.stats.with_tags(
+                "path:stack" if ids is None else "path:filter"
+            ).count("group_prune_fields_total")
+        # The int field's sign row rides along as one more candidate list.
+        lists = list(zip(stacks, cands))
+        if depth > 0:
+            lists.append((bsi_block, np.array([BSI_SIGN_BIT])))
+        # Per list: its launch, its ids, whether the launch counted the
+        # ids alone (padded to a slot bucket by an id past the stack) or
+        # every row of the stack.
+        pending = []
         with prof.phase("dispatch", span="pilosa.group_sum"):
-            pending = [prune(*((s, filt) if filtered else (s,)))
-                       for s in list(stacks) + [bsi_block]]
+            for stack, ids in lists:
+                if ids is not None and ids.size <= MAX_GROUP_ROW_SLOTS and (
+                    _slot_bucket(ids.size) * GROUP_ROWS_SHARE <= stack.shape[1]
+                ):
+                    slots = np.full(_slot_bucket(ids.size), stack.shape[1],
+                                    np.int32)
+                    slots[:ids.size] = ids
+                    if repl is not None:
+                        slots = jax.device_put(slots, repl)
+                    pending.append((count_rows(stack, slots, *extra), ids))
+                else:
+                    pending.append((prune(stack, *extra), None))
         with prof.phase("device_wait"):
-            self.programs.block_ready(pending)
+            self.programs.block_ready([out for out, _ in pending])
         with prof.phase("readback"):
-            per_row = [np.asarray(p) for p in pending]
-        live = [np.flatnonzero(c > 0) for c in per_row[:-1]]
-        signed = depth > 0 and int(per_row[-1][BSI_SIGN_BIT]) > 0
+            counts = [np.asarray(out) for out, _ in pending]
+        live = [np.flatnonzero(c > 0) if ids is None else ids[c[:ids.size] > 0]
+                for c, (_, ids) in zip(counts, pending)]
+        signed = depth > 0 and live.pop().size > 0
         k_live = 1
         for lr in live:
             k_live *= lr.size
@@ -3579,8 +3763,6 @@ class TPUBackend(VersionWalks):
         grids = np.meshgrid(*live, indexing="ij")
         combos = np.stack([g.ravel() for g in grids], axis=1).astype(np.int32)
         prog = self._group_sum_program(len(stacks), filtered, depth, signed)
-        repl = (NamedSharding(self.mesh.mesh, P()) if self.mesh is not None
-                else None)
         # What a launch has to read once: its combinations' rows of each
         # field, the BSI block (exists, sign and the planes) and the filter.
         fixed = depth + 2 + (1 if filtered else 0)

@@ -388,3 +388,30 @@ def test_a_union_of_rows_of_one_stack_reads_it_as_one_argument(v5e, on_chip):
     spec = ("@", (0,) * 8, ("U", (("R", "p_brand1"),) * 8))
     mem = _compiled(be._program("vec", spec, False).__wrapped__, (stack,), (u32,) * 16)
     assert mem.argument_size_in_bytes < 5 << 30
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["one", "mesh4"])
+def test_group_rows_program_reads_the_listed_rows_in_place(v5e, on_chip, meshed):
+    """`pilosa_groupby_rows`, the count under a filter of the rows a
+    grouped Sum's filter names, at SSB's p_brand1 shape (1,000 rows of
+    36 shards, 4.7 GB) and a bucket of 8 ids (Q2.2's brands): it reads
+    those rows where they lie, so its temporaries hold no copy of the
+    stack, nor of the eight rows; and the check that a stack's rows are
+    disjoint, which reads the whole stack once, holds no copy of it
+    either."""
+    shards = 36
+    be, place, scalar, _ = _deployment(v5e, on_chip, meshed)
+    stack = _stack(place, shards=shards, rows=1000)
+    slab = jax.ShapeDtypeStruct((shards, WORD_LINES, WORD_LANES), jnp.uint32,
+                                sharding=place)
+    ids = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=scalar)
+    local = shards // (len(v5e) if meshed else 1)
+    row_bytes = local * WORD_LINES * WORD_LANES * 4
+    lowered = be._group_rows_program(True).__wrapped__.lower(stack, ids, slab)
+    assert "jit_pilosa_groupby_rows" in lowered.as_text()[:400]
+    mem = _compiled(be._group_rows_program(True).__wrapped__, stack, ids, slab)
+    assert mem.temp_size_in_bytes < row_bytes, mem.temp_size_in_bytes
+    mem = _compiled(
+        be._overlap_program().__wrapped__, stack
+    )
+    assert mem.temp_size_in_bytes < row_bytes, mem.temp_size_in_bytes

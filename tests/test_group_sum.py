@@ -131,7 +131,65 @@ CASES = {
                      "v", 5, 3),
     "empty": ("GroupBy(Rows(a), filter=Row(f=9), aggregate=Sum(field=v))",
               ["a"], lambda d: np.zeros(d["cols"].size, bool), "v", None, 0),
+    # Filters that name rows of a grouped field: its live rows are among
+    # them, and only they are counted.
+    "filter_row_of_a_grouped_field": (
+        "GroupBy(Rows(a), Rows(b), filter=Intersect(Row(a=1), Row(w < 50)), "
+        "aggregate=Sum(field=v))",
+        ["a", "b"], lambda d: (d["a"] == 1) & (d["w"] < 50), "v", None, 0),
+    "filter_union_of_a_tall_field": (
+        "GroupBy(Rows(big), Rows(a), filter=Intersect(Union(Row(big=25), "
+        "Row(big=26), Row(big=51), Row(big=999)), Row(f=0)), "
+        "aggregate=Sum(field=v))",
+        ["big", "a"],
+        lambda d: np.isin(d["big"], [25, 26, 51, 999]) & (d["f"] == 0),
+        "v", None, 0),
+    "two_conjuncts_name_one_field": (
+        "GroupBy(Rows(a), Rows(c), filter=Intersect(Union(Row(a=0), Row(a=1), "
+        "Row(a=2)), Union(Row(a=1), Row(a=2), Row(a=3))), "
+        "aggregate=Sum(field=u))",
+        ["a", "c"], lambda d: np.isin(d["a"], [1, 2]), "u", None, 0),
+    "an_id_past_the_stack": (
+        "GroupBy(Rows(big), filter=Intersect(Union(Row(big=50), "
+        "Row(big=4000)), Row(w >= 20)), aggregate=Sum(field=v))",
+        ["big"], lambda d: (d["big"] == 50) & (d["w"] >= 20), "v", None, 0),
+    "bare_row_filter": (
+        "GroupBy(Rows(big), Rows(b), filter=Row(big=26), aggregate=Sum(field=v))",
+        ["big", "b"], lambda d: d["big"] == 26, "v", None, 0),
+    "bare_union_of_most_of_a_short_field": (
+        "GroupBy(Rows(a), filter=Union(Row(a=0), Row(a=2), Row(a=3)), "
+        "aggregate=Sum(field=u))",
+        ["a"], lambda d: np.isin(d["a"], [0, 2, 3]), "u", None, 0),
+    "three_fields_one_restricted": (
+        "GroupBy(Rows(a), Rows(b), Rows(c), filter=Intersect(Union(Row(b=1), "
+        "Row(b=3)), Row(w < 60)), aggregate=Sum(field=v))",
+        ["a", "b", "c"], lambda d: np.isin(d["b"], [1, 3]) & (d["w"] < 60),
+        "v", None, 0),
 }
+
+#: How a device sweep of each case prunes its grouped fields: (fields
+#: pruned from the filter's rows, fields pruned over the whole stack, of
+#: the first those whose rows `pilosa_groupby_rows` counts alone). Three
+#: ids of `a` (8 rows with the pad) are a bucket of 4, past a quarter of
+#: the stack: the whole-stack count serves them.
+PRUNED = {
+    "one_field": (0, 1, 0), "two_fields_union_filter": (0, 2, 0),
+    "three_fields_bsi_less_than": (0, 3, 0), "thousand_rows_bsi_between": (0, 2, 0),
+    "unsigned_field": (0, 2, 0), "limit_offset": (0, 2, 0), "empty": (0, 1, 0),
+    "filter_row_of_a_grouped_field": (1, 1, 1),
+    "filter_union_of_a_tall_field": (1, 1, 1),
+    "two_conjuncts_name_one_field": (1, 1, 1),
+    "an_id_past_the_stack": (1, 0, 1),
+    "bare_row_filter": (1, 1, 1),
+    "bare_union_of_most_of_a_short_field": (1, 0, 0),
+    "three_fields_one_restricted": (1, 2, 1),
+}
+
+
+def pruned(path: str) -> float:
+    return global_stats.counter_totals("group_prune_fields_total").get(
+        f'group_prune_fields_total{{path="{path}"}}', 0.0
+    )
 
 
 def executor_for(holder, path):
@@ -153,6 +211,8 @@ def test_grouped_sum_equals_the_numpy_reference(data, case, path):
     ex = executor_for(holder, path)
     before = {p: served(p) for p in ("device", "host")}
     before_launches = launches("group_sum")
+    before_rows = launches("groupby_rows")
+    before_pruned = {p: pruned(p) for p in ("filter", "stack")}
     got = ex.execute("i", pql)[0]
     want = reference(draws, fields, keep(draws), agg, limit, offset)
     assert result_to_json(got) == want
@@ -162,6 +222,11 @@ def test_grouped_sum_equals_the_numpy_reference(data, case, path):
         assert isinstance(got, GroupCounts)
         assert served("device") == before["device"] + 1
         assert launches("group_sum") >= before_launches + (1 if want else 0)
+        by_filter, by_stack, listed = PRUNED[case]
+        assert pruned("filter") == before_pruned["filter"] + by_filter
+        assert pruned("stack") == before_pruned["stack"] + by_stack
+        # The listed fields' rows, and the int field's sign row.
+        assert launches("groupby_rows") == before_rows + listed + 1
     else:
         assert served("host") == before["host"] + 1
     if case != "empty":
@@ -330,3 +395,156 @@ def test_the_device_s_work_is_timed_as_dispatch_and_its_wait(data):
     assert {"plan", "device_dispatch", "host_reduce"} <= set(prof.phases)
     assert not set(prof.phases) & {"dispatch", "device_wait", "readback"}
     assert prof.counters["device_wait_us"] > 0
+
+
+@pytest.mark.parametrize("path", ["device", "mesh"])
+def test_a_mutex_field_with_a_column_in_two_rows_is_counted_whole(tmp_path, path):
+    """A mutex field's writes keep a column in one row, but a roaring
+    import unions bits as they come: here column 7 lies in rows 1 and 2.
+    Under `Row(m=1)` the host iterator counts it in group 2 too, so the
+    device may not take row 1 for the only row that can be live: the
+    stack's rows are not disjoint, and the field is counted whole. Once
+    a write has made them disjoint again, the filter's rows serve."""
+    from pilosa_tpu.roaring import Bitmap, serialize
+
+    holder = Holder(str(tmp_path / "data")).open()
+    try:
+        idx = holder.create_index("i")
+        idx.create_field("m", options_for_mutex())
+        idx.create_field("v", options_for_int(0, 1000))
+        cols = np.arange(40, dtype=np.uint64)
+        idx.field("m").import_bits(cols % 4, cols)
+        idx.field("v").import_value(cols, (cols * 10).astype(np.int64))
+        # Column 7 is in row 3; the import adds it to row 1.
+        idx.field("m").import_roaring(
+            0, serialize(Bitmap(np.array([1 * SHARD_WIDTH + 7], np.uint64)))
+        )
+        pql = "GroupBy(Rows(m), filter=Row(m=1), aggregate=Sum(field=v))"
+        host = result_to_json(executor_for(holder, "host").execute("i", pql)[0])
+        assert host == result_to_json(
+            executor_for(holder, "cpu").execute("i", pql)[0]
+        )
+        assert [g["group"][0]["rowID"] for g in host] == [1, 3]
+        ex = executor_for(holder, path)
+        before = {p: pruned(p) for p in ("filter", "stack")}
+        assert result_to_json(ex.execute("i", pql)[0]) == host
+        assert pruned("stack") == before["stack"] + 1
+        assert pruned("filter") == before["filter"]
+        # Clearing the extra bit gives the field a new stack, counted
+        # again: now disjoint, so only row 1 is counted.
+        ex.execute("i", "Clear(7, m=1)")
+        want = result_to_json(executor_for(holder, "host").execute("i", pql)[0])
+        assert [g["group"][0]["rowID"] for g in want] == [1]
+        before = {p: pruned(p) for p in ("filter", "stack")}
+        assert result_to_json(ex.execute("i", pql)[0]) == want
+        assert pruned("filter") == before["filter"] + 1
+        assert pruned("stack") == before["stack"]
+    finally:
+        holder.close()
+
+
+# -- the Star Schema Benchmark's calls ------------------------------------
+
+#: One stream's constants, chosen so that every call finds columns in
+#: the data below: nation 3 of region 0, its cities 30..35, categories 7
+#: and 8 (brands 280..359, manufacturer 1).
+SSB_CONSTANTS = {
+    "q1.1": (2, 1), "q1.2": (30, 2, 10), "q1.3": (3, 10, 4, 20),
+    "q2.1": (7, 0), "q2.2": (7, 4, 0), "q2.3": (285, 0),
+    "q3.1": (0, 1), "q3.2": (3, 0), "q3.3": (3, 0, 5, 1), "q3.4": (3, 1, 2, 30),
+    "q4.1": (0, 1), "q4.2": (0, 2, 1), "q4.3": (3, 2, 8),
+}
+
+#: A call's grouped fields pruned from the filter's rows and over the
+#: whole stack. A stream prunes 37 fields, 16 of them from the filter.
+SSB_PRUNED = {
+    "q1.1": (1, 0), "q1.2": (1, 0), "q1.3": (1, 0),
+    "q2.1": (0, 2), "q2.2": (1, 1), "q2.3": (1, 1),
+    "q3.1": (1, 2), "q3.2": (1, 2), "q3.3": (3, 0), "q3.4": (2, 1),
+    "q4.1": (0, 2), "q4.2": (1, 2), "q4.3": (1, 2),
+}
+
+
+@pytest.fixture(scope="module")
+def ssb(tmp_path_factory):
+    """(holder, the `ssb` call shape) over one shard of lineorder rows at
+    the benchmark's widths (benchmark/configs/ssb-sf100-1chip.json), each
+    row's dimensions drawn within the hierarchy the queries walk."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import plugins
+
+    rng = np.random.default_rng(20261018)
+    n = 30000
+    cols = np.sort(rng.choice(SHARD_WIDTH, n, replace=False)).astype(np.uint64)
+    month = rng.integers(0, 84, n)
+    c_city = 30 + rng.integers(0, 6, n)
+    s_city = np.where(rng.random(n) < 0.5, c_city, 30 + rng.integers(0, 6, n))
+    brand = 40 * rng.integers(7, 9, n) + rng.integers(0, 40, n)
+    dims = {
+        "d_year": month // 12, "d_yearmonthnum": month,
+        "d_weeknuminyear": rng.integers(0, 53, n),
+        "lo_discount": rng.integers(0, 11, n),
+        "c_city": c_city, "c_nation": c_city // 10, "c_region": c_city // 50,
+        "s_city": s_city, "s_nation": s_city // 10, "s_region": s_city // 50,
+        "p_brand1": brand, "p_category": brand // 40, "p_mfgr": brand // 200,
+    }
+    measures = {"lo_quantity": (0, 50), "lo_extendedprice": (0, 16777215),
+                "lo_revenue": (0, 16777215), "lo_supplycost": (0, 131071)}
+    holder = Holder(str(tmp_path_factory.mktemp("ssb") / "data")).open()
+    idx = holder.create_index("ssb")
+    for name, rows in dims.items():
+        idx.create_field(name, options_for_mutex())
+        idx.field(name).import_bits(rows.astype(np.uint64), cols)
+    for name, (lo, hi) in measures.items():
+        idx.create_field(name, options_for_int(lo, hi))
+        idx.field(name).import_value(cols, rng.integers(lo + 1, hi + 1, n))
+    yield holder, plugins.load("shapes", "ssb")
+    holder.close()
+
+
+def test_a_stream_of_star_schema_calls_prunes_16_of_its_37_fields(ssb):
+    """The sixteen calls of an SSB stream as the benchmark renders them:
+    the device's answer is the host iterator's, and each call's grouped
+    fields are pruned from the filter's rows where it names them (a
+    discount window, a brand or eight, two cities, a range of years)."""
+    holder, shape = ssb
+    device, host = executor_for(holder, "device"), executor_for(holder, "host")
+    totals = np.zeros(2)
+    for query, agg in shape.STREAM:
+        pql = shape.pql((query, agg, SSB_CONSTANTS[query]))
+        before = np.array([pruned("filter"), pruned("stack")])
+        got = result_to_json(device.execute("ssb", pql)[0])
+        grew = np.array([pruned("filter"), pruned("stack")]) - before
+        assert tuple(grew) == SSB_PRUNED[query], query
+        totals += grew
+        assert got == result_to_json(host.execute("ssb", pql)[0]), query
+        assert got, f"{query} finds no group: it tests nothing"
+    assert tuple(totals) == (16, 21)
+
+
+@pytest.mark.parametrize("pql, want", [
+    ("Row(a=1)", {1}),
+    ("Union(Row(a=1), Row(a=3), Row(a=1))", {1, 3}),
+    ("Union(Row(a=1), Row(b=2))", None),
+    ("Row(b=1)", None),
+    ('Row(a=1, from="2020-01-01T00:00", to="2021-01-01T00:00")', None),
+    ("Row(a > 3)", None),
+    ("Row(a=true)", None),
+    ("Not(Row(a=1))", None),
+    ("Intersect(Row(a=1), Row(a=2))", None),
+])
+def test_a_filter_names_rows_of_a_field_only_as_rows_or_their_union(pql, want):
+    """What `_filter_candidates` takes from a conjunct: a plain Row of the
+    field, or a Union of nothing else. A time range reads other views, a
+    comparison no row, and a Not or a nested set operation is not a
+    subset of the rows it names."""
+    from pilosa_tpu.exec.tpu import _named_rows
+    from pilosa_tpu.pql import parse_string
+
+    assert _named_rows(parse_string(pql).calls[0], "a") == want

@@ -176,8 +176,8 @@ ALLOWED_TAG_KEYS = {
                # literals never survive into the key)
     "path",    # which code path did the work (literals at the call
                # sites: columnar/objects of a GroupBy's encoding;
-               # device/cache/tables/host of its answer); never a file
-               # or URL path
+               # device/cache/tables/host of its answer; filter/stack
+               # of a grouped Sum's pruning); never a file or URL path
     "list",    # which of a Tanimoto launch's lists answered a leg
                # (three literals: short/long/finish); never a list's
                # content
