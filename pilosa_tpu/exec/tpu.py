@@ -33,12 +33,17 @@ reference's algorithms (fragment.go:1111-1537); predicate magnitudes ride
 in as traced uint32 bit-vectors so one compiled program serves any
 predicate value of the same (op, sign, bit-depth) shape.
 
-HBM residency: stacks are LRU-tracked against a byte budget; stacks too
-large to ever fit fall back to the CPU oracle (SURVEY.md §7 hard part c).
+HBM residency: stacks are LRU-tracked against a byte budget
+(`max-hbm-bytes`, else the device's own limit less a measured reserve),
+room is made before every upload, and a stack a query holds is pinned;
+stacks too large to ever fit fall back to row pages or the CPU oracle
+(SURVEY.md §7 hard part c), and where an index's dense stacks do not fit
+the budget its tallest mutex fields are held as one row id a column.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import threading
@@ -54,11 +59,15 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pilosa_tpu.core.cache import Pair
-from pilosa_tpu.core.field import FIELD_TYPE_INT
+from pilosa_tpu.core.field import FIELD_TYPE_INT, FIELD_TYPE_MUTEX
 from pilosa_tpu.core.fragment import BSI_EXISTS_BIT, BSI_OFFSET_BIT, BSI_SIGN_BIT
 from pilosa_tpu.core.row import Row
 from pilosa_tpu.core.timequantum import parse_time, views_by_time_range
-from pilosa_tpu.core.view import VIEW_STANDARD, bsi_view_name
+from pilosa_tpu.core.view import (
+    VIEW_STANDARD,
+    bsi_view_name,
+    generation_watermark,
+)
 from pilosa_tpu.exec.cpu import CPUBackend, NotFoundError, QueryError
 from pilosa_tpu.exec.tiers import (  # noqa: F401 — the _names: tests import them from here
     GroupNRows,
@@ -85,8 +94,11 @@ from pilosa_tpu.ops.blocks import (
     pack_fragment_packed,
     pack_row,
     pack_rows,
+    pack_fragment_rowid,
     pack_rows_packed,
     packed_rows,
+    rowid_dtype,
+    rowid_shape,
     stack_shape,
     tile_words,
     unpack_row,
@@ -104,6 +116,10 @@ from pilosa_tpu.ops.kernels import (
     pair_stats,
     pair_stats_pershard,
     packed_row_counts,
+    ROWID_NO_ROW,
+    rowid_hist,
+    rowid_row,
+    rowid_rows,
     slab_counts,
     splice_shard_slabs,
     tanimoto_counts,
@@ -125,6 +141,7 @@ from pilosa_tpu.roaring import Bitmap
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.utils.locks import InstrumentedRLock
 from pilosa_tpu.utils.qprofile import current_profile, device_feed
+from pilosa_tpu.utils.qprofile import span as trace_span
 from pilosa_tpu.utils.reuse import ReuseDistanceEstimator
 from pilosa_tpu.utils.stats import global_stats
 
@@ -213,6 +230,25 @@ def _named_rows(call: Call, field: str) -> Optional[set]:
     return None if r is None else {r}
 
 
+def _layout_of(key: tuple) -> str:
+    """The layout of a block-store key: (index, field, view) and a row
+    page (…, "row", id) are dense; (…, "packed") and (…, "rowid")."""
+    return key[3] if len(key) > 3 and key[3] != "row" else "dense"
+
+
+def _pinning(method):
+    """A backend method with every stack it fetches pinned until it
+    returns (_StackedBlocks.pinned): its launches' outputs are read back
+    by then, or, for a method that hands back a resolver, dispatched."""
+
+    @functools.wraps(method)
+    def run(self, *args, **kwargs):
+        with self.blocks.pinned():
+            return method(self, *args, **kwargs)
+
+    return run
+
+
 class _Unsupported(Exception):
     """Raised by the spec builder when a call can't be device-lowered."""
 
@@ -236,8 +272,15 @@ class _StackedBlocks:
 
     With a mesh, the shard axis is padded to a multiple of the device count
     and placed with NamedSharding(P('shards')) so each device holds its
-    shards in local HBM. An optional byte budget LRU-evicts whole stacks
-    (the HBM residency policy; resident_bytes feeds /metrics).
+    shards in local HBM. A byte budget (budget(): `max-hbm-bytes`, or the
+    device's own limit less a reserve) LRU-evicts whole entries before an
+    upload, never one a query in flight has pinned (pinned()); the HBM
+    residency policy, resident_bytes feeding /metrics.
+
+    Three layouts: dense (above); packed, a narrow set field's first
+    4,096 columns (get_packed); and row ids, a mutex field as the one row
+    that holds each column (get_rowid), chosen where the index's dense
+    stacks would not fit the budget (rowid_field).
     """
 
     #: Incremental-update cutoff: splice at most this fraction of the
@@ -272,12 +315,27 @@ class _StackedBlocks:
     #: stragglers without forgetting a diurnal lull.
     HEAT_HALF_LIFE = 300.0
 
+    #: The least a device keeps beside the entries where the budget is
+    #: its own limit: one chunked stack build's staging (a stack of two
+    #: chunks, ChunkedStackBuilder.transient_bytes).
+    RESERVE_FLOOR = ChunkedStackBuilder.transient_bytes((MIN_CHUNKED_WORDS,))
+
+    #: Longest an upload waits for other queries' pins to clear before
+    #: it is refused (make_room).
+    PIN_WAIT_SECONDS = 30.0
+
+    #: (index, shards) layout choices kept; more and the cache restarts.
+    MAX_LAYOUT_CHOICES = 64
+
     def __init__(self, device=None, mesh=None, max_bytes: Optional[int] = None,
                  fallback=None, heat_half_life: Optional[float] = None,
-                 packed_extra=None):
+                 packed_extra=None, holder=None):
         self.device = device
         self.mesh = mesh  # ShardMesh or None
         self.max_bytes = max_bytes
+        # The holder whose indexes the layout choice reckons (None: every
+        # field is held dense or packed).
+        self.holder = holder
         self.heat_half_life = heat_half_life or self.HEAT_HALF_LIFE
         # Online miss-ratio-curve input (ISSUE 18): every ledger access
         # (hit or rebuild) is offered to the SHARDS sampler; admission
@@ -331,34 +389,148 @@ class _StackedBlocks:
         # only (row pages are demand-paged by design). GIL-atomic dict
         # writes; pruned against _entries under _lock in refresh_stale.
         self._refresh_args: dict[tuple, tuple] = {}
-        # bytes_limit of the device, read once (admission_bytes).
+        # bytes_limit of the device, read once (_device_limit).
         self._device_bytes: Optional[int] = None
         # Called with (a packed stack as it is built or spliced, the
         # stack it replaces or None); what it returns is kept in the
         # stack's entry and get_packed hands it back. TPUBackend counts
         # the rows there.
         self.packed_extra = packed_extra or (lambda arr, stale_arr: None)
+        # Pins: key -> how many pin scopes hold it (pinned()); a thread's
+        # own scope is the set of keys it pinned. An upload waiting for
+        # pins to clear sleeps on the event, set as a scope ends.
+        self._pins: dict[tuple, int] = {}
+        self._pin_local = threading.local()
+        self._unpinned = threading.Event()
+        # Open pin scopes that hold a key, and how many of them wait in
+        # make_room: where all of them wait, none will unpin.
+        self._holders = 0
+        self._stalled = 0
+        # The most the device was seen to hold beyond the entries, and
+        # the largest transient a launch was reckoned to take
+        # (note_transient): the measured part of the reserve.
+        self._other_bytes = 0
+        self._launch_bytes = 0
+        # (index, shards) -> (generation watermark, frozenset of the
+        # fields held as row ids): the layout choice (rowid_field).
+        self._layouts: dict[tuple, tuple] = {}
+        # (index, field) -> the view generation at which the row-id
+        # build found a column in two rows: held dense until it moves.
+        self._rowid_refused: dict[tuple, int] = {}
 
-    def admission_bytes(self) -> Optional[int]:
-        """The most bytes one stack may take: the configured budget, or
-        where none is set what the device says it has (a stack taller
-        than the chip's memory can never be resident: ISSUE 36, a field
-        of 1.7 M rows asked for 207 GB dense). None where neither is
-        known (a CPU device reports no limit): everything is admitted."""
+    # -- the budget ---------------------------------------------------------
+
+    def _device_limit(self) -> int:
+        """bytes_limit of one device, read once; 0 where none is known (a
+        CPU device reports no limit)."""
+        with self._lock:
+            if self._device_bytes is None:
+                limit = 0
+                try:
+                    limit = int((self._first_device().memory_stats() or {})
+                                .get("bytes_limit", 0))
+                except (RuntimeError, AttributeError, NotImplementedError):
+                    limit = 0  # no memory_stats on this backend: no limit known
+                self._device_bytes = limit
+            return self._device_bytes
+
+    def _first_device(self):
+        return self.device or (
+            self.mesh.devices[0] if self.mesh is not None else jax.devices()[0]
+        )
+
+    def reserve_bytes(self) -> int:
+        """Bytes a device keeps beside the entries where the budget is its
+        own limit: the largest of RESERVE_FLOOR (one chunked build's
+        staging), the largest transient a launch of this process was
+        reckoned to take (note_transient: a grouped Sum's row operand,
+        a histogram's two small stacks), and the most the device was
+        measured to hold beyond the entries (`bytes_in_use` less the
+        entries' bytes, read before every upload: programs' temporaries
+        and outputs, arrays a query still holds)."""
+        with self._lock:
+            return max(self.RESERVE_FLOOR, self._launch_bytes,
+                       self._other_bytes)
+
+    def budget(self) -> Optional[int]:
+        """The bytes the entries may take: `max-hbm-bytes` where it is
+        set; else every device's bytes_limit less the reserve
+        (reserve_bytes); None where no limit is known (a CPU device):
+        nothing is then evicted, refused or held as row ids."""
         if self.max_bytes is not None:
             return self.max_bytes
-        if self._device_bytes is None:
-            limit = 0
-            try:
-                dev = self.device or (
-                    self.mesh.devices[0] if self.mesh is not None
-                    else jax.devices()[0]
-                )
-                limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
-            except (RuntimeError, AttributeError, NotImplementedError):
-                limit = 0  # no memory_stats on this backend: no limit known
-            self._device_bytes = limit
-        return self._device_bytes or None
+        limit = self._device_limit()
+        if not limit:
+            return None
+        n = self.mesh.n if self.mesh is not None else 1
+        return max(0, n * (limit - self.reserve_bytes()))
+
+    def note_transient(self, nbytes: int) -> None:
+        """A launch about to take `nbytes` of temporaries beside the
+        entries: the reserve keeps the largest such."""
+        with self._lock:
+            if nbytes > self._launch_bytes:
+                self._launch_bytes = int(nbytes)
+
+    def _measure_other(self) -> None:
+        """Read what the device holds beyond the entries (caller holds
+        _lock); where no figure is known, nothing is read."""
+        if self.max_bytes is not None or not self._device_limit():
+            return
+        try:
+            in_use = int((self._first_device().memory_stats() or {})
+                         .get("bytes_in_use", 0))
+        except (RuntimeError, AttributeError, NotImplementedError):
+            return
+        n = self.mesh.n if self.mesh is not None else 1
+        other = in_use - self.resident_bytes() // n
+        if other > self._other_bytes:
+            self._other_bytes = other
+
+    # -- pins ---------------------------------------------------------------
+
+    @contextlib.contextmanager
+    def pinned(self):
+        """Pin every entry this thread fetches inside the block until the
+        block ends: make_room and _evict skip a pinned entry, so an entry
+        whose array a query holds for its launches is never counted freed
+        while the device still holds it. Callers end the block once the
+        launches' outputs are read back. A block inside another pins
+        into the outer one."""
+        if getattr(self._pin_local, "keys", None) is not None:
+            yield
+            return
+        keys: set = set()
+        self._pin_local.keys = keys
+        try:
+            yield
+        finally:
+            self._pin_local.keys = None
+            if keys:
+                with self._lock:
+                    self._holders -= 1
+                    for k in keys:
+                        n = self._pins.get(k, 0) - 1
+                        if n > 0:
+                            self._pins[k] = n
+                        else:
+                            self._pins.pop(k, None)
+                self._unpinned.set()
+
+    def _pin(self, key: tuple) -> None:
+        """Pin `key` into this thread's open scope, if any (caller holds
+        _lock)."""
+        keys = getattr(self._pin_local, "keys", None)
+        if keys is not None and key not in keys:
+            if not keys:
+                self._holders += 1
+            keys.add(key)
+            self._pins[key] = self._pins.get(key, 0) + 1
+
+    def _pinned_by_others(self, key: tuple) -> bool:
+        """Caller holds _lock."""
+        mine = getattr(self._pin_local, "keys", None) or ()
+        return self._pins.get(key, 0) > (1 if key in mine else 0)
 
     def _pad_shards(self, n: int) -> int:
         if self.mesh is None or self.mesh.n <= 1:
@@ -380,7 +552,12 @@ class _StackedBlocks:
         """Returns (block [S_pad,R,W/128,128], rows_p). Missing fragments
         pack as zeros; padded shards are all-zero (they contribute nothing
         to any count/bitwise result). min_rows forces taller stacks (BSI plane
-        count independent of stored max row)."""
+        count independent of stored max row). A field held as row ids
+        (rowid_field) has no dense stack: (None, 0)."""
+        if view_name == VIEW_STANDARD and self.rowid_field(
+            index, field_obj, shards
+        ):
+            return None, 0
         v = field_obj.view(view_name)
         # O(1) freshness: the view's generation covers every fragment
         # mutation and create/delete under it (core/view.py), so a hit
@@ -420,9 +597,22 @@ class _StackedBlocks:
             )
             rows_p = _padded_rows(n_rows)
             s_pad = self._pad_shards(len(shards))
-            updated = self._try_incremental(
-                stale, shards, min_rows, frags, vers, rows_p, s_pad
-            )
+            nbytes = s_pad * rows_p * WORDS_PER_SHARD * 4
+            if stale is not None and stale[0][0] != tuple(shards):
+                # Another shard set (a `?shards=` subset): the stack it
+                # replaces goes first, so the two are never held at once.
+                self._drop_unpinned(key)
+            # A splice writes a new array beside the old one, and ships
+            # its dirty slabs a chunk at a time.
+            splice_bytes = nbytes + self.UPDATE_CHUNK * rows_p * WORDS_PER_SHARD * 4
+            updated = None
+            if (
+                stale is not None and stale[0][0] == tuple(shards)
+                and self.make_room(splice_bytes, keep=key)
+            ):
+                updated = self._try_incremental(
+                    stale, shards, min_rows, frags, vers, rows_p, s_pad
+                )
             if updated is not None:
                 # tiers=None: the ledger keeps the previous split (the
                 # splice touched O(dirty) shards; re-walking EVERY
@@ -430,13 +620,16 @@ class _StackedBlocks:
                 # O(all-shards) host work the incremental path removes —
                 # the mix re-trues on the next full rebuild).
                 return updated, rows_p, vers, None
-            nbytes = s_pad * rows_p * WORDS_PER_SHARD * 4
-            budget = self.admission_bytes()
+            budget = self.budget()
             if budget is not None and nbytes > budget:
                 # Stack can never be resident under the budget: the caller
                 # falls back to row paging or the CPU oracle instead of
                 # blowing HBM. Not cached (None entries are cheap to
                 # recompute and must not evict real stacks).
+                return None, rows_p, vers, None
+            if not self.make_room(
+                nbytes + self._build_transient(nbytes, rows_p), keep=key
+            ):
                 return None, rows_p, vers, None
             if stale is not None:
                 # A resident stack is being fully re-packed + re-shipped
@@ -519,26 +712,15 @@ class _StackedBlocks:
                         host[i] = pack_fragment(fr, n_rows=rows_p)
                 arr = self._put(host)
             if nbytes >= (64 << 20):
-                # Identity-splice warmup: compile the epoch-update scatter
-                # NOW, while the build already costs seconds — the first
-                # write of a serving window must not stall on XLA compile
-                # (it wedged a whole churn window before this). Zero
-                # payloads: only the SHAPES matter for the compile. Under
-                # a mesh the shard_map splice program warms the same way
-                # (valid=0 lanes: executed, content unchanged, result
-                # discarded).
+                # Splice warm-up: compile the epoch-update scatter NOW,
+                # while the build already costs seconds — the first write
+                # of a serving window must not stall on XLA compile (it
+                # wedged a whole churn window before this). On one device
+                # it compiles from the shapes alone and runs nothing.
+                # Under a mesh the shard_map splice program runs once
+                # (valid=0 lanes: content unchanged, result discarded).
                 if self.mesh is None:
-                    ix = np.minimum(
-                        np.arange(self.UPDATE_CHUNK, dtype=np.int32), s_pad - 1
-                    )
-                    slabs0 = np.zeros(
-                        (self.UPDATE_CHUNK, rows_p, WORDS_PER_SHARD), np.uint32
-                    )
-                    self._warm_update_fn(shape)(
-                        arr,
-                        jax.device_put(tile_words(slabs0), self.device),
-                        jax.device_put(ix, self.device),
-                    )
+                    self._update_fn(shape)
                 else:
                     self._warm_mesh_splice(arr, rows_p)
             # A full build only (not a hit, not a splice): host pack, the
@@ -548,6 +730,12 @@ class _StackedBlocks:
             global_stats.with_tags(f"field:{field_obj.name}").timing(
                 "stack_build_seconds", time.perf_counter() - t_build
             )
+            self._count_upload("dense", stale, nbytes)
+            if view_name == VIEW_STANDARD:
+                # Held dense again (the choice moved): the ids go.
+                self._drop_unpinned(
+                    (index, field_obj.name, VIEW_STANDARD, "rowid")
+                )
             return arr, rows_p, vers, tiers
 
         return self._cached_build(key, fingerprint, build)[:2]
@@ -598,13 +786,13 @@ class _StackedBlocks:
                 )
                 return None
         # Fixed-chunk scatters, chained: each chunk is one upload + one
-        # dispatch of the SAME compiled program (warmed at build time —
-        # see _warm_update_fn), so no epoch ever pays an XLA compile in
+        # dispatch of the SAME compiled program (compiled at build time —
+        # see _update_fn), so no epoch ever pays an XLA compile in
         # the serving path. A short chunk pads by repeating the first
         # dirty slab (duplicate scatter indices with identical payloads
         # are benign). Dispatches pipeline: the chain is async until the
         # caller's readback.
-        fn = self._warm_update_fn(old_arr.shape)
+        fn = self._update_fn(old_arr.shape)
         arr = old_arr
         for c0 in range(0, len(dirty), self.UPDATE_CHUNK):
             chunk = dirty[c0 : c0 + self.UPDATE_CHUNK]
@@ -625,19 +813,36 @@ class _StackedBlocks:
                 jax.device_put(idx, self.device),
             )
             global_stats.count("stack_update_bytes_total", slabs.nbytes)
+            self._count_upload("dense", True, slabs.nbytes)
         global_stats.count("stack_incremental_updates_total")
         global_stats.count("stack_incremental_shards_total", len(dirty))
         return arr
 
     def _warm_update_fn(self, shape: tuple):
-        """The compiled dirty-shard scatter for a stack shape. Called at
-        full-build time too (for large stacks) so the one-time XLA
-        compile lands during build/preheat, not on the first write of a
-        serving window."""
+        """The jitted dirty-shard scatter for a stack shape (UPDATE_CHUNK
+        slabs a call); _update_fn compiles it ahead of time."""
         fn = self._update_fns.get(shape)
         if fn is None:
             fn = jax.jit(lambda arr, sl, ix: arr.at[ix].set(sl))
             self._update_fns[shape] = fn
+        return fn
+
+    def _update_fn(self, shape: tuple, dtype=np.uint32):
+        """The dirty-shard scatter for a stack shape, compiled ahead of
+        time from the shapes alone. Called at full-build time too (for
+        large stacks) so the one-time XLA compile lands during
+        build/preheat, not on the first write of a serving window, and
+        no warm-up run writes a copy of the stack."""
+        key = (tuple(shape), np.dtype(dtype).str)
+        fn = self._update_fns.get(key)
+        if fn is None:
+            chunk = (self.UPDATE_CHUNK,) + tuple(shape[1:])
+            fn = self._warm_update_fn(tuple(shape)).lower(
+                _sds(tuple(shape), dtype, self.device),
+                _sds(chunk, dtype, self.device),
+                _sds((self.UPDATE_CHUNK,), np.int32, self.device),
+            ).compile()
+            self._update_fns[key] = fn
         return fn
 
     def _mesh_update_fn(self):
@@ -728,6 +933,7 @@ class _StackedBlocks:
                     valid[d * c + j] = 1
             arr = fn(arr, *self._mesh_splice_args(slabs, idx, valid))
             global_stats.count("stack_update_bytes_total", slabs.nbytes)
+            self._count_upload("dense", True, slabs.nbytes)
         global_stats.count("stack_incremental_updates_total")
         global_stats.count("stack_incremental_shards_total", len(dirty))
         return arr
@@ -768,7 +974,7 @@ class _StackedBlocks:
         None) where the field is not: chosen from what the fragments
         show (ISSUE 36). A field is held packed when every bit of it
         lies in a shard's first PACKED_BITS columns and the packed stack
-        is admitted (admission_bytes); whether its dense stack is
+        is admitted (budget); whether its dense stack is
         resident too, or could be, does not enter, so the same field is
         packed beside any neighbour and under any budget that holds it.
         Packed it takes at most half of what it takes dense, and from
@@ -798,14 +1004,22 @@ class _StackedBlocks:
                 [fr.max_row_id + 1 for fr in frags if fr is not None] + [1]
             )
             rows_p = packed_rows(n_rows)
-            budget = self.admission_bytes()
+            budget = self.budget()
             if (
                 budget is not None
                 and len(shards) * rows_p * PACKED_WORDS * 4 > budget
             ):
                 return None, 0, None, None
+            nbytes = len(shards) * rows_p * PACKED_WORDS * 4
+            if stale is not None and stale[0][0] != tuple(shards):
+                self._drop_unpinned(key)
+                stale = None
             stale_arr = stale[1] if stale is not None else None
-            updated = self._packed_splice(stale, shards, frags, rows_p)
+            updated = None
+            if stale_arr is not None and self.make_room(
+                nbytes + self.PACKED_UPDATE_ROWS * PACKED_WORDS * 4, keep=key
+            ):
+                updated = self._packed_splice(stale, shards, frags, rows_p)
             if updated is not None:
                 arr, vers = updated
                 extra = (
@@ -830,15 +1044,13 @@ class _StackedBlocks:
                 host[i] = slab
             if stale_arr is not None:
                 global_stats.count("stack_full_rebuilds_total")
+            if not self.make_room(host.nbytes, keep=key):
+                return None, 0, None, None
             arr = jax.device_put(host, self.device)
+            self._count_upload("packed", stale_arr, host.nbytes)
             # Compile the row splice now, beside a build that already
             # costs seconds, and not under a window's first write.
-            self._packed_update_fn()(
-                arr,
-                np.zeros(self.PACKED_UPDATE_ROWS, np.int32),
-                np.zeros(self.PACKED_UPDATE_ROWS, np.int32),
-                np.asarray(host[0, :1]).repeat(self.PACKED_UPDATE_ROWS, 0),
-            )
+            self._packed_splice_fn(arr.shape)
             global_stats.with_tags(f"field:{field_obj.name}").timing(
                 "stack_build_seconds", time.perf_counter() - t_build
             )
@@ -853,15 +1065,31 @@ class _StackedBlocks:
         return self._cached_build(key, fingerprint, build, keep_none=True)
 
     def _packed_update_fn(self):
-        """The compiled row splice of a packed stack: PACKED_UPDATE_ROWS
-        (shard, row) places set to as many rows of words. Shard 0's row
-        0 repeated pads a short epoch (equal payloads: benign)."""
+        """The row splice of a packed stack: PACKED_UPDATE_ROWS (shard,
+        row) places set to as many rows of words. Shard 0's row 0
+        repeated pads a short epoch (equal payloads: benign)."""
         fn = self._update_fns.get("packed")
         if fn is None:
             fn = jax.jit(
                 lambda arr, si, ri, rows: arr.at[si, ri].set(rows)
             )
             self._update_fns["packed"] = fn
+        return fn
+
+    def _packed_splice_fn(self, shape: tuple):
+        """_packed_update_fn for one stack shape, compiled ahead of time
+        from the shapes alone: no warm-up run writes a copy."""
+        key = ("packed", tuple(shape))
+        fn = self._update_fns.get(key)
+        if fn is None:
+            n = self.PACKED_UPDATE_ROWS
+            fn = self._packed_update_fn().lower(
+                _sds(tuple(shape), np.uint32, self.device),
+                _sds((n,), np.int32, self.device),
+                _sds((n,), np.int32, self.device),
+                _sds((n, PACKED_WORDS), np.uint32, self.device),
+            ).compile()
+            self._update_fns[key] = fn
         return fn
 
     def _packed_splice(self, stale, shards, frags, rows_p):
@@ -921,7 +1149,7 @@ class _StackedBlocks:
         # payloads at equal places: benign).
         rows[len(places):] = rows[0]
         places += [places[0]] * (self.PACKED_UPDATE_ROWS - len(places))
-        arr = self._packed_update_fn()(
+        arr = self._packed_splice_fn(old_arr.shape)(
             old_arr,
             np.array([p[0] for p in places], np.int32),
             np.array([p[1] for p in places], np.int32),
@@ -929,20 +1157,196 @@ class _StackedBlocks:
         )
         global_stats.count("stack_update_bytes_total", rows.nbytes)
         global_stats.count("stack_incremental_updates_total")
+        self._count_upload("packed", True, rows.nbytes)
         return arr, tuple(vers)
+
+    # -- the row-id layout ---------------------------------------------------
+
+    def rowid_field(self, index: str, field_obj, shards) -> bool:
+        """Whether a field is held as row ids over `shards`: a mutex
+        field, on one device, among those the layout choice names for
+        the index (_choose_rowid). O(1) while no write has moved the
+        process's generation watermark."""
+        if (
+            self.holder is None or self.mesh is not None or not shards
+            or field_obj.options.type != FIELD_TYPE_MUTEX
+        ):
+            return False
+        key = (index, tuple(shards))
+        mark = generation_watermark()
+        with self._lock:
+            got = self._layouts.get(key)
+            if got is None or got[0] != mark:
+                if len(self._layouts) >= self.MAX_LAYOUT_CHOICES:
+                    self._layouts.clear()
+                got = (mark, self._choose_rowid(index, key[1]))
+                self._layouts[key] = got
+        return field_obj.name in got[1]
+
+    def _choose_rowid(self, index: str, shards: tuple) -> frozenset:
+        """The mutex fields of an index held as row ids over `shards`:
+        none while every view's dense stack would fit the budget
+        together; else the tallest mutex fields, tallest first, until
+        they do (or none is left). A field qualifies where its ids fit
+        uint16 and take fewer bytes than its dense rows, and no build
+        found a column in two of its rows at the view's generation."""
+        budget = self.budget()
+        idx = self.holder.index(index)
+        if budget is None or idx is None:
+            return frozenset()
+        s_pad = self._pad_shards(len(shards))
+        total = 0
+        tall = []
+        for name, f in list(idx.fields.items()):
+            for vn, v in list(f.views.items()):
+                n_rows = 1
+                if vn == bsi_view_name(name) and f.options.type == FIELD_TYPE_INT:
+                    n_rows = BSI_OFFSET_BIT + f.options.bit_depth
+                for sh in shards:
+                    fr = v.fragment(sh)
+                    if fr is not None:
+                        n_rows = max(n_rows, fr.max_row_id + 1)
+                dense = s_pad * _padded_rows(n_rows) * WORDS_PER_SHARD * 4
+                total += dense
+                dt = rowid_dtype(n_rows)
+                if (
+                    vn != VIEW_STANDARD or f.options.type != FIELD_TYPE_MUTEX
+                    or dt is None
+                    or self._rowid_refused.get((index, name)) == v.generation
+                ):
+                    continue
+                ids = s_pad * SHARD_WIDTH * np.dtype(dt).itemsize
+                if ids < dense:
+                    tall.append((dense, name, ids))
+        chosen = []
+        for dense, name, ids in sorted(tall, reverse=True):
+            if total <= budget:
+                break
+            chosen.append(name)
+            total -= dense - ids
+        return frozenset(chosen)
+
+    def get_rowid(self, index: str, field_obj, shards: tuple[int, ...]):
+        """(ids uint8|uint16[S, WORD_LINES, 32, WORD_LANES], rows) of a
+        mutex field held as row ids (ops/blocks.py rowid_shape), or
+        (None, 0) where it cannot be: a column in two rows (the field is
+        then held dense until a write moves its generation), more rows
+        than uint16 holds, or no room under the budget. Built from the
+        fragments one numpy pass a shard; cached, ledgered, pinned and
+        evicted like any stack under (index, field, view, "rowid"), and
+        as fresh: the view's generation is the fingerprint, and a moved
+        one re-packs the dirty shards' ids and splices them in (a new
+        array), or re-packs all of them."""
+        v = field_obj.view(VIEW_STANDARD)
+        if v is None or not shards or self.mesh is not None:
+            return None, 0
+        fingerprint = (tuple(shards), v.generation)
+        key = (index, field_obj.name, VIEW_STANDARD, "rowid")
+
+        def refuse(gen):
+            with self._lock:
+                self._rowid_refused[(index, field_obj.name)] = gen
+                self._layouts.clear()
+            return None, 0, None, None
+
+        def build(stale):
+            t_build = time.perf_counter()
+            gen = v.generation
+            frags = [v.fragment(sh) for sh in shards]
+            vers = []
+            n_rows = 1
+            for fr in frags:
+                if fr is None:
+                    vers.append(None)
+                    continue
+                with fr.lock:
+                    vers.append((fr.uid, fr.version))
+                n_rows = max(n_rows, fr.max_row_id + 1)
+            vers = tuple(vers)
+            dt = rowid_dtype(n_rows)
+            if dt is None:
+                return refuse(gen)
+            shape = rowid_shape(len(shards))
+            nbytes = int(np.prod(shape)) * np.dtype(dt).itemsize
+            if stale is not None and stale[1] is not None and (
+                stale[0][0] != tuple(shards) or stale[1].dtype != dt
+            ):
+                self._drop_unpinned(key)
+                stale = None
+            none = np.iinfo(dt).max
+
+            def slab(fr):
+                if fr is None:
+                    return np.full(shape[1:], none, dt)
+                return pack_fragment_rowid(fr, dt)
+
+            old = stale[1] if stale is not None else None
+            if old is not None and stale[3] is not None:
+                dirty = [i for i, (a, b) in enumerate(zip(stale[3], vers))
+                         if a != b]
+                chunk = self.UPDATE_CHUNK
+                if dirty and len(dirty) <= max(
+                    1, len(shards) // self.MAX_INCREMENTAL_FRACTION
+                ) and self.make_room(
+                    nbytes + chunk * int(np.prod(shape[1:])) * old.dtype.itemsize,
+                    keep=key,
+                ):
+                    fn = self._update_fn(old.shape, old.dtype)
+                    arr = old
+                    for c0 in range(0, len(dirty), chunk):
+                        part = dirty[c0:c0 + chunk]
+                        part = part + [part[0]] * (chunk - len(part))
+                        slabs = [slab(frags[i]) for i in part]
+                        if any(sl is None for sl in slabs):
+                            return refuse(gen)
+                        slabs = np.stack(slabs)
+                        arr = fn(arr, jax.device_put(slabs, self.device),
+                                 jax.device_put(np.array(part, np.int32),
+                                                self.device))
+                        self._count_upload("rowid", True, slabs.nbytes)
+                    global_stats.count("stack_incremental_updates_total")
+                    global_stats.count("stack_incremental_shards_total",
+                                       len(dirty))
+                    return arr, n_rows, vers, None
+                if not dirty:
+                    return old, n_rows, vers, None
+            host = np.empty(shape, dt)
+            for i, fr in enumerate(frags):
+                sl = slab(fr)
+                if sl is None:
+                    return refuse(gen)
+                host[i] = sl
+            if old is not None:
+                global_stats.count("stack_full_rebuilds_total")
+            if not self.make_room(nbytes, keep=key):
+                return None, 0, None, None
+            arr = jax.device_put(host, self.device)
+            self._count_upload("rowid", old, nbytes)
+            global_stats.with_tags(f"field:{field_obj.name}").timing(
+                "stack_build_seconds", time.perf_counter() - t_build
+            )
+            # Held as ids now: the dense stack, if any, goes.
+            self._drop_unpinned((index, field_obj.name, VIEW_STANDARD))
+            return arr, n_rows, vers, None
+
+        arr, rows, _ = self._cached_build(key, fingerprint, build)
+        return arr, rows
 
     def get_row(self, index: str, field_obj, shards: tuple[int, ...],
                 view_name: str, row_id: int):
         """[S_pad, 1, W/128, 128] single-row stack — the on-demand page
         for fields whose full stack exceeds the HBM budget (VERDICT r2 #8: row
         paging instead of whole-stack CPU fallback). Cached and
-        LRU-evicted like whole stacks; each entry costs S_pad x 128 KiB."""
+        LRU-evicted like whole stacks; each entry costs S_pad x 128 KiB.
+        None where the page cannot be admitted under the budget."""
         v = field_obj.view(view_name)
         fingerprint = (tuple(shards), v.generation if v is not None else -1)
         key = (index, field_obj.name, view_name, "row", row_id)
 
         def build(stale):
             s_pad = self._pad_shards(len(shards))
+            if not self.make_room(s_pad * WORDS_PER_SHARD * 4, keep=key):
+                return None, 1, None, None
             host = np.zeros((s_pad, 1, WORDS_PER_SHARD), dtype=np.uint32)
             for i, s in enumerate(shards):
                 fr = v.fragment(s) if v is not None else None
@@ -950,6 +1354,7 @@ class _StackedBlocks:
                     host[i, 0] = pack_row(fr, row_id)
             global_stats.count("hbm_page_uploads_total")
             global_stats.count("hbm_page_bytes_total", host.nbytes)
+            self._count_upload("dense", "page", host.nbytes)
             return self._put(host), 1, None, None
 
         return self._cached_build(key, fingerprint, build)[0]
@@ -1014,7 +1419,9 @@ class _StackedBlocks:
         the key's entry instead (no bytes, no ledger line), so that a
         verdict that cost a walk is reached once a fingerprint.
         Concurrent misses for one key build once (losers wait on the
-        winner's latch, then re-check)."""
+        winner's latch, then re-check). Inside a pin scope (pinned())
+        the entry handed back is pinned; a build is the span
+        `pilosa.stack_build` with its layout."""
         while True:
             hit = None
             nbytes = 0
@@ -1029,6 +1436,8 @@ class _StackedBlocks:
                     if led is not None:
                         self._bump_heat(led)
                         nbytes = led["bytes"]
+                    if cached[1] is not None:
+                        self._pin(key)
                     hit = (cached[1], cached[2], cached[4])
                 else:
                     latch = self._building.get(key)
@@ -1047,7 +1456,8 @@ class _StackedBlocks:
             # its fingerprint usually matches ours (same live fragments).
             latch.wait()
         try:
-            arr, rows_p, vers, tiers, *extra = build(cached)
+            with trace_span("pilosa.stack_build", layout=_layout_of(key)):
+                arr, rows_p, vers, tiers, *extra = build(cached)
             extra = extra[0] if extra else None
             if arr is None:
                 if keep_none:
@@ -1060,10 +1470,11 @@ class _StackedBlocks:
                 self._entries.pop(key, None)
                 self._entries[key] = (fingerprint, arr, rows_p, vers, extra)
                 self._ledger_upload(key, arr, tiers)
+                self._pin(key)
                 self._evict(keep=key)
             # Misses are references too: without them the reuse stream
             # would be hits-only and every distance would look resident.
-            self._record_reuse(key, int(np.prod(arr.shape)) * 4)
+            self._record_reuse(key, arr.nbytes)
             return arr, rows_p, extra
         finally:
             with self._lock:
@@ -1077,7 +1488,7 @@ class _StackedBlocks:
         the previous tier split (incremental splices don't re-attribute;
         the mix re-trues on the next full rebuild); otherwise the bytes
         default to the dense tier."""
-        nbytes = int(np.prod(arr.shape)) * 4
+        nbytes = int(arr.nbytes)
         self._upload_epoch += 1
         led = self._ledger.get(key)
         if led is None:
@@ -1129,22 +1540,111 @@ class _StackedBlocks:
             ent = self._entries.get((index, field_name, view_name))
             return ent[1] if ent is not None else None
 
-    def make_room(self, nbytes: int) -> None:
-        """LRU-evict cached stacks until `nbytes` fits under the budget —
-        used by streaming page sweeps so transient page uploads stay
-        inside max_bytes instead of stacking on top of a full cache."""
-        if self.max_bytes is None:
-            return
-        with self._lock:
-            target = max(0, self.max_bytes - nbytes)
-            while self.resident_bytes() > target and self._entries:
-                self._drop(next(iter(self._entries)))
+    def make_room(self, nbytes: int, keep: Optional[tuple] = None) -> bool:
+        """Evict least-recently-used entries until `nbytes` more fit under
+        the budget, BEFORE an upload (a whole stack and its build's
+        transient, a splice's new array, a page). `keep` and pinned
+        entries stay. Where what is left to evict is pinned by other
+        queries, wait for their pins to clear (hbm_pin_wait_seconds), at
+        most PIN_WAIT_SECONDS, and not at all where every query holding
+        pins waits here too (none of them would unpin). False where the
+        bytes cannot be admitted: the caller does not upload (no
+        over-allocation)."""
+        if self.budget() is None:
+            return True
+        t0 = None
+        mine = getattr(self._pin_local, "keys", None)
+        stalled = False
+        try:
+            while True:
+                self._unpinned.clear()
+                with self._lock:
+                    self._measure_other()
+                    budget = self.budget()
+                    resident = self.resident_bytes()
+                    waiting = False
+                    for k in list(self._entries):
+                        if resident + nbytes <= budget:
+                            break
+                        if k == keep:
+                            continue
+                        if self._pins.get(k):
+                            waiting = waiting or self._pinned_by_others(k)
+                            continue
+                        resident -= self._entry_bytes(k)
+                        self._drop(k)
+                    if resident + nbytes <= budget:
+                        return True
+                    if waiting and mine and not stalled:
+                        stalled = True
+                        self._stalled += 1
+                    if self._stalled >= self._holders:
+                        waiting = False
+                if not waiting:
+                    return False
+                if t0 is None:
+                    t0 = time.perf_counter()
+                elif time.perf_counter() - t0 > self.PIN_WAIT_SECONDS:
+                    return False
+                self._unpinned.wait(0.05)
+        finally:
+            if stalled:
+                with self._lock:
+                    self._stalled -= 1
+            if t0 is not None:
+                global_stats.timing("hbm_pin_wait_seconds",
+                                    time.perf_counter() - t0)
 
     def _evict(self, keep: tuple) -> None:
-        if self.max_bytes is None:
+        """After an upload: evict unpinned entries, least recently used
+        first, while the entries exceed the budget (make_room has made
+        the room before; this catches a budget that shrank since)."""
+        budget = self.budget()
+        if budget is None:
             return
-        while self.resident_bytes() > self.max_bytes and len(self._entries) > 1:
-            self._drop(next(k for k in self._entries if k != keep))
+        resident = self.resident_bytes()
+        for k in list(self._entries):
+            if resident <= budget:
+                break
+            if k == keep or self._pins.get(k):
+                continue
+            resident -= self._entry_bytes(k)
+            self._drop(k)
+
+    def _drop_unpinned(self, key: tuple) -> None:
+        """Evict `key` now unless a query holds it pinned."""
+        with self._lock:
+            if key in self._entries and not self._pins.get(key):
+                self._drop(key)
+
+    def _entry_bytes(self, key: tuple) -> int:
+        """Caller holds _lock."""
+        arr = self._entries[key][1]
+        return int(arr.nbytes) if arr is not None else 0
+
+    def _build_transient(self, nbytes: int, rows_p: int) -> int:
+        """Device bytes a full dense build holds beside the stack it
+        makes: the chunked builder's staging where it streams
+        (ChunkedStackBuilder.transient_bytes), and under a mesh, where
+        the splice program is warmed by a run (stacks of 64 MiB and
+        more), the copy that run writes and its round of slabs."""
+        out = 0
+        words = nbytes // 4
+        if words >= MIN_CHUNKED_WORDS:
+            out += ChunkedStackBuilder.transient_bytes((words,))
+        if self.mesh is not None and nbytes >= (64 << 20):
+            out += nbytes + self.mesh.n * rows_p * WORDS_PER_SHARD * 4
+        return out
+
+    @staticmethod
+    def _count_upload(layout: str, why, nbytes: int) -> None:
+        """hbm_upload_bytes_total{layout, why}: `why` is "page", or the
+        stale entry a build replaced (None: a miss; else a refresh)."""
+        if not isinstance(why, str):
+            why = "miss" if why is None else "refresh"
+        global_stats.with_tags(f"layout:{layout}", f"why:{why}").count(
+            "hbm_upload_bytes_total", int(nbytes)
+        )
 
     def _drop(self, victim: tuple) -> None:
         """Caller holds _lock. An entry with no array (a packed key's
@@ -1156,9 +1656,20 @@ class _StackedBlocks:
     def resident_bytes(self) -> int:
         with self._lock:
             return sum(
-                int(np.prod(e[1].shape)) * 4
+                int(e[1].nbytes)
                 for e in self._entries.values() if e[1] is not None
             )
+
+    def layout_bytes(self) -> dict[str, int]:
+        """Resident bytes by layout (dense, with row pages; packed;
+        rowid): the split behind hbm_layout_bytes{layout}, summing to
+        resident_bytes()."""
+        out = {"dense": 0, "packed": 0, "rowid": 0}
+        with self._lock:
+            for key, ent in self._entries.items():
+                if ent[1] is not None:
+                    out[_layout_of(key)] += int(ent[1].nbytes)
+        return out
 
     def tier_bytes(self) -> dict[str, int]:
         """Resident bytes by representation tier; the dict sums exactly
@@ -1403,6 +1914,13 @@ def _eval_spec(spec, blocks_it, scalars_it):
         with jax.named_scope("row_gather"):
             slab = jnp.take(block, row, axis=1)  # [S, W/128, 128]
             return slab * mask  # mask=0 zeroes rows beyond the packed range
+    if tag == "RI":
+        # ("RI", field, k): the columns of a row-id stack whose id is one
+        # of k traced rows (Row(f=r), or a Union of Rows of one field).
+        block = next(blocks_it)  # [S, W/128, 32, 128] row ids
+        rows = next(scalars_it)  # uint32[k], ROWID_NO_ROW past the field
+        with jax.named_scope("rowid_row"):
+            return rowid_row(block, rows)
     if tag == "T":
         # Time-range row: union of per-view row slabs (executor.go:1441).
         n_views = spec[2]
@@ -1826,6 +2344,7 @@ class TPUBackend(VersionWalks):
             device, self.mesh, max_bytes, fallback=self._count_device_fallback,
             heat_half_life=heat_half_life,
             packed_extra=self._packed_row_counts,
+            holder=holder,
         )
         self._fns: dict = {}
         self._fns_lock = threading.RLock()
@@ -2005,6 +2524,10 @@ class TPUBackend(VersionWalks):
         # n-ary bitwise verbs
         if not c.children:
             raise _Unsupported("empty verb")  # CPU path yields reference error/empty
+        if c.name == "Union":
+            leaf = self._build_rowid_union(index, c, shards, blocks, scalars)
+            if leaf is not None:
+                return leaf
         kids = tuple(
             self._build(index, ch, shards, blocks, scalars) for ch in c.children
         )
@@ -2032,6 +2555,9 @@ class TPUBackend(VersionWalks):
         if "from" in c.args or "to" in c.args:
             return self._build_time_row(index, c, f, row_id, shards, blocks, scalars)
 
+        leaf = self._rowid_leaf(index, f, shards, [row_id], blocks, scalars)
+        if leaf is not None:
+            return leaf
         try:
             block, rows_p = self._get_block(index, f, shards)
         except _Unsupported:
@@ -2046,6 +2572,46 @@ class TPUBackend(VersionWalks):
         scalars.append(np.uint32(min(row_id, rows_p - 1)))
         scalars.append(np.uint32(1 if row_id < rows_p else 0))
         return ("R", field_name)
+
+    def _get_rowid(self, index, f, shards):
+        """(row-id stack, rows) of a field held as row ids over `shards`,
+        or None where it is held dense (or its ids could not be built:
+        the choice then moves it back to dense)."""
+        if not self.blocks.rowid_field(index, f, shards):
+            return None
+        ids, rows = self.blocks.get_rowid(index, f, tuple(shards))
+        return None if ids is None else (ids, rows)
+
+    def _rowid_leaf(self, index, f, shards, row_ids, blocks, scalars):
+        """The ("RI", field, k) leaf over a field held as row ids, or
+        None where the field is held dense."""
+        got = self._get_rowid(index, f, shards)
+        if got is None:
+            return None
+        ids, n_rows = got
+        blocks.append(ids)
+        scalars.append(np.array(
+            [r if r < n_rows else ROWID_NO_ROW for r in row_ids],
+            dtype=np.uint32,
+        ))
+        return ("RI", f.name, len(row_ids))
+
+    def _build_rowid_union(self, index, c, shards, blocks, scalars):
+        """A Union of plain Rows of one field held as row ids, as ONE
+        row-id leaf (one pass over the ids), or None."""
+        names = {next(iter(ch.args), None) for ch in c.children}
+        if len(names) != 1:
+            return None
+        name = names.pop()
+        if not isinstance(name, str) or is_reserved_arg(name):
+            return None
+        ids = _named_rows(c, name)
+        if ids is None:
+            return None
+        f = self._field(index, name)
+        if f.options.type != FIELD_TYPE_MUTEX:
+            return None
+        return self._rowid_leaf(index, f, shards, sorted(ids), blocks, scalars)
 
     def _build_time_row(self, index, c, f, row_id, shards, blocks, scalars):
         """Row(f=r, from=, to=) — union over quantum views (executor.go:1441)."""
@@ -2529,6 +3095,7 @@ class TPUBackend(VersionWalks):
         ) * np.uint64(SHARD_WIDTH)
         return Row.from_columns(cols)
 
+    @_pinning
     def bitmap_call(self, index: str, c: Call, shards: list[int]) -> Row:
         """Whole-query bitmap materialization: evaluate the stack ONCE and
         read back [S, W/128, 128], slicing per-shard segments on the host: one
@@ -2588,6 +3155,7 @@ class TPUBackend(VersionWalks):
     def count_shard(self, index: str, c: Call, shard: int) -> int:
         return self.count_shards(index, c, [shard])
 
+    @_pinning
     def count_shards(self, index: str, c: Call, shards: list[int]) -> int:
         """Whole-query count: ONE jitted dispatch over all shards + one
         scalar readback — the reference's scatter-gather mapReduce
@@ -2845,6 +3413,11 @@ class TPUBackend(VersionWalks):
         entries, fa, fb = plan
         f_obj = self._field(index, fa)
         g_obj = self._field(index, fb)
+        if any(self.blocks.rowid_field(index, fo, shards_t)
+               for fo in (f_obj, g_obj)):
+            # The pair tables count dense stacks; the scan path reads
+            # row ids (_eval_spec's row-id leaf).
+            raise _Unsupported("row-id layout")
         # Host stats cache (the reference's rank-cache idea, cache.go:136:
         # materialize counts once, serve queries from them until writes
         # invalidate). Freshness is the LIVE per-shard fragment versions:
@@ -3313,11 +3886,13 @@ class TPUBackend(VersionWalks):
                 except Exception as e:  # noqa: BLE001
                     _log("pair program", e)
 
+    @_pinning
     def group_by(self, index, c: Call, filter_call, child_rows, shards,
                  cap=None, aggregate=None) -> Optional[list]:
         """_group_by, with a None (the executor then runs the host
         iterator) counted as a host-path answer. `aggregate` names the
-        int field of `aggregate=Sum(field=…)`, validated by the executor."""
+        int field of `aggregate=Sum(field=…)`, validated by the executor.
+        The stacks it fetches stay pinned until its answer is read back."""
         out = self._group_by(index, c, filter_call, child_rows, shards, cap,
                              aggregate)
         if out is None:
@@ -3359,6 +3934,9 @@ class TPUBackend(VersionWalks):
         if aggregate is not None:
             return self._group_sum(index, fields, starts, child_rows,
                                    shards_t, filter_call, aggregate, cap)
+        if any(self.blocks.rowid_field(index, fo, shards_t)
+               for _, fo in fields):
+            return None  # a count per group of row ids: the host's
         # Unfiltered 1-/2-field groups ARE the maintained host tables:
         # the TopN rank vector and the pair-count matrix — both
         # refreshed incrementally under write churn, so these GroupBys
@@ -3579,6 +4157,72 @@ class TPUBackend(VersionWalks):
 
         return self._stack_program("groupby_overlap", stats, (True,))
 
+    #: High parts of a row id one `pilosa_rowid_hist` launch counts: its
+    #: two small stacks hold at most this many and 32 dense rows.
+    ROWID_HIST_HIGHS = 32
+
+    def _rowid_program(self, kind: str, make, *static):
+        """`jit_pilosa_<kind>` over row ids (one device), counted as the
+        launches of its kind: `make(*static)` is the program's body, and
+        `static` keys it."""
+        key = (kind,) + static
+        with self._fns_lock:
+            got = self._fns.get(key)
+        if got is not None:
+            return got
+        prog = self._counted_launch(
+            kind, jax.jit(_named(kind, make(*static))), key=key
+        )
+        with self._fns_lock:
+            return self._fns.setdefault(key, prog)
+
+    def _rowid_hist_launches(self, ids, n_rows: int, extra: tuple) -> list:
+        """Launches of `pilosa_rowid_hist` that count a row-id stack's
+        ids under the filter (`extra`: the filter slab, or nothing),
+        their outputs read back in order the counts of ids 0, 1, … An id
+        is split into its low five bits and the rest: one launch counts
+        ROWID_HIST_HIGHS high parts against every low part (ops/kernels.py
+        rowid_hist); a field of up to 1,024 rows is one launch."""
+        bits = max(1, (n_rows - 1).bit_length())
+        lo_bits = min(bits, 5)
+        highs = 1 << (bits - lo_bits)
+        hi_rows = min(highs, self.ROWID_HIST_HIGHS)
+        interpret = pallas_interpret()
+
+        def make(lo_bits, hi_rows, _filtered):
+            def hist(ids, hi0, *filt):
+                return rowid_hist(ids, filt[0] if filt else None, hi0,
+                                  lo_bits, hi_rows, interpret=interpret)
+            return hist
+
+        prog = self._rowid_program("rowid_hist", make, lo_bits, hi_rows,
+                                   bool(extra))
+        s = ids.shape[0]
+        self.blocks.note_transient(
+            (hi_rows + (1 << lo_bits)) * s * WORDS_PER_SHARD * 4
+        )
+        outs = []
+        for h0 in range(0, highs, hi_rows):
+            outs.append(prog(ids, np.int32(h0), *extra))
+            # The bit-rows a launch reads: the ids' bits and the filter.
+            self.stats.count("rowid_hist_rows_total",
+                             8 * ids.dtype.itemsize + 1)
+        return outs
+
+    def _rowid_build_launch(self, ids, rows):
+        """The rows `rows` of a row-id stack as a dense stack
+        [S, bucket, L, 128] (`pilosa_rowid_build`), padded to a slot
+        bucket by empty rows so that few programs serve every count."""
+        k = _slot_bucket(rows.size)
+        padded = np.full(k, ROWID_NO_ROW, np.uint32)
+        padded[:rows.size] = rows
+        self.blocks.note_transient(k * ids.shape[0] * WORDS_PER_SHARD * 4)
+        prog = self._rowid_program(
+            "rowid_build", lambda: lambda ids, rows: rowid_rows(ids, rows)
+        )
+        self.stats.count("rowid_rows_built_total", int(rows.size))
+        return prog(ids, padded)
+
     def _rows_disjoint(self, index: str, name: str, stack) -> bool:
         """Whether no column of `stack` lies in two of its rows: the sum
         of the rows' counts equals the count of their union (never
@@ -3599,7 +4243,8 @@ class TPUBackend(VersionWalks):
         self._disjoint[key] = (weakref.ref(stack), verdict)
         return verdict
 
-    def _filter_candidates(self, index, names, stacks, filter_call) -> list:
+    def _filter_candidates(self, index, names, stacks, filter_call,
+                           rowids=None) -> list:
         """Per grouped field, the sorted row ids its live rows can only be
         among, or None (count the whole stack). A field has them where
         the filter, or a conjunct of an Intersect filter, names its rows
@@ -3608,10 +4253,12 @@ class TPUBackend(VersionWalks):
         other. Whether a stack's rows are disjoint is read from the
         stack (`_rows_disjoint`), whatever the field's type: a mutex
         field loaded by `import_roaring` can hold a column in two rows,
-        and both count it."""
+        and both count it. A field held as row ids (`rowids[i]` its row
+        count) holds a column in one row by construction."""
         out = [None] * len(names)
         if filter_call is None:
             return out
+        rowids = rowids or [None] * len(names)
         conjuncts = (filter_call.children if filter_call.name == "Intersect"
                      else [filter_call])
         for i, (name, stack) in enumerate(zip(names, stacks)):
@@ -3620,10 +4267,15 @@ class TPUBackend(VersionWalks):
                 named = _named_rows(call, name)
                 if named is not None:
                     ids = named if ids is None else ids & named
-            if ids is None or not self._rows_disjoint(index, name, stack):
+            if ids is None:
                 continue
+            n_rows = rowids[i]
+            if n_rows is None:
+                if not self._rows_disjoint(index, name, stack):
+                    continue
+                n_rows = stack.shape[1]
             out[i] = np.array(
-                sorted(r for r in ids if r < stack.shape[1]), dtype=np.int64
+                sorted(r for r in ids if r < n_rows), dtype=np.int64
             )
         return out
 
@@ -3663,8 +4315,17 @@ class TPUBackend(VersionWalks):
         else:
             prof = current_profile()
             try:
-                stacks = [self._get_block(index, fo, shards_t)[0]
-                          for _, fo in fields]
+                # A field held as row ids brings its ids and its row
+                # count; a dense one its stack and None.
+                stacks, rowids = [], []
+                for _, fo in fields:
+                    got = self._get_rowid(index, fo, shards_t)
+                    if got is None:
+                        stacks.append(self._get_block(index, fo, shards_t)[0])
+                        rowids.append(None)
+                    else:
+                        stacks.append(got[0])
+                        rowids.append(got[1])
                 bsi_block, _ = self._get_block(
                     index, f_agg, shards_t, view_name=vn,
                     min_rows=BSI_OFFSET_BIT + opts.bit_depth,
@@ -3684,10 +4345,10 @@ class TPUBackend(VersionWalks):
             if stacks[0].shape[0] > MAX_PAIR_SHARDS:
                 return None  # int32 accumulator bound (ops/kernels.py)
             cands = self._filter_candidates(
-                index, [f for f, _ in fields], stacks, filter_call
+                index, [f for f, _ in fields], stacks, filter_call, rowids
             )
             payload = self._group_sum_sweep(stacks, bsi_block, filt, opts,
-                                            cands)
+                                            cands, rowids)
             if payload is None:
                 return None
             if ckey is not None:
@@ -3698,7 +4359,8 @@ class TPUBackend(VersionWalks):
             [f for f, _ in fields], starts, child_rows, live, counts, sums, cap
         )
 
-    def _group_sum_sweep(self, stacks, bsi_block, filt, opts, cands):
+    def _group_sum_sweep(self, stacks, bsi_block, filt, opts, cands,
+                         rowids=None):
         """("sum", live rows a field, counts int64[K], sums int64[K]) over
         the K live combinations in odometer order, or None past
         MAX_GROUP_SUM_COMBOS. A row with no column under the filter is
@@ -3707,9 +4369,14 @@ class TPUBackend(VersionWalks):
         A field with candidates (`_filter_candidates`) has only those
         counted, by `pilosa_groupby_rows` where they are few; the count
         of the BSI block's sign row under the filter says whether any
-        column there holds a negative value. Each round of launches
-        is timed as the other device reads are: `dispatch`, then
-        `device_wait` until the device is done, then `readback`."""
+        column there holds a negative value. A field held as row ids
+        (`rowids[i]` its row count) has its candidates taken as its live
+        rows, or else its ids counted under the filter by
+        `pilosa_rowid_hist`; its live rows are then built into a small
+        dense stack (`pilosa_rowid_build`) that the count kernel reads
+        by position. Each round of launches is timed as the other device
+        reads are: `dispatch`, then `device_wait` until the device is
+        done, then `readback`."""
         depth = opts.bit_depth
         filtered = filt is not None
         extra = (filt,) if filtered else ()
@@ -3718,22 +4385,29 @@ class TPUBackend(VersionWalks):
                 else None)
         prune = self._group_program(1, filtered)
         count_rows = self._group_rows_program(filtered)
+        rowids = list(rowids or [None] * len(stacks))
 
         for ids in cands:
             self.stats.with_tags(
                 "path:stack" if ids is None else "path:filter"
             ).count("group_prune_fields_total")
         # The int field's sign row rides along as one more candidate list.
-        lists = list(zip(stacks, cands))
+        lists = list(zip(stacks, cands, rowids))
         if depth > 0:
-            lists.append((bsi_block, np.array([BSI_SIGN_BIT])))
-        # Per list: its launch, its ids, whether the launch counted the
-        # ids alone (padded to a slot bucket by an id past the stack) or
-        # every row of the stack.
+            lists.append((bsi_block, np.array([BSI_SIGN_BIT]), None))
+        # Per list: its launches, its ids, whether the launches counted
+        # the ids alone (padded to a slot bucket by an id past the stack)
+        # or every row (of a stack, or of row ids by id). Row ids with
+        # candidates launch nothing: the candidates are their live rows.
         pending = []
         with prof.phase("dispatch", span="pilosa.group_sum"):
-            for stack, ids in lists:
-                if ids is not None and ids.size <= MAX_GROUP_ROW_SLOTS and (
+            for stack, ids, n_rows in lists:
+                if n_rows is not None:
+                    pending.append(
+                        ([], ids) if ids is not None else
+                        (self._rowid_hist_launches(stack, n_rows, extra), None)
+                    )
+                elif ids is not None and ids.size <= MAX_GROUP_ROW_SLOTS and (
                     _slot_bucket(ids.size) * GROUP_ROWS_SHARE <= stack.shape[1]
                 ):
                     slots = np.full(_slot_bucket(ids.size), stack.shape[1],
@@ -3741,15 +4415,26 @@ class TPUBackend(VersionWalks):
                     slots[:ids.size] = ids
                     if repl is not None:
                         slots = jax.device_put(slots, repl)
-                    pending.append((count_rows(stack, slots, *extra), ids))
+                    pending.append(([count_rows(stack, slots, *extra)], ids))
                 else:
-                    pending.append((prune(stack, *extra), None))
+                    pending.append(([prune(stack, *extra)], None))
         with prof.phase("device_wait"):
-            self.programs.block_ready([out for out, _ in pending])
+            self.programs.block_ready([o for outs, _ in pending for o in outs])
         with prof.phase("readback"):
-            counts = [np.asarray(out) for out, _ in pending]
-        live = [np.flatnonzero(c > 0) if ids is None else ids[c[:ids.size] > 0]
-                for c, (_, ids) in zip(counts, pending)]
+            counts = [
+                np.concatenate([np.asarray(o).reshape(-1) for o in outs])
+                if outs else None
+                for outs, _ in pending
+            ]
+        live = []
+        for c, (_, ids), (_, _, n_rows) in zip(counts, pending, lists):
+            if c is None:
+                live.append(ids)
+            elif ids is None:
+                live.append(np.flatnonzero(c[:n_rows] > 0) if n_rows is not None
+                            else np.flatnonzero(c > 0))
+            else:
+                live.append(ids[c[:ids.size] > 0])
         signed = depth > 0 and live.pop().size > 0
         k_live = 1
         for lr in live:
@@ -3760,6 +4445,15 @@ class TPUBackend(VersionWalks):
         if k_live == 0:
             empty = np.zeros(0, np.int64)
             return ("sum", live_t, empty, empty)
+        # Row ids to dense: a field's live rows as a stack the count
+        # kernel reads by position (0 .. live - 1).
+        stacks, at = list(stacks), list(live)
+        with prof.phase("dispatch", span="pilosa.group_sum"):
+            for i, n_rows in enumerate(rowids):
+                if n_rows is not None:
+                    stacks[i] = self._rowid_build_launch(stacks[i], live[i])
+                    at[i] = np.arange(live[i].size)
+        live = at
         grids = np.meshgrid(*live, indexing="ij")
         combos = np.stack([g.ravel() for g in grids], axis=1).astype(np.int32)
         prog = self._group_sum_program(len(stacks), filtered, depth, signed)
@@ -4330,6 +5024,7 @@ class TPUBackend(VersionWalks):
             assembled[i] = (blocks, scalars)
         return groups, assembled, fallbacks
 
+    @_pinning
     def _generic_batch_dispatch(self, index, calls, shards_t, batch=None):
         """Group same-(spec, leaf-blocks) calls into fused scan dispatches:
         row ids become [Q] traced slot vectors, one program per group.
@@ -4545,6 +5240,7 @@ class TPUBackend(VersionWalks):
 
     # -- exact TopN (device fast path) -------------------------------------
 
+    @_pinning
     def topn_field(
         self,
         index: str,
@@ -4560,6 +5256,10 @@ class TPUBackend(VersionWalks):
         if f.view(VIEW_STANDARD) is None:
             return []
         shards_t = tuple(shards)
+        if self.blocks.rowid_field(index, f, shards_t):
+            # Per-row counts of a field held as row ids: the host's.
+            self._host_path("topn", "row-id layout")
+            return None
         if src_call is not None:
             try:
                 spec, blocks, scalars = self._assemble(index, src_call, shards_t)
@@ -4739,6 +5439,7 @@ class TPUBackend(VersionWalks):
             dev = self.blocks._put(host)
             global_stats.count("hbm_page_uploads_total")
             global_stats.count("hbm_page_bytes_total", host.nbytes)
+            self.blocks._count_upload("dense", "page", host.nbytes)
             with current_profile().phase("dispatch", span="pilosa.topn_page"):
                 if src is None:
                     out = self._program("topn_plain", None, reduce_dev)(dev)
@@ -5005,6 +5706,7 @@ class TPUBackend(VersionWalks):
             )
         return result
 
+    @_pinning
     def bsi_sum(self, index, field_name, shards, filter_call=None):
         """Distributed Sum(field): per-plane popcounts fused on device
         (+psum over ICI with a mesh), exact host weighting. Returns

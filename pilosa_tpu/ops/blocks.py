@@ -22,6 +22,14 @@ uint32[shards, rows_padded, PACKED_WORDS]: the 128 words in use on the
 lane axis, rows on the sublane axis, so a program sweeps every row of the
 field in one pass (pack_fragment_packed, pack_rows_packed).
 
+A third layout, "row ids", is for a mutex field where the index's dense
+stacks would not fit the block store's budget: one row id a column,
+uint8 up to 255 rows and uint16 up to 65,535, [shards, WORD_LINES, 32,
+WORD_LANES] so that the ids of a dense word's 32 columns lie on the
+sublane axis under its lane (pack_fragment_rowid; ops/kernels.py
+rowid_row, rowid_rows, rowid_hist). A row of it reads the whole field's
+ids, 2 MiB a shard at uint16 where a dense row reads 128 KiB.
+
 Packing walks roaring containers directly: a container key maps to
 (row, word-range) and its 1024 uint64 words view as 2048 little-endian
 uint32 words, so dense containers are a straight memcpy and array
@@ -318,3 +326,65 @@ def pack_rows_packed(frag, row_ids) -> Optional[np.ndarray]:
         datas.append(data)
     _or_positions(out, np.arange(len(row_ids)), datas)
     return out
+
+
+# -- the row-id layout: a mutex field as one row id a column ---------------
+
+#: Columns a word holds: the sublane axis of a row-id stack.
+ROWID_WORD_BITS = 32
+
+
+def rowid_dtype(n_rows: int):
+    """The id type of a row-id stack of n_rows rows, or None where ids
+    would not fit: uint8 up to 255 rows, uint16 up to 65,535. The type's
+    largest value marks a column that no row holds."""
+    if n_rows <= np.iinfo(np.uint8).max:
+        return np.uint8
+    if n_rows <= np.iinfo(np.uint16).max:
+        return np.uint16
+    return None
+
+
+def rowid_shape(n_shards: int) -> tuple[int, int, int, int]:
+    """Device shape of a row-id stack: [S, WORD_LINES, 32, WORD_LANES],
+    element [s, i, b, j] the id of column 32 (128 i + j) + b, so the ids
+    of one dense word's 32 columns lie on the sublane axis under that
+    word's lane and a compare packs to the dense layout's word in one
+    reduce (ops/kernels.py rowid_words)."""
+    return (n_shards, WORD_LINES, ROWID_WORD_BITS, WORD_LANES)
+
+
+def pack_fragment_rowid(frag, dtype) -> Optional[np.ndarray]:
+    """A fragment of a mutex field as its row-id slab, `dtype`[WORD_LINES,
+    32, WORD_LANES] in device order, or None where a column lies in two
+    rows (a mutex field loaded by import_roaring can hold one in two, and
+    the layout holds one id a column) or a row id does not fit `dtype`.
+    One numpy pass over the concatenated container positions."""
+    none = np.iinfo(dtype).max
+    ids = np.full(SHARD_WIDTH, none, dtype=dtype)
+    storage = frag.storage
+    keys = []
+    datas = []
+    for key in storage.keys():
+        c = storage.container(key)
+        if c is None or c.n == 0:
+            continue
+        keys.append(key)
+        datas.append(c.positions())
+    if keys:
+        keys_a = np.asarray(keys, dtype=np.int64)
+        rows = keys_a // _CONTAINERS_PER_ROW
+        if int(rows[-1]) >= none:
+            return None
+        lens = np.fromiter(map(len, datas), dtype=np.int64, count=len(datas))
+        cols = np.repeat((keys_a % _CONTAINERS_PER_ROW) << 16, lens)
+        cols += np.concatenate(datas).astype(np.int64)
+        rid = np.repeat(rows, lens).astype(dtype)
+        ids[cols] = rid
+        # A column set in two rows keeps one of them: the other reads
+        # back different.
+        if not np.array_equal(ids[cols], rid):
+            return None
+    return np.ascontiguousarray(
+        ids.reshape(WORD_LINES, WORD_LANES, ROWID_WORD_BITS).transpose(0, 2, 1)
+    )

@@ -877,3 +877,78 @@ def tanimoto_topn(packed, row_counts, ids, thresholds, active,
 def packed_row_counts(packed):
     """int32[S, R]: bits a row holds in each shard of a packed stack."""
     return jnp.sum(jax.lax.population_count(packed), axis=-1, dtype=jnp.int32)
+
+
+# -- the row-id layout (ops/blocks.py rowid_shape) --------------------------
+
+
+def rowid_words(match):
+    """bool[..., 32, 128] -> uint32[..., 128]: bit b of a lane's word is
+    the match of sublane b, the dense layout's word of the same 32
+    columns (distinct powers of two: their sum is their OR)."""
+    shifts = jnp.arange(32, dtype=jnp.uint32)[:, None]
+    return jnp.sum(match.astype(jnp.uint32) << shifts, axis=-2,
+                   dtype=jnp.uint32)
+
+
+#: A row a row-id leaf asks for that matches no column (a row past the
+#: field's): no id type holds it.
+ROWID_NO_ROW = 0xFFFFFFFF
+
+
+def _rowid_match(ids, rows):
+    """ids == rows in the ids' own type (a widened copy of the ids would
+    be a program's largest temporary), with a row the type cannot hold,
+    ROWID_NO_ROW among them, matching nothing (the type's largest value
+    marks a column no row holds)."""
+    none = jnp.iinfo(ids.dtype).max
+    return (ids == rows.astype(ids.dtype)) & (rows < none)
+
+
+def rowid_row(ids, rows):
+    """The columns of a row-id stack whose id is one of `rows`, as a
+    dense slab uint32[S, L, 128]: Row(f=r), or a Union of Rows of one
+    field, in one pass over the ids. `rows` uint32[k]; ROWID_NO_ROW
+    matches no column."""
+    match = _rowid_match(ids, rows[0])
+    for j in range(1, rows.shape[0]):
+        match = match | _rowid_match(ids, rows[j])
+    return rowid_words(match)
+
+
+def rowid_rows(ids, rows):
+    """Rows of a row-id stack as a dense stack uint32[S, k, L, 128], row
+    j the columns whose id is rows[j] (ROWID_NO_ROW: an empty row): the
+    operand a dense program (group_sum_counts) reads by row id."""
+    return rowid_words(
+        _rowid_match(ids[:, None], rows[None, :, None, None, None])
+    )
+
+
+def rowid_hist(ids, filt, hi0, lo_bits: int, hi_rows: int,
+               interpret: bool = False):
+    """int32[hi_rows, 2^lo_bits]: out[h, l] counts the columns under
+    `filt` (None: every column) whose id is ((hi0 + h) << lo_bits) | l.
+
+    An id is split in two: the columns of each of the hi_rows high parts
+    under the filter, and those of each low part, are built as two small
+    dense stacks and counted against each other by the pair sweep
+    (pair_stats): a column falls in exactly one (high, low) pair, its
+    id's. A column no row holds (the id type's largest value) is under
+    no filter. Temporaries: hi_rows + 2^lo_bits dense rows."""
+    # In the ids' own type throughout: a widened copy of the ids would
+    # be the program's largest temporary.
+    none = jnp.iinfo(ids.dtype).max
+    under = rowid_words(ids != none)
+    if filt is not None:
+        under = under & filt
+    lo_n = 1 << lo_bits
+    highs = (hi0 + jnp.arange(hi_rows, dtype=jnp.int32)).astype(ids.dtype)
+    hi_stack = rowid_words(
+        (ids >> lo_bits)[:, None] == highs[None, :, None, None, None]
+    ) & under[:, None]
+    lows = jnp.arange(lo_n, dtype=ids.dtype)
+    lo_stack = rowid_words(
+        (ids & (lo_n - 1))[:, None] == lows[None, :, None, None, None]
+    )
+    return pair_stats(hi_stack, lo_stack, interpret=interpret)[0]

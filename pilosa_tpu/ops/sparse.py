@@ -442,6 +442,21 @@ class ChunkedStackBuilder:
     r5 #2): past the bound, pending chunks drain into the placement
     accumulator early instead of stacking on top of it."""
 
+    @staticmethod
+    def transient_bytes(shape: tuple) -> int:
+        """Device bytes a build of `shape` holds beside the stack it
+        makes: the pending chunks' buffers (at most MAX_PENDING_BYTES,
+        and at most the stack), a chunk being placed and one being
+        expanded, the accumulator's padding past the stack, and where
+        that padding is not zero the final slice's copy of the stack
+        (finish())."""
+        n = int(np.prod(shape))
+        n_pad = ((n + CHUNK_WORDS - 1) // CHUNK_WORDS) * CHUNK_WORDS
+        return (
+            min(MAX_PENDING_BYTES, n * 4) + 2 * CHUNK_WORDS * 4
+            + (n_pad - n) * 4 + (n * 4 if n_pad != n else 0)
+        )
+
     def __init__(self, device, shape: tuple):
         self.device = device
         self.shape = tuple(int(s) for s in shape)

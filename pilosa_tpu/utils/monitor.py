@@ -87,6 +87,15 @@ def publish_hbm_gauges(blocks, stats=None) -> None:
     if tiers is not None:
         for tier, nbytes in tiers().items():
             s.with_tags(f"tier:{tier}").gauge("hbm_resident_bytes", nbytes)
+    # The same total by layout (dense, packed, rowid), and the budget the
+    # entries are held to (0 where no limit is known).
+    layouts = getattr(blocks, "layout_bytes", None)
+    if layouts is not None:
+        for layout, nbytes in layouts().items():
+            s.with_tags(f"layout:{layout}").gauge("hbm_layout_bytes", nbytes)
+    budget = getattr(blocks, "budget", None)
+    if budget is not None:
+        s.gauge("hbm_budget_bytes", budget() or 0)
     # Decayed-frequency heat per tier (ISSUE 18): same publisher
     # discipline as residency — poll loop and /metrics scrape share
     # this block, so the heat gauges can never disagree with the

@@ -43,6 +43,7 @@ claim is only as good as the attribution behind it (arXiv:1709.07821).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -122,6 +123,14 @@ def _span(phase: str, name: Optional[str], meta: dict):
     return _span_factory(
         name or _SPAN_NAMES.get(phase) or "pilosa." + phase, **meta
     )
+
+
+def span(name: str, **meta):
+    """A profiler span alone, timing no phase: work that runs inside a
+    request's phase or in no request at all (a stack build)."""
+    if _span_factory is None:
+        return contextlib.nullcontext()
+    return _span_factory(name, **meta)
 
 
 def cache_state(counters: Optional[dict]) -> Optional[str]:

@@ -415,3 +415,55 @@ def test_group_rows_program_reads_the_listed_rows_in_place(v5e, on_chip, meshed)
         be._overlap_program().__wrapped__, stack
     )
     assert mem.temp_size_in_bytes < row_bytes, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("filtered", [True, False])
+def test_row_id_programs_compile_at_72_shards(v5e, on_chip, filtered):
+    """A mutex field of 1,000 rows held as uint16 row ids over 72 shards
+    (an SF 100 node's share of eight, p_brand1): the histogram that
+    prunes it, the build of 64 live rows into a dense stack, and a row
+    leaf of a Union of eight of its rows compile for one v5e. The
+    histogram's two small stacks (32 + 32 rows, 604 MB) and a part of
+    the ids (151 MB) are its temporaries, the built rows (64 rows, 604
+    MB) the build's output; nothing of the size of the 9.4 GB dense
+    stack appears."""
+    shards = 72
+    be = TPUBackend(on_chip, device=v5e[0])
+    one = SingleDeviceSharding(v5e[0])
+    ids = jax.ShapeDtypeStruct((shards, WORD_LINES, 32, WORD_LANES), jnp.uint16,
+                               sharding=one)
+    slab = jax.ShapeDtypeStruct((shards, WORD_LINES, WORD_LANES), jnp.uint32,
+                                sharding=one)
+    hi0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    row_bytes = shards * WORD_LINES * WORD_LANES * 4
+
+    def make(lo_bits, hi_rows, _filtered):
+        def hist(ids, hi0, *filt):
+            return kernels.rowid_hist(ids, filt[0] if filt else None, hi0,
+                                      lo_bits, hi_rows)
+        return hist
+
+    prog = be._rowid_program("rowid_hist", make, 5, 32, filtered).__wrapped__
+    lowered = prog.lower(ids, hi0, *((slab,) if filtered else ()))
+    assert "jit_pilosa_rowid_hist" in lowered.as_text()[:400]
+    exe = lowered.compile()
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes <= (64 + 16) * row_bytes + (64 << 20)
+
+    build = be._rowid_program(
+        "rowid_build", lambda: lambda ids, rows: kernels.rowid_rows(ids, rows)
+    ).__wrapped__
+    exe = build.lower(
+        ids, jax.ShapeDtypeStruct((64,), jnp.uint32, sharding=one)
+    ).compile()
+    assert exe.memory_analysis().temp_size_in_bytes <= 16 << 20
+
+    from pilosa_tpu.exec.tpu import _eval_spec
+
+    spec = ("RI", "p_brand1", 8)
+    leaf = jax.jit(lambda blocks, scalars: _eval_spec(
+        spec, iter(blocks), iter(scalars)))
+    exe = leaf.lower(
+        (ids,), (jax.ShapeDtypeStruct((8,), jnp.uint32, sharding=one),)
+    ).compile()
+    assert exe.memory_analysis().temp_size_in_bytes <= MAX_COUNT_TEMP_BYTES

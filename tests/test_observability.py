@@ -360,6 +360,23 @@ class TestHbmLedger:
         assert tiers["run"] > 0
         h.close()
 
+    def test_layout_bytes_sum_to_resident(self):
+        """hbm_layout_bytes{layout}: dense, packed and rowid sum to the
+        resident total, as the tiers do."""
+        from pilosa_tpu.core.holder import Holder
+
+        _StackedBlocks = self._blocks_cls()
+        h = Holder(None).open()
+        rng = np.random.default_rng(8)
+        f = self._field(h, "f", rng.integers(0, SHARD_WIDTH, 500))
+        blocks = _StackedBlocks()
+        blocks.get("b", f, (0,))
+        layouts = blocks.layout_bytes()
+        assert layouts == {"dense": blocks.resident_bytes(), "packed": 0,
+                           "rowid": 0}
+        assert blocks.budget() is None  # a CPU device reports no limit
+        h.close()
+
     def test_ledger_coldness_order_and_access_churn(self):
         from pilosa_tpu.core.holder import Holder
 

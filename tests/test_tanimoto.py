@@ -274,7 +274,7 @@ def test_the_same_search_is_exact_under_any_budget(mole, budget):
     answered on the device, over every row, and no fallback is counted."""
     _, _, bits, holder = mole
     be = TPUBackend(holder, max_bytes=budget)
-    assert be.blocks.admission_bytes() == budget
+    assert be.blocks.budget() == budget
     assert be.packed_field("mole", "fingerprint", [0])
     ex = Executor(holder, backend=be)
     ex.batcher = ShardLegBatcher(be)
@@ -350,13 +350,15 @@ def test_not_packed_is_an_entry_of_the_block_store_and_goes_with_it(mole):
 
 
 def test_the_devices_own_limit_is_the_budget_where_none_is_set(mole, monkeypatch):
-    """On a chip `memory_stats()` gives `bytes_limit`: a dense stack past
-    it is refused (ISSUE 36: 207 GB were asked of 16) and the field is
-    held packed, with the server's options at their defaults."""
+    """On a chip `memory_stats()` gives `bytes_limit`: the budget is that
+    less the block store's reserve, a dense stack past it is refused
+    (207 GB were asked of 16) and the field is held packed, with the
+    server's options at their defaults."""
     ex, _, bits, holder = mole
     be = TPUBackend(holder)
-    monkeypatch.setattr(be.blocks, "_device_bytes", BUDGET)
-    assert be.blocks.admission_bytes() == BUDGET
+    monkeypatch.setattr(be.blocks, "_device_bytes",
+                        BUDGET + be.blocks.reserve_bytes())
+    assert be.blocks.budget() == BUDGET
     fld = holder.index("mole").field("fingerprint")
     assert be.blocks.get("mole", fld, (0,))[0] is None
     assert be.packed_field("mole", "fingerprint", [0])

@@ -218,6 +218,38 @@ class TestTPUBackendDifferential:
         assert be.blocks.evictions > 0
         assert be.blocks.resident_bytes() <= 3 * 8 * WORDS_PER_SHARD * 4
 
+    def test_hbm_budget_holds_at_every_upload(self, holder, rng):
+        """Room is made BEFORE an upload: the entries never exceed the
+        budget, not even for the moment between an upload and an
+        eviction, and a pinned stack stays while its query runs."""
+        ex_cpu, _ = self._setup(holder, rng)
+        # One stack of the index's shards fits, and not two.
+        budget = 4 * 8 * WORDS_PER_SHARD * 4
+        be = TPUBackend(holder, max_bytes=budget)
+        seen = []
+        real = be.blocks._ledger_upload
+
+        def upload(key, arr, tiers):
+            real(key, arr, tiers)
+            seen.append(be.blocks.resident_bytes())
+
+        be.blocks._ledger_upload = upload
+        ex_tpu = Executor(holder, backend=be)
+        for q in ["Count(Row(f=1))", "Count(Row(g=7))", "Count(Row(f=2))",
+                  "Count(Intersect(Row(f=1), Row(g=7)))"]:
+            want = [result_to_json(r) for r in ex_cpu.execute("i", q)]
+            got = [result_to_json(r) for r in ex_tpu.execute("i", q)]
+            assert got == want, q
+        assert seen and max(seen) <= budget
+        f = holder.index("i").field("f")
+        shards = tuple(be.blocks._entries[next(iter(be.blocks._entries))][0][0])
+        with be.blocks.pinned():
+            assert be.blocks.get("i", f, shards)[0] is not None
+            assert be.blocks.make_room(budget) is False
+            assert ("i", "f", "standard") in be.blocks._entries
+        assert be.blocks.make_room(budget) is True
+        assert be.blocks.resident_bytes() == 0
+
 
 class TestMeshExecutor:
     """Real PQL through the 8-device mesh: holder-resident fragments are

@@ -187,6 +187,10 @@ ALLOWED_TAG_KEYS = {
                # `no_drain`): literals, never request content
     "runtime", # whose thread spent CPU: python/native (two literals)
     "fill",    # a launched slot: live/pad (two literals)
+    "layout",  # a block-store entry's layout: dense/packed/rowid (three
+               # literals, exec/tpu.py _layout_of)
+    "why",     # why an upload was made: miss/refresh/page (three
+               # literals, exec/tpu.py _count_upload)
 }
 
 #: Variable names that smell like raw request content. A tag VALUE
